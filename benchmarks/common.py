@@ -8,8 +8,11 @@ the same blocks ``benchmarks/run.py`` embeds in ``summary.json``.
 
 from __future__ import annotations
 
+import contextlib
+import io
 import json
 import os
+import subprocess
 import sys
 import time
 from typing import Callable, Dict, List
@@ -33,6 +36,34 @@ def timeit(fn: Callable, *args, repeats: int = 5, warmup: int = 1) -> float:
         jax.block_until_ready(out)
         ts.append(time.perf_counter() - t0)
     return float(np.median(ts))
+
+
+def run_worker(script: str, nranks: int, name: str) -> Dict:
+    """Run a benchmark worker script; return its ``RESULT_JSON=`` payload.
+
+    Under ``JAX_PLATFORMS=cpu`` the worker runs in a child process with
+    ``nranks`` emulated CPU devices, a flag that must be set before JAX
+    starts. On an accelerator a chip belongs to one process, so the worker
+    runs in this one.
+    """
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_"
+                                         f"device_count={nranks}")
+        proc = subprocess.run([sys.executable, "-c", script], env=env,
+                              capture_output=True, text=True, timeout=3600)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"{name} worker failed:\n{proc.stderr[-3000:]}")
+        stdout = proc.stdout
+    else:
+        buf = io.StringIO()
+        with contextlib.redirect_stdout(buf):
+            exec(compile(script, f"<{name} worker>", "exec"),
+                 {"__name__": f"{name}_worker"})
+        stdout = buf.getvalue()
+    payload = next(line for line in stdout.splitlines()
+                   if line.startswith("RESULT_JSON="))
+    return json.loads(payload[len("RESULT_JSON="):])
 
 
 def emit(rows: List[Dict], name: str) -> None:

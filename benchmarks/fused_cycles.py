@@ -34,9 +34,9 @@ measured window (must be 0). The headline artifact lands at the repo root
 as ``BENCH_fused_cycles.json`` with ``_env`` provenance; CSV rows go to
 ``benchmarks/results/fused_cycles.json``.
 
-The measurement runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` so the mesh exists
-regardless of how the parent process configured jax.
+Under ``JAX_PLATFORMS=cpu`` the measurement runs in a subprocess with
+4 emulated CPU devices; on an accelerator it runs in this process,
+which holds the chip (``common.run_worker``).
 
 Run:  PYTHONPATH=src python benchmarks/fused_cycles.py [ncycles]
 """
@@ -45,13 +45,12 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 try:                                    # runnable as module or script
-    from .common import emit
+    from .common import emit, run_worker
 except ImportError:                     # pragma: no cover
-    from common import emit
+    from common import emit, run_worker
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
@@ -64,12 +63,10 @@ REGIMES = {
 
 _WORKER = """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(nranks)d"
 import sys, time, json
 sys.path.insert(0, %(src)r)
 import numpy as np
 import jax
-jax.config.update("jax_default_matmul_precision", "float32")
 from repro.sph import SimulationSpec, SPHConfig, build_simulation
 
 base = SimulationSpec(
@@ -146,14 +143,7 @@ def _measure(regime: dict, ncycles: int, nranks: int, max_warm: int) -> dict:
     script = _WORKER % {"nranks": nranks, "ncycles": ncycles,
                         "max_warm": max_warm,
                         "src": os.path.join(ROOT, "src"), **regime}
-    proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, text=True, timeout=3600)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"fused_cycles worker failed:\n{proc.stderr[-3000:]}")
-    payload = next(line for line in proc.stdout.splitlines()
-                   if line.startswith("RESULT_JSON="))
-    return json.loads(payload[len("RESULT_JSON="):])
+    return run_worker(script, nranks, "fused_cycles")
 
 
 def run(ncycles=4, nranks=4, max_warm=4) -> list:

@@ -23,36 +23,31 @@ measured at the same simulation epoch and the final states can be compared
 bitwise; ``measure_compiles`` reports any compile residue in the measured
 window.
 
-The measurement runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` so the collective
-paths have a 4-device mesh regardless of how the parent process configured
-jax. Results land in ``benchmarks/results/halo_transport.json``.
+Under ``JAX_PLATFORMS=cpu`` the measurement runs in a subprocess with
+4 emulated CPU devices; on an accelerator it runs in this process,
+which holds the chip (``common.run_worker``).
 
 Run:  PYTHONPATH=src python benchmarks/halo_transport.py [n_side] [ncycles]
 """
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
 import sys
 
 try:                                    # runnable as module or script
-    from .common import emit
+    from .common import emit, run_worker
 except ImportError:                     # pragma: no cover
-    from common import emit
+    from common import emit, run_worker
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 _WORKER = """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(nranks)d"
 import sys, time, json
 sys.path.insert(0, %(src)r)
 import numpy as np
 import jax
-jax.config.update("jax_default_matmul_precision", "float32")
 from repro.sph import SimulationSpec, SPHConfig, build_simulation
 
 base = SimulationSpec(
@@ -125,14 +120,7 @@ def run(n_side=8, ncycles=3, nranks=4, max_warm=8) -> list:
     script = _WORKER % {"nranks": nranks, "n_side": n_side,
                         "ncycles": ncycles, "max_warm": max_warm,
                         "src": os.path.join(ROOT, "src")}
-    proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, text=True, timeout=3600)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"halo_transport worker failed:\n{proc.stderr[-3000:]}")
-    payload = next(line for line in proc.stdout.splitlines()
-                   if line.startswith("RESULT_JSON="))
-    res = json.loads(payload[len("RESULT_JSON="):])
+    res = run_worker(script, nranks, "halo_transport")
 
     rows = []
     for label in ("host", "collective", "fused"):

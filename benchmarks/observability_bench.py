@@ -14,9 +14,9 @@ per-cell work vectors' share of the single per-cycle metrics pull, the
 cost-calibration fit residual, and the repartition advisor's
 advised-vs-current imbalance (which must never regress).
 
-The measurement runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` so the mesh exists
-regardless of how the parent process configured jax.
+Under ``JAX_PLATFORMS=cpu`` the measurement runs in a subprocess with
+4 emulated CPU devices; on an accelerator it runs in this process,
+which holds the chip (``common.run_worker``).
 
 Run:  PYTHONPATH=src python benchmarks/observability_bench.py [n_side] [ncycles]
 """
@@ -25,25 +25,22 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
 try:                                    # runnable as module or script
-    from .common import emit, env_provenance
+    from .common import emit, env_provenance, run_worker
 except ImportError:                     # pragma: no cover
-    from common import emit, env_provenance
+    from common import emit, env_provenance, run_worker
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 _WORKER = """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(nranks)d"
 import sys, json
 sys.path.insert(0, %(src)r)
 import numpy as np
 import jax
-jax.config.update("jax_default_matmul_precision", "float32")
 from repro.sph import SimulationSpec, SPHConfig, build_simulation
 from repro.observability import UMBRELLA_SPANS
 
@@ -114,14 +111,7 @@ def run(n_side=6, ncycles=3, nranks=4, warm=2) -> list:
     script = _WORKER % {"nranks": nranks, "n_side": n_side,
                         "ncycles": ncycles, "warm": warm,
                         "src": os.path.join(ROOT, "src")}
-    proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, text=True, timeout=3600)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"observability_bench worker failed:\n{proc.stderr[-3000:]}")
-    payload = next(line for line in proc.stdout.splitlines()
-                   if line.startswith("RESULT_JSON="))
-    res = json.loads(payload[len("RESULT_JSON="):])
+    res = run_worker(script, nranks, "observability_bench")
     overhead_us = _tracer_overhead_us()
 
     rows = []

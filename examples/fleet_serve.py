@@ -17,6 +17,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.fleet import FleetRunner
     from repro.sph import SimulationSpec
 

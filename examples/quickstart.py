@@ -26,6 +26,8 @@ from repro.sph.engine import build_taskgraph
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("=== 1. clustered initial conditions")
     ic = clustered_ic(3000, seed=0)
     print(f"    {len(ic['pos'])} particles, h ∈ "

@@ -24,6 +24,8 @@ import numpy as np
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n_side = int(sys.argv[1]) if len(sys.argv) > 1 else 12
     ncycles = int(sys.argv[2]) if len(sys.argv) > 2 else 4
 

@@ -23,6 +23,8 @@ import numpy as np
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config

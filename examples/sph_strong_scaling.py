@@ -15,6 +15,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 8000
     from benchmarks.strong_scaling import run
     rows = run(n_particles=n,

@@ -1,7 +1,7 @@
 """Distribution layer: sharding rules, overlapped collectives, placement."""
 
-from .mesh_utils import axis_size, batch_pref, data_axes, \
-    mesh_with_auto_axes, named, ranks_mesh, ring_perm, valid_spec
+from .mesh_utils import batch_pref, data_axes, named, ranks_mesh, \
+    ring_perm, valid_spec
 from .sharding_rules import ShardingRules
 from .transport import (BucketPolicy, CompileProbe, HostTransport,
                         ProgramCache, ResidentBuffers, ShipSlots,
@@ -16,7 +16,7 @@ from .compression import (CompressState, compress_grads, compressed_bytes,
                           decompress_grads, init_compress_state)
 
 __all__ = [
-    "axis_size", "batch_pref", "data_axes", "mesh_with_auto_axes",
+    "batch_pref", "data_axes",
     "named", "ranks_mesh", "ring_perm", "valid_spec", "ShardingRules",
     "BucketPolicy", "CompileProbe", "HostTransport", "ProgramCache",
     "ResidentBuffers", "ShipSlots", "TransferProbe", "Transport",

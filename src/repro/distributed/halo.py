@@ -19,16 +19,16 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh_utils import axis_size, ring_perm
+from .mesh_utils import ring_perm
 
 
 def swa_halo_exchange(kv_local, *, axis: str, window: int):
     """kv_local (B, S_shard, …): returns the previous shard's trailing
     ``window`` positions (zeros for shard 0)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     tail = kv_local[:, -window:]
     halo = jax.lax.ppermute(tail, axis, ring_perm(n))   # from shard idx-1

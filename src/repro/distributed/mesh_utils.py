@@ -34,29 +34,6 @@ def ring_perm(n: int, offset: int = 1) -> List[Tuple[int, int]]:
     return [(i, (i + offset) % n) for i in range(n)]
 
 
-def axis_size(axis: str):
-    """Named-axis size from inside shard_map/pmap, across jax versions:
-    ``jax.lax.axis_size`` arrived after 0.4; older jax constant-folds a
-    ``psum`` of a literal to the axis size."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis_name=axis)
-
-
-def mesh_with_auto_axes(devices, axes: Sequence[str]) -> Mesh:
-    """``Mesh(devices, axes)`` with explicit Auto axis types where the jax
-    version has them (``jax.sharding.AxisType`` arrived after 0.4; older
-    jax has no ``axis_types`` keyword and defaults to the same
-    behaviour)."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:                 # pragma: no cover
-        return Mesh(devices, tuple(axes))
-    return Mesh(devices, tuple(axes),
-                axis_types=(AxisType.Auto,) * len(tuple(axes)))
-
-
 def valid_spec(shape: Sequence[int], prefs: Sequence[AxisPref],
                mesh: Mesh) -> P:
     """Build a PartitionSpec, taking each dim's first *valid* axis choice.

@@ -25,10 +25,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh_utils import axis_size, ring_perm
+from .mesh_utils import ring_perm
 
 
 def allgather_matmul_local(x_local, w_local, *, axis: str):
@@ -39,7 +39,7 @@ def allgather_matmul_local(x_local, w_local, *, axis: str):
     plain allgather-then-matmul gives; here each round contributes the rows
     owned by a different shard, written into its slice of the output.
     """
-    n_dev = axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     m = x_local.shape[0]
     out = jnp.zeros((m * n_dev, w_local.shape[1]), x_local.dtype)
@@ -63,7 +63,7 @@ def matmul_reducescatter_local(x_local, w_local, *, axis: str):
     adds it to the accumulator riding the ring — the classic reduce-scatter
     matmul fusion.
     """
-    n_dev = axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     m = x_local.shape[0]
     assert m % n_dev == 0, "row dim must divide the axis"

@@ -92,6 +92,8 @@ def main(argv=None) -> int:
                     help="write the multi-request Chrome trace here")
     args = ap.parse_args(argv)
 
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
     from .queue import RequestState
     from .runner import FleetRunner, sequential_reference
 

@@ -328,7 +328,7 @@ class FleetRunner:
             f = jax.vmap(functools.partial(step, box=box, cfg=cfg),
                          in_axes=(0, None, 0))
             if ndev > 1:
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
                 from ..distributed.mesh_utils import ranks_mesh
                 mesh = ranks_mesh(ndev, axis="fleet")
@@ -342,7 +342,7 @@ class FleetRunner:
                 return jnp.min(cfl_timestep_particles(state, cfg))
             f = jax.vmap(one)
             if ndev > 1:
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
                 from ..distributed.mesh_utils import ranks_mesh
                 mesh = ranks_mesh(ndev, axis="fleet")

@@ -1,13 +1,12 @@
 """Pallas TPU kernels for SPH cell-pair interactions.
 
-One grid program = one SWIFT pair task (``density_pair`` / ``force_pair``).
-TPU-native design decisions (DESIGN.md §8.3):
+One grid program = a block of ``PAIRS_PER_PROGRAM`` SWIFT pair tasks
+(``density_pair`` / ``force_pair``). TPU-native design decisions:
 
-* The (C × C) interaction matrix is the unit of work. Distances use the
-  dot-product form r² = |xi|² + |xj|² − 2·xi·xjᵀ, so the inner op is a
-  (C,3) @ (3,C) matmul feeding the MXU, followed by VPU element-wise kernel
-  evaluation. C (cell capacity) is padded to a multiple of 8 and capped by
-  VMEM: C=128 gives 64 kB per f32 (C,C) buffer.
+* The (C × C) interaction matrix is the unit of work, laid out with the
+  i-side particle on sublanes and the j-side particle on lanes. Distances
+  are formed from coordinate differences, three (C, C) planes, so they are
+  exact f32 element-wise arithmetic with no matmul precision to choose.
 * **Symmetry exploited** — both directions of the pair are produced in one
   program (row-reductions → i-side, column-reductions → j-side), reusing the
   distance matrix. The vmapped reference evaluates each direction separately;
@@ -16,16 +15,16 @@ TPU-native design decisions (DESIGN.md §8.3):
 * Periodic image shifts are applied by the host wrapper (ops.py), so the
   kernel body is branch-free Euclidean geometry.
 
-Layout: positions/velocities are passed as (C, 3) blocks; the small
-trailing dim lives in lanes only during the matmul and is irrelevant for
-correctness in interpret mode. Per-pair scalar-ish fields (h, m, mask, …)
-are (C,) blocks.
+Layout: the wrappers pass per-particle scalars as (P, C) and vectors as
+coordinate planes (P, 3, C), and pad P to a multiple of
+``PAIRS_PER_PROGRAM``, so every block's last two dimensions are either
+(8, C) or the whole (3, C) — the shapes Mosaic tiles. Inside a program each
+pair's i-side fields are turned from (1, C) rows into (C, 1) columns.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,195 +57,249 @@ def _two_prod(a, b):
     return p, err
 
 
-def _df_weighted_contract(w, g, rhat, axis):
-    """Σ_axis w·g·r̂ in double-float precision, rounded once at the end.
+def _df_sum_rows(hi, lo):
+    """Σ over axis 0 of the double-float matrix hi + lo, rounded once.
 
-    ``w`` broadcasts against the (C, C) matrix ``g``; ``rhat`` is (C, C, 3).
-    Both directions of a pair task contract the *same* g and r̂ matrices, so
-    computing the products and the reduction in double-float makes the pair's
-    momentum exchange antisymmetric to the final-rounding floor — Newton's
-    third law holds to ~1 ulp of each dv entry instead of drifting with the
-    length of the f32 product/reduction chain.
+    A compensated (two-sum) accumulation in a fixed order: first over
+    aligned 8-row slabs, then down the 8 rows of the slab sum, so the result
+    is the same on every backend.
+    """
+    slab = 8 if hi.shape[0] % 8 == 0 else 1
+
+    def acc(s_hi, s_lo, x_hi, x_lo):
+        s, e = _two_sum(s_hi, x_hi)
+        e = e + (s_lo + x_lo)
+        t = s + e                       # renormalise the pair
+        return t, e - (t - s)
+
+    s_hi, s_lo = hi[:slab], lo[:slab]
+    for k in range(slab, hi.shape[0], slab):
+        s_hi, s_lo = acc(s_hi, s_lo, hi[k:k + slab], lo[k:k + slab])
+    r_hi, r_lo = s_hi[0:1], s_lo[0:1]
+    for k in range(1, slab):
+        r_hi, r_lo = acc(r_hi, r_lo, s_hi[k:k + 1], s_lo[k:k + 1])
+    return r_hi + r_lo
+
+
+def _df_weighted_contract(w, g, rhat, axis):
+    """Σ_axis w·g·r̂ in double-float precision, as a (1, C) row per
+    coordinate of the (C, C) planes ``rhat``.
+
+    ``w`` broadcasts against the (C, C) matrix ``g``. Both directions of a
+    pair task contract the *same* g and r̂ matrices, so computing the
+    products and the reduction in double-float makes the pair's momentum
+    exchange antisymmetric to the final-rounding floor — Newton's third law
+    holds to ~1 ulp of each dv entry instead of drifting with the length of
+    the f32 product/reduction chain. ``axis=1`` (the i-side) contracts the
+    transposed planes down their rows.
     """
     p1, e1 = _two_prod(jnp.broadcast_to(w, g.shape), g)
-    p2, e2 = _two_prod(p1[:, :, None], rhat)
-    lo = e2 + e1[:, :, None] * rhat
-    hi = jnp.moveaxis(p2, axis, 0)
-    lo = jnp.moveaxis(lo, axis, 0)
-
-    def body(k, carry):
-        s_hi, s_lo = carry
-        s, e = _two_sum(s_hi, hi[k])
-        e = e + (s_lo + lo[k])
-        s2 = s + e                      # renormalise the pair
-        return s2, e - (s2 - s)
-
-    init = (jnp.zeros_like(hi[0]), jnp.zeros_like(lo[0]))
-    s_hi, s_lo = jax.lax.fori_loop(0, hi.shape[0], body, init)
-    return s_hi + s_lo
+    out = []
+    for rk in rhat:
+        p2, e2 = _two_prod(p1, rk)
+        lo = e2 + e1 * rk
+        if axis == 1:
+            p2, lo = p2.T, lo.T
+        out.append(_df_sum_rows(p2, lo))
+    return out
 
 
-def _r_and_rhat(xi, xj):
-    """(C,C) distances, (C,C,3) displacement and unit displacement via the
-    MXU dot form."""
-    sq_i = jnp.sum(xi * xi, axis=-1)
-    sq_j = jnp.sum(xj * xj, axis=-1)
-    cross = jnp.dot(xi, xj.T, preferred_element_type=jnp.float32)
-    r2 = jnp.maximum(sq_i[:, None] + sq_j[None, :] - 2.0 * cross, 0.0)
-    r = jnp.sqrt(r2 + EPS)
-    dx = xi[:, None, :] - xj[None, :, :]
-    rhat = dx / r[:, :, None]
-    return r2, r, dx, rhat
+PAIRS_PER_PROGRAM = 8       # pair tasks per grid program: the (8, C) block
+
+
+def _col(row):
+    """(1, C) row → (C, 1) column."""
+    return row.reshape(row.shape[1], 1)
+
+
+def _rows(ref, b):
+    """The three (1, C) coordinate rows of pair ``b`` of a (TB, 3, C)
+    block."""
+    return [ref[b, k:k + 1, :] for k in range(3)]
+
+
+def _geometry(xi, xj):
+    """(C, C) displacement planes, r² and r from (1, C) coordinate rows:
+    exact f32 differences, so close pairs far from the origin keep their
+    digits."""
+    dx = [_col(a) - b for a, b in zip(xi, xj)]
+    r2 = dx[0] * dx[0] + dx[1] * dx[1] + dx[2] * dx[2]
+    return dx, r2, jnp.sqrt(r2 + EPS)
+
+
+def _flag(cond):
+    return jnp.where(cond, 1.0, 0.0)
 
 
 # ------------------------------------------------------------------ density
-def _density_kernel(pos_i_ref, h_i_ref, m_i_ref, mask_i_ref,
-                    pos_j_ref, h_j_ref, m_j_ref, mask_j_ref,
+def _density_kernel(h_i_ref, m_i_ref, mask_i_ref, h_j_ref, m_j_ref,
+                    mask_j_ref, pos_i_ref, pos_j_ref,
                     rho_i_ref, drho_i_ref, nngb_i_ref,
                     rho_j_ref, drho_j_ref, nngb_j_ref,
                     *, kernel: str):
     w_fn, dwdr_fn = get_kernel(kernel)
-    xi = pos_i_ref[0]          # (C, 3)
-    xj = pos_j_ref[0]
-    hi = h_i_ref[0][:, None]   # (C, 1)
-    hj = h_j_ref[0][None, :]   # (1, C)
-    _r2, r, _dx, _rhat = _r_and_rhat(xi, xj)
 
-    # i <- j (rows reduce over j)
-    wi = w_fn(r, hi)
-    mj = (m_j_ref[0] * mask_j_ref[0])[None, :]
-    rho_i_ref[0] = jnp.sum(mj * wi, axis=1)
-    dwdh_i = -(3.0 * wi + r * dwdr_fn(r, hi)) / hi
-    drho_i_ref[0] = jnp.sum(mj * dwdh_i, axis=1)
-    nngb_i_ref[0] = jnp.sum((wi > 0.0) * mask_j_ref[0][None, :], axis=1)
+    def pair(b, carry):
+        row = lambda ref: ref[pl.ds(b, 1), :]        # noqa: E731
+        _dx, _r2, r = _geometry(_rows(pos_i_ref, b), _rows(pos_j_ref, b))
+        hi = _col(row(h_i_ref))                      # (C, 1)
+        hj = row(h_j_ref)                            # (1, C)
 
-    # j <- i (columns reduce over i) — same r matrix, h_j kernel
-    wj = w_fn(r, hj)
-    mi = (m_i_ref[0] * mask_i_ref[0])[:, None]
-    rho_j_ref[0] = jnp.sum(mi * wj, axis=0)
-    dwdh_j = -(3.0 * wj + r * dwdr_fn(r, hj)) / hj
-    drho_j_ref[0] = jnp.sum(mi * dwdh_j, axis=0)
-    nngb_j_ref[0] = jnp.sum((wj > 0.0) * mask_i_ref[0][:, None], axis=0)
+        # i <- j (rows reduce over j)
+        wi = w_fn(r, hi)
+        mj = row(m_j_ref) * row(mask_j_ref)
+        dwdh_i = -(3.0 * wi + r * dwdr_fn(r, hi)) / hi
+        for out, val in ((rho_i_ref, mj * wi), (drho_i_ref, mj * dwdh_i),
+                         (nngb_i_ref, _flag(wi > 0.0) * row(mask_j_ref))):
+            out[pl.ds(b, 1), :] = jnp.sum(val, axis=1, keepdims=True).T
+
+        # j <- i (columns reduce over i) — same r matrix, h_j kernel
+        wj = w_fn(r, hj)
+        mi = _col(row(m_i_ref) * row(mask_i_ref))
+        dwdh_j = -(3.0 * wj + r * dwdr_fn(r, hj)) / hj
+        for out, val in ((rho_j_ref, mi * wj), (drho_j_ref, mi * dwdh_j),
+                         (nngb_j_ref, _flag(wj > 0.0) * _col(row(mask_i_ref)))):
+            out[pl.ds(b, 1), :] = jnp.sum(val, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, PAIRS_PER_PROGRAM, pair, 0)
+
+
+def _blocked(fn, scalars, planes, n_scalar_out, n_plane_out, interpret):
+    """Run the kernel ``fn`` over pair blocks: pad P to the block, lay
+    vectors out as coordinate planes, and undo both on the outputs. ``fn``
+    takes the scalar refs, then the plane refs, then the scalar and the
+    plane output refs."""
+    P, C = scalars[0].shape
+    tb = PAIRS_PER_PROGRAM
+    pad = -P % tb
+    if pad:
+        edge = lambda a: jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1),
+                                 mode="edge")                # noqa: E731
+        scalars = [edge(a) for a in scalars]
+        planes = [edge(a) for a in planes]
+    planes = [jnp.swapaxes(a, 1, 2) for a in planes]          # (P, 3, C)
+    Pp = P + pad
+    f32 = scalars[0].dtype
+    sca = pl.BlockSpec((tb, C), lambda p: (p, 0))
+    vec = pl.BlockSpec((tb, 3, C), lambda p: (p, 0, 0))
+    outs = pl.pallas_call(
+        fn,
+        grid=(Pp // tb,),
+        in_specs=[sca] * len(scalars) + [vec] * len(planes),
+        out_specs=[sca] * n_scalar_out + [vec] * n_plane_out,
+        out_shape=([jax.ShapeDtypeStruct((Pp, C), f32)] * n_scalar_out
+                   + [jax.ShapeDtypeStruct((Pp, 3, C), f32)] * n_plane_out),
+        interpret=interpret,
+    )(*scalars, *planes)
+    return ([o[:P] for o in outs[:n_scalar_out]]
+            + [jnp.swapaxes(o[:P], 1, 2) for o in outs[n_scalar_out:]])
 
 
 def density_pair_pallas(pos_i, h_i, m_i, mask_i, pos_j, h_j, m_j, mask_j,
-                        *, kernel: str = "cubic", interpret: bool = True):
+                        *, kernel: str = "cubic", interpret: bool = False):
     """Batched cell-pair density, both directions per program.
 
     Shapes: pos (P, C, 3); h/m/mask (P, C). Returns six (P, C) arrays:
-    (rho_i, drho_i, nngb_i, rho_j, drho_j, nngb_j).
+    (rho_i, drho_i, nngb_i, rho_j, drho_j, nngb_j). ``interpret=True`` runs
+    the kernel in the Pallas interpreter (CPU); otherwise Mosaic compiles it.
     """
-    P, C, _ = pos_i.shape
-    f32 = pos_i.dtype
-    vec = pl.BlockSpec((1, C, 3), lambda p: (p, 0, 0))
-    sca = pl.BlockSpec((1, C), lambda p: (p, 0))
-    out_shape = [jax.ShapeDtypeStruct((P, C), f32)] * 6
-    out_specs = [sca] * 6
-    fn = functools.partial(_density_kernel, kernel=kernel)
-    return pl.pallas_call(
-        fn,
-        grid=(P,),
-        in_specs=[vec, sca, sca, sca, vec, sca, sca, sca],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(pos_i, h_i, m_i, mask_i, pos_j, h_j, m_j, mask_j)
+    return tuple(_blocked(functools.partial(_density_kernel, kernel=kernel),
+                          [h_i, m_i, mask_i, h_j, m_j, mask_j],
+                          [pos_i, pos_j], 6, 0, interpret))
 
 
 # -------------------------------------------------------------------- force
-def _force_kernel(pos_i_ref, vel_i_ref, h_i_ref, P_i_ref, rho_i_ref,
-                  om_i_ref, cs_i_ref, m_i_ref, mask_i_ref,
-                  pos_j_ref, vel_j_ref, h_j_ref, P_j_ref, rho_j_ref,
-                  om_j_ref, cs_j_ref, m_j_ref, mask_j_ref,
-                  dv_i_ref, du_i_ref, dv_j_ref, du_j_ref,
+def _force_kernel(h_i_ref, P_i_ref, rho_i_ref, om_i_ref, cs_i_ref,
+                  m_i_ref, mask_i_ref,
+                  h_j_ref, P_j_ref, rho_j_ref, om_j_ref, cs_j_ref,
+                  m_j_ref, mask_j_ref,
+                  pos_i_ref, vel_i_ref, pos_j_ref, vel_j_ref,
+                  du_i_ref, du_j_ref, dv_i_ref, dv_j_ref,
                   *, kernel: str, alpha_visc: float):
     _w_fn, dwdr_fn = get_kernel(kernel)
-    xi, xj = pos_i_ref[0], pos_j_ref[0]
-    vi, vj = vel_i_ref[0], vel_j_ref[0]
-    hi = h_i_ref[0][:, None]
-    hj = h_j_ref[0][None, :]
-    r2, r, dx, rhat = _r_and_rhat(xi, xj)
 
-    dwi = dwdr_fn(r, hi)
-    dwj = dwdr_fn(r, hj)
-    ai = (P_i_ref[0] / (om_i_ref[0] * rho_i_ref[0] ** 2))[:, None]
-    aj = (P_j_ref[0] / (om_j_ref[0] * rho_j_ref[0] ** 2))[None, :]
-    fmag = ai * dwi + aj * dwj
+    def pair(b, carry):
+        row = lambda ref: ref[pl.ds(b, 1), :]        # noqa: E731
+        dx, r2, r = _geometry(_rows(pos_i_ref, b), _rows(pos_j_ref, b))
+        rhat = [d / r for d in dx]
+        hi = _col(row(h_i_ref))
+        hj = row(h_j_ref)
+        m_i, m_j = _col(row(m_i_ref)), row(m_j_ref)
+        k_i, k_j = _col(row(mask_i_ref)), row(mask_j_ref)
+        rho_i, rho_j = _col(row(rho_i_ref)), row(rho_j_ref)
+        cs_i, cs_j = _col(row(cs_i_ref)), row(cs_j_ref)
+        coef_i = _col(row(P_i_ref) / (row(om_i_ref) * row(rho_i_ref) ** 2))
+        coef_j = row(P_j_ref) / (row(om_j_ref) * row(rho_j_ref) ** 2)
 
-    valid = (mask_i_ref[0][:, None] * mask_j_ref[0][None, :]
-             * (r < jnp.maximum(hi, hj)) * (r2 > EPS))
+        dwi = dwdr_fn(r, hi)
+        dwj = dwdr_fn(r, hj)
+        fmag = coef_i * dwi + coef_j * dwj
 
-    dvel = vi[:, None, :] - vj[None, :, :]
-    vdotrhat = jnp.sum(dvel * rhat, axis=-1)
+        valid = k_i * k_j * _flag(r < jnp.maximum(hi, hj)) * _flag(r2 > EPS)
 
-    du_visc_i = jnp.zeros(xi.shape[0], dtype=xi.dtype)
-    du_visc_j = jnp.zeros(xj.shape[0], dtype=xj.dtype)
-    if alpha_visc > 0.0:
-        # match physics.force_block's rounding path exactly (vdotr from dx,
-        # not vdotrhat*r) so the fused kernel keeps Newton's third law to
-        # the same ulp as the two-sided reference
-        vdotr = jnp.sum(dvel * dx, axis=-1)
-        hbar = 0.5 * (hi + hj)
-        rhobar = 0.5 * (rho_i_ref[0][:, None] + rho_j_ref[0][None, :])
-        csbar = 0.5 * (cs_i_ref[0][:, None] + cs_j_ref[0][None, :])
-        mu = hbar * vdotr / (r2 + 0.01 * hbar * hbar)
-        mu = jnp.where(vdotr < 0.0, mu, 0.0)
-        beta = 2.0 * alpha_visc
-        piij = (-alpha_visc * csbar * mu + beta * mu * mu) / rhobar
-        dwbar = 0.5 * (dwi + dwj)
-        fmag = fmag + piij * dwbar
-        mvisc_i = m_j_ref[0][None, :] * valid
-        du_visc_i = 0.5 * jnp.sum(
-            mvisc_i * piij * dwbar * (vdotr / r), axis=1)
-        mvisc_j = (m_i_ref[0][:, None] * valid).T
-        du_visc_j = 0.5 * jnp.sum(
-            mvisc_j * piij.T * dwbar.T * (vdotr.T / r.T), axis=1)
+        dvel = [_col(a) - c for a, c in zip(_rows(vel_i_ref, b),
+                                             _rows(vel_j_ref, b))]
+        vdotrhat = dvel[0] * rhat[0] + dvel[1] * rhat[1] + dvel[2] * rhat[2]
 
-    # Both directions contract the *same* (C, C) interaction matrix against
-    # the same r̂, in double-float, so the pair's momentum exchange is
-    # antisymmetric to the output-rounding floor (Newton's third law).
-    g = jnp.where(valid > 0, fmag, 0.0) * valid
-    dv_i_ref[0] = -_df_weighted_contract(m_j_ref[0][None, :], g, rhat, axis=1)
-    dv_j_ref[0] = _df_weighted_contract(m_i_ref[0][:, None], g, rhat, axis=0)
+        du_visc_i = du_visc_j = 0.0
+        if alpha_visc > 0.0:
+            # match physics.force_block's rounding path exactly (vdotr from
+            # dx, not vdotrhat*r) so the fused kernel keeps Newton's third
+            # law to the same ulp as the two-sided reference
+            vdotr = dvel[0] * dx[0] + dvel[1] * dx[1] + dvel[2] * dx[2]
+            hbar = 0.5 * (hi + hj)
+            rhobar = 0.5 * (rho_i + rho_j)
+            csbar = 0.5 * (cs_i + cs_j)
+            mu = hbar * vdotr / (r2 + 0.01 * hbar * hbar)
+            mu = jnp.where(vdotr < 0.0, mu, 0.0)
+            beta = 2.0 * alpha_visc
+            piij = (-alpha_visc * csbar * mu + beta * mu * mu) / rhobar
+            dwbar = 0.5 * (dwi + dwj)
+            fmag = fmag + piij * dwbar
+            heat = valid * piij * dwbar * (vdotr / r)
+            du_visc_i = 0.5 * jnp.sum(m_j * heat, axis=1, keepdims=True).T
+            du_visc_j = 0.5 * jnp.sum(m_i * heat, axis=0, keepdims=True)
 
-    # energy eq. (4): per-side cutoff r < h_side
-    valid_ui = mask_j_ref[0][None, :] * (r < hi) * (r2 > EPS)
-    coef_i = P_i_ref[0] / (om_i_ref[0] * rho_i_ref[0] ** 2)
-    du_i_ref[0] = coef_i * jnp.sum(
-        m_j_ref[0][None, :] * valid_ui * vdotrhat * dwi, axis=1) + du_visc_i
-    valid_uj = mask_i_ref[0][:, None] * (r < hj) * (r2 > EPS)
-    coef_j = P_j_ref[0] / (om_j_ref[0] * rho_j_ref[0] ** 2)
-    # v_ji·r̂_ji = (−dvel)·(−r̂) = vdotrhat
-    du_j_ref[0] = coef_j * jnp.sum(
-        m_i_ref[0][:, None] * valid_uj * vdotrhat * dwj, axis=0) + du_visc_j
+        # Both directions contract the *same* (C, C) interaction matrix
+        # against the same r̂, in double-float, so the pair's momentum
+        # exchange is antisymmetric to the output-rounding floor (Newton's
+        # third law).
+        g = jnp.where(valid > 0, fmag, 0.0) * valid
+        dv_i = _df_weighted_contract(m_j, g, rhat, axis=1)
+        dv_j = _df_weighted_contract(m_i, g, rhat, axis=0)
+        for k in range(3):
+            dv_i_ref[b, k:k + 1, :] = -dv_i[k]
+            dv_j_ref[b, k:k + 1, :] = dv_j[k]
+
+        # energy eq. (4): per-side cutoff r < h_side
+        valid_ui = k_j * _flag(r < hi) * _flag(r2 > EPS)
+        du_i_ref[pl.ds(b, 1), :] = (coef_i.T * jnp.sum(
+            m_j * valid_ui * vdotrhat * dwi, axis=1, keepdims=True).T
+            + du_visc_i)
+        valid_uj = k_i * _flag(r < hj) * _flag(r2 > EPS)
+        # v_ji·r̂_ji = (−dvel)·(−r̂) = vdotrhat
+        du_j_ref[pl.ds(b, 1), :] = (coef_j * jnp.sum(
+            m_i * valid_uj * vdotrhat * dwj, axis=0, keepdims=True)
+            + du_visc_j)
+        return carry
+
+    jax.lax.fori_loop(0, PAIRS_PER_PROGRAM, pair, 0)
 
 
 def force_pair_pallas(pos_i, vel_i, h_i, press_i, rho_i, om_i, cs_i, m_i,
                       mask_i, pos_j, vel_j, h_j, press_j, rho_j, om_j, cs_j,
                       m_j, mask_j, *, kernel: str = "cubic",
-                      alpha_visc: float = 0.0, interpret: bool = True):
+                      alpha_visc: float = 0.0, interpret: bool = False):
     """Batched cell-pair forces, both directions per program.
 
     Returns (dv_i, du_i, dv_j, du_j): (P,C,3), (P,C), (P,C,3), (P,C).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU).
     """
-    P, C, _ = pos_i.shape
-    f32 = pos_i.dtype
-    vec = pl.BlockSpec((1, C, 3), lambda p: (p, 0, 0))
-    sca = pl.BlockSpec((1, C), lambda p: (p, 0))
-    out_shape = [jax.ShapeDtypeStruct((P, C, 3), f32),
-                 jax.ShapeDtypeStruct((P, C), f32),
-                 jax.ShapeDtypeStruct((P, C, 3), f32),
-                 jax.ShapeDtypeStruct((P, C), f32)]
-    out_specs = [vec, sca, vec, sca]
     fn = functools.partial(_force_kernel, kernel=kernel,
                            alpha_visc=alpha_visc)
-    return pl.pallas_call(
-        fn,
-        grid=(P,),
-        in_specs=[vec, vec, sca, sca, sca, sca, sca, sca, sca,
-                  vec, vec, sca, sca, sca, sca, sca, sca, sca],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(pos_i, vel_i, h_i, press_i, rho_i, om_i, cs_i, m_i, mask_i,
-      pos_j, vel_j, h_j, press_j, rho_j, om_j, cs_j, m_j, mask_j)
+    du_i, du_j, dv_i, dv_j = _blocked(
+        fn, [h_i, press_i, rho_i, om_i, cs_i, m_i, mask_i,
+             h_j, press_j, rho_j, om_j, cs_j, m_j, mask_j],
+        [pos_i, vel_i, pos_j, vel_j], 2, 2, interpret)
+    return dv_i, du_i, dv_j, du_j

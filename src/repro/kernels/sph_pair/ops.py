@@ -25,7 +25,7 @@ def _gather(cells, pairs):
 
 
 def density_pairs(cells, pairs, *, kernel: str = "cubic",
-                  interpret: bool = True, pair_mask=None):
+                  interpret: bool = False, pair_mask=None):
     """All density_pair/density_self tasks → (rho, drho_dh, nngb).
 
     ``pair_mask`` (npairs,) zeroes masked pair tasks' contributions (padding
@@ -53,7 +53,7 @@ def density_pairs(cells, pairs, *, kernel: str = "cubic",
 
 def force_pairs(cells, pairs, rho, press, omega, cs, *,
                 kernel: str = "cubic", alpha_visc: float = 0.0,
-                interpret: bool = True, pair_mask=None):
+                interpret: bool = False, pair_mask=None):
     """All force_pair/force_self tasks → (dv, du)."""
     gi, gj, pos_i, pos_j = _gather(cells, pairs)
     dv_i, du_i, dv_j, du_j = force_pair_pallas(

@@ -13,8 +13,6 @@ import math
 import jax
 from jax.sharding import Mesh
 
-from ..distributed.mesh_utils import mesh_with_auto_axes
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -28,7 +26,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"before importing jax (dryrun.py does this)")
     import numpy as np
     dev_array = np.asarray(devices[:n]).reshape(shape)
-    return mesh_with_auto_axes(dev_array, axes)
+    return Mesh(dev_array, tuple(axes))
 
 
 def make_host_mesh(shape=(1, 1), axes=("data", "model")) -> Mesh:
@@ -36,4 +34,4 @@ def make_host_mesh(shape=(1, 1), axes=("data", "model")) -> Mesh:
     n = math.prod(shape)
     import numpy as np
     dev_array = np.asarray(jax.devices()[:n]).reshape(shape)
-    return mesh_with_auto_axes(dev_array, axes)
+    return Mesh(dev_array, tuple(axes))

@@ -133,8 +133,8 @@ def mesh_override(name: str, multi_pod: bool):
     import math
     import numpy as np
     import jax
-    from ..distributed.mesh_utils import mesh_with_auto_axes
+    from jax.sharding import Mesh
     shape, axes = MESH_OVERRIDES[name][multi_pod]
     n = math.prod(shape)
     dev = np.asarray(jax.devices()[:n]).reshape(shape)
-    return mesh_with_auto_axes(dev, axes)
+    return Mesh(dev, axes)

@@ -32,8 +32,9 @@ renders the per-rank cost-attribution table and the repartition advisor's
 current-vs-advised imbalance trend, either from an existing metrics log
 or from a fresh short clustered run (host transport — emulated ranks).
 
-Must run before jax is imported elsewhere: it sets ``XLA_FLAGS`` to emulate
-the requested rank count when the environment hasn't already.
+Must run before jax is imported elsewhere: under ``JAX_PLATFORMS=cpu`` it
+sets ``XLA_FLAGS`` to emulate the requested rank count when the environment
+hasn't already.
 """
 
 from __future__ import annotations
@@ -44,13 +45,19 @@ import os
 import sys
 
 
-def _ensure_devices(ranks: int) -> None:
-    if "jax" not in sys.modules:
+def _prepare_jax(ranks: int, collective: bool) -> None:
+    """Emulate ``ranks`` CPU devices for the collective transport when JAX
+    runs on the CPU (the flag must be set before JAX starts; on a chip the
+    real devices serve), then turn the persistent compilation cache on."""
+    on_cpu = os.environ.get("JAX_PLATFORMS") == "cpu"
+    if collective and on_cpu and "jax" not in sys.modules:
         flags = os.environ.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{ranks}").strip()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
 
 def _spec(args):
@@ -82,8 +89,7 @@ def main(argv=None) -> int:
                          "still computed in-program)")
     args = ap.parse_args(argv)
 
-    if args.transport == "collective":
-        _ensure_devices(args.ranks)
+    _prepare_jax(args.ranks, args.transport == "collective")
 
     from repro.sph import build_simulation
     from repro.observability import jsonify, validate_chrome_trace
@@ -248,8 +254,7 @@ def dump_main(argv=None) -> int:
                           "manifest": manifest, "ok": True}, indent=1))
         return 0
 
-    if args.transport == "collective":
-        _ensure_devices(args.ranks)
+    _prepare_jax(args.ranks, args.transport == "collective")
 
     import numpy as np
     from repro.sph import build_simulation
@@ -326,6 +331,7 @@ def advise_main(argv=None) -> int:
         from repro.observability import read_metrics_jsonl
         records = read_metrics_jsonl(args.metrics)
     else:
+        _prepare_jax(args.ranks, collective=False)
         from repro.sph import SimulationSpec, build_simulation
         spec = SimulationSpec(
             scenario=args.scenario,

@@ -50,7 +50,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.comm_planner import ppermute_rounds
@@ -619,7 +619,7 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
         return ({k: v[None] for k, v in out.items()}, cnt_out, met)
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
-                   out_specs=(P(axis), P(axis), P(axis)), check_rep=False)
+                   out_specs=(P(axis), P(axis), P(axis)), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -780,7 +780,7 @@ def build_plan_program(mesh, axis: str, *, mode: str,
         return upd, scal, flags
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P()),
-                   out_specs=(P(axis), P(axis), P(axis)), check_rep=False)
+                   out_specs=(P(axis), P(axis), P(axis)), check_vma=False)
     return jax.jit(fn)
 
 

@@ -32,7 +32,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core import CostModel, decompose_cells
@@ -223,8 +223,8 @@ def _exchange(fields: Tuple[jax.Array, ...], export_slots, export_valid,
         return tuple(halos)
 
     if halo == "ring":
-        from ..distributed.mesh_utils import axis_size, ring_perm
-        ndev = axis_size(axis)
+        from ..distributed.mesh_utils import ring_perm
+        ndev = jax.lax.axis_size(axis)
         perm = ring_perm(ndev)
         halos = [jnp.zeros((import_flat.shape[0],) + e.shape[1:], e.dtype)
                  for e in exports]

@@ -50,6 +50,7 @@ class SPHConfig:
     adapt_h: bool = False          # keep h fixed unless asked (conservation tests)
     cfl: float = 0.25
     use_pallas: bool = False
+    pallas_interpret: bool = False  # run the Pallas kernels interpreted (CPU)
 
 
 # --------------------------------------------------------------- wave passes
@@ -64,6 +65,7 @@ def _density_pass(cells: ParticleCells, pairs: PairList, cfg: SPHConfig,
     if cfg.use_pallas:
         from ..kernels.sph_pair import ops as pair_ops
         return pair_ops.density_pairs(cells, pairs, kernel=cfg.kernel,
+                                      interpret=cfg.pallas_interpret,
                                       pair_mask=pair_mask)
 
     pos_i = cells.pos[pairs.ci]                        # (P, C, 3)
@@ -100,6 +102,7 @@ def _force_pass(cells: ParticleCells, pairs: PairList, rho, press, omega, cs,
         return pair_ops.force_pairs(cells, pairs, rho, press, omega, cs,
                                     kernel=cfg.kernel,
                                     alpha_visc=cfg.alpha_visc,
+                                    interpret=cfg.pallas_interpret,
                                     pair_mask=pair_mask)
 
     gi = lambda a: a[pairs.ci]

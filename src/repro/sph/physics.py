@@ -6,8 +6,8 @@ the numerical payload of SWIFT's ``density_pair`` / ``force_pair`` tasks.
 The engine vmaps them over the cell-pair list; ``kernels/sph_pair`` provides
 the Pallas TPU version with these as the oracle.
 
-Distances use the dot-product form |xi−xj|² = |xi|² + |xj|² − 2·xi·xjᵀ so the
-inner operation is an MXU matmul. Periodic wrapping is handled *before* the
+Distances are formed from coordinate differences, exact f32 element-wise
+arithmetic on every backend. Periodic wrapping is handled *before* the
 kernel by shifting the source block by the cell-pair's periodic image offset
 (provided by the cell grid), so no per-element modulo is needed inside the
 hot loop — a TPU-friendly restructuring of the usual min-image convention.
@@ -40,12 +40,11 @@ def sound_speed(rho, u, gamma: float = GAMMA):
 
 
 def pairwise_r2(pos_i, pos_j):
-    """(Ci, Cj) squared distances via the MXU-friendly dot form."""
-    sq_i = jnp.sum(pos_i * pos_i, axis=-1)          # (Ci,)
-    sq_j = jnp.sum(pos_j * pos_j, axis=-1)          # (Cj,)
-    cross = pos_i @ pos_j.T                         # (Ci, Cj) matmul
-    r2 = sq_i[:, None] + sq_j[None, :] - 2.0 * cross
-    return jnp.maximum(r2, 0.0)
+    """(Ci, Cj) squared distances."""
+    # from differences: the |xi|²+|xj|²−2·xi·xj dot form compiles to a TPU
+    # convolution at default (bf16-pass) precision and cancels for close pairs
+    dx = pos_i[:, None, :] - pos_j[None, :, :]
+    return jnp.sum(dx * dx, axis=-1)
 
 
 class DensityResult(NamedTuple):
@@ -89,9 +88,9 @@ def force_block(pos_i, vel_i, h_i, P_i, rho_i, omega_i, cs_i,
     r < h_i for the energy equation, exactly as in the paper.
     """
     _w_fn, dwdr_fn = get_kernel(kernel)
-    r2 = pairwise_r2(pos_i, pos_j)
-    r = jnp.sqrt(r2 + EPS)
     dx = pos_i[:, None, :] - pos_j[None, :, :]       # (Ci, Cj, 3)
+    r2 = jnp.sum(dx * dx, axis=-1)
+    r = jnp.sqrt(r2 + EPS)
     rhat = dx / r[:, :, None]
 
     hi = h_i[:, None]

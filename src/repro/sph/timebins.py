@@ -451,7 +451,8 @@ def _apply_final_kick(state: TimeBinState, dv, du, rho, omega, dt_max,
     cells = _kick(cells, dv, du, active, close)
     return state._replace(cells=cells, accel=dv, dudt=du, rho=rho,
                           omega=omega,
-                          t_start=jnp.full_like(state.t_start, state.time))
+                          t_start=jnp.broadcast_to(state.time,
+                                                   state.t_start.shape))
 
 
 def _final_force_phase(state: TimeBinState, pairs: PairList, pair_mask,

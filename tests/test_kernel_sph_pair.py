@@ -148,7 +148,8 @@ def test_pallas_matches_vmap_on_padded_masked_pair_list():
     m = np.asarray(cells.mask)
     got = {}
     for use_pallas in (False, True):
-        cfg = SPHConfig(alpha_visc=0.8, use_pallas=use_pallas)
+        cfg = SPHConfig(alpha_visc=0.8, use_pallas=use_pallas,
+                        pallas_interpret=True)
         rho, drho, nngb = _density_pass(cells, sub, cfg, pair_mask=pm)
         dv, du = _force_pass(cells, sub, rho_full, press, omega, cs, cfg,
                              pair_mask=pm)
