@@ -459,23 +459,19 @@ class HostTransport(Transport):
     def exchange(self, slots: ShipSlots, fields: List[List],
                  stream: str = "substep",
                  label: Optional[str] = None) -> List[List]:
-        tr = self.tracer
-        t0 = tr.now() if tr.enabled else 0.0
         nranks = max(len(f) for f in fields)
-        arrays = [[np.array(fr) for fr in f] for f in fields]
-        self.host_bytes += 2 * sum(a.nbytes for f in arrays for a in f)
-        self.exchanges += 1
-        for (s, d), pairs in slots.edges.items():
-            for (srow, drow) in pairs:
-                for f in range(len(arrays)):
-                    arrays[f][d][drow] = arrays[f][s][srow]
-        out = [[jnp.asarray(arrays[f][r]) for r in range(nranks)]
-               for f in range(len(arrays))]
-        if tr.enabled:
-            tr.record_all(range(nranks), label or "exchange", t0,
-                          stream=stream, units=slots.total,
-                          kind="host", collective=1)
-        return out
+        with self.tracer.span(label or "exchange", ranks=range(nranks),
+                              stream=stream, units=slots.total, kind="host",
+                              collective=1):
+            arrays = [[np.array(fr) for fr in f] for f in fields]
+            self.host_bytes += 2 * sum(a.nbytes for f in arrays for a in f)
+            self.exchanges += 1
+            for (s, d), pairs in slots.edges.items():
+                for (srow, drow) in pairs:
+                    for f in range(len(arrays)):
+                        arrays[f][d][drow] = arrays[f][s][srow]
+            return [[jnp.asarray(arrays[f][r]) for r in range(nranks)]
+                    for f in range(len(arrays))]
 
     def stats(self) -> Dict[str, object]:
         return {"kind": self.kind, "exchanges": self.exchanges,

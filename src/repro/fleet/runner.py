@@ -257,13 +257,13 @@ class FleetRunner:
         if not expired:
             return
         tr = self.tracer
-        now = tr.now() if tr.enabled else 0.0
         for r in expired:
             self._count_terminal(r)
             if tr.enabled:
-                tr.record("expired", r.row, now, now,
-                          request_id=r.request_id, deadline=r.deadline,
-                          error=str(r.error))
+                # an instant marker on the request's row
+                with tr.span("expired", rank=r.row, request_id=r.request_id,
+                             deadline=r.deadline, error=str(r.error)):
+                    pass
         if self.flight_dir is not None:
             from ..observability.flight import FlightRecorder
             path = FlightRecorder().dump(
@@ -399,21 +399,19 @@ class FleetRunner:
         max_steps = max(m.req.n_steps for m in group)
         t_start = time.perf_counter()
         for n in range(max_steps):
-            t0 = tr.now() if tr.enabled else time.perf_counter()
-            if spec.dt is not None:
-                dts = jnp.full((bucket,), float(spec.dt),
-                               stacked.cells.pos.dtype)
-            else:
-                dts = cfl_fn(stacked).astype(stacked.cells.pos.dtype)
-            stacked = step_fn(stacked, pairs, dts)
-            if tr.enabled:
+            # one batched program: a task on every live request's row
+            with tr.span("fleet_step",
+                         ranks={m.req.row: {"request_id": m.req.request_id}
+                                for m in group if not m.done},
+                         signature=sig_key, step=n, batch=len(group),
+                         bucket=bucket):
+                if spec.dt is not None:
+                    dts = jnp.full((bucket,), float(spec.dt),
+                                   stacked.cells.pos.dtype)
+                else:
+                    dts = cfl_fn(stacked).astype(stacked.cells.pos.dtype)
+                stacked = step_fn(stacked, pairs, dts)
                 tr.fence(stacked.cells.pos)
-                for m in group:
-                    if not m.done:
-                        tr.record("fleet_step", m.req.row, t0,
-                                  request_id=m.req.request_id,
-                                  signature=sig_key, step=n, batch=len(group),
-                                  bucket=bucket)
             self.particle_steps += sum(m.n for m in group if not m.done)
             # lockstep host bookkeeping, mirroring engine.Simulation.run
             finish, rebin = [], False
@@ -486,13 +484,11 @@ class FleetRunner:
                 dt = float(spec.dt)
             else:
                 dt = float(cfl_timestep(m.state, spec.physics))
-            t0 = tr.now() if tr.enabled else 0.0
-            m.state = step_fn(m.state, m.pairs,
-                              jnp.asarray(dt, m.state.cells.pos.dtype))
-            if tr.enabled:
+            with tr.span("fleet_step", rank=m.req.row,
+                         request_id=m.req.request_id, sequential=1):
+                m.state = step_fn(m.state, m.pairs,
+                                  jnp.asarray(dt, m.state.cells.pos.dtype))
                 tr.fence(m.state.cells.pos)
-                tr.record("fleet_step", m.req.row, t0,
-                          request_id=m.req.request_id, sequential=1)
             m.steps_done += 1
             m.steps_since_rebin += 1
             self.particle_steps += m.n
@@ -514,22 +510,20 @@ class FleetRunner:
         tr = self.tracer
         for req in batch.requests:
             t_start = time.perf_counter()
-            t0 = tr.now() if tr.enabled else 0.0
             try:
-                sim = build_simulation(req.spec)
-                for _ in range(req.n_steps):
-                    sim.step()
-                res = self._sequential_result(
-                    sim, req, time.perf_counter() - t_start)
+                with tr.span("fleet_run", rank=req.row,
+                             request_id=req.request_id,
+                             signature=batch.signature_key,
+                             quadrant=f"{req.spec.integrator}/"
+                                      f"{req.spec.backend}"):
+                    sim = build_simulation(req.spec)
+                    for _ in range(req.n_steps):
+                        sim.step()
+                    res = self._sequential_result(
+                        sim, req, time.perf_counter() - t_start)
             except Exception as e:
                 self.queue.fail(req, e)
                 continue
-            if tr.enabled:
-                tr.record("fleet_run", req.row, t0,
-                          request_id=req.request_id,
-                          signature=batch.signature_key,
-                          quadrant=f"{req.spec.integrator}/"
-                                   f"{req.spec.backend}")
             self.sequential_runs += 1
             self.queue.complete(req, res)
 

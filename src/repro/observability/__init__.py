@@ -6,9 +6,10 @@ task, from which load imbalance, dead time and communication stalls are
 read off directly (arXiv:1606.02738 §4; first-class tooling in modern
 SWIFT, arXiv:2305.13380). This package is that loop for the XLA substrate:
 
-* :mod:`~repro.observability.tracer` — the low-overhead span tracer with
-  ``block_until_ready`` fencing (device work attributed to the phase that
-  launched it); free when disabled.
+* :mod:`~repro.observability.tracer` — the low-overhead span tracer:
+  every span is also a profiler ``TraceAnnotation``; host-scheduled
+  ladders fence (``block_until_ready``) so device work is attributed to
+  the phase that launched it; free when disabled.
 * :mod:`~repro.observability.metrics` — counters/gauges registry absorbing
   the engines' ledgers (transfer bytes, compile counts, bucket events,
   halo volume, bin-occupancy imbalance) behind one API.

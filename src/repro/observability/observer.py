@@ -41,6 +41,10 @@ from .tracer import NULL_TRACER, Tracer
 # umbrella spans cover a whole cycle/step — they time the container, not a
 # task, and must not count toward any rank's busy time
 UMBRELLA_SPANS = frozenset({"cycle", "step", "engine_step"})
+# spans that are no rank's work either: the host waiting on the device
+# (``wait``) and the observer's own per-cycle cost (``observe``). A dotted
+# sub-span (``rebin.unbin``) is part of a task already counted.
+IDLE_SPANS = frozenset({"wait", "observe"})
 
 # stats keys copied into the per-cycle record when the engine provides them
 _STAT_KEYS = ("t", "dt_max", "dt", "depth", "substeps", "force_substeps",
@@ -128,13 +132,15 @@ class RunObserver:
             dur = s.dur
             phase_wall[s.name] = phase_wall.get(s.name, 0.0) + dur
             phase_count[s.name] = phase_count.get(s.name, 0) + 1
-            busy[s.rank] = busy.get(s.rank, 0.0) + dur
+            task = s.name not in IDLE_SPANS and "." not in s.name
+            if task:
+                busy[s.rank] = busy.get(s.rank, 0.0) + dur
             collective = bool(a.get("collective"))
             wkey = (s.name, s.t0, s.t1)
             if not collective or wkey not in seen_wall:
                 dedup_wall[s.name] = dedup_wall.get(s.name, 0.0) + dur
                 seen_wall.add(wkey)
-            if not collective:
+            if task and not collective:
                 work[s.rank] = work.get(s.rank, 0.0) + dur
             units = a.get("units", a.get("pairs"))
             if units:

@@ -102,6 +102,14 @@ def _allgather_copy(loc, pack, unpack_src, unpack_rows, valid, axis: str,
     return loc[:nrows]
 
 
+def _named(body, name: str):
+    """Name a program's body: ``jax.jit`` calls the program's module
+    ``jit_<name>``, the prefix a device trace puts on every operation it
+    ran, so a trace says which program each operation belongs to."""
+    body.__name__ = body.__qualname__ = name
+    return body
+
+
 def build_permute_program(mesh, axis: str,
                           rounds: Sequence[Sequence[Tuple[int, int]]],
                           nrows: int, bucket: int, nfields: int):
@@ -120,7 +128,7 @@ def build_permute_program(mesh, axis: str,
                           nrows)[None]
             for f in fields)
 
-    fn = shard_map(body, mesh=mesh,
+    fn = shard_map(_named(body, "halo_permute"), mesh=mesh,
                    in_specs=(P(axis),) * (3 + nfields),
                    out_specs=(P(axis),) * nfields)
     return jax.jit(fn)
@@ -141,7 +149,7 @@ def build_allgather_program(mesh, axis: str, nrows: int, bucket_out: int,
                             valid[0], axis, nrows)[None]
             for f in fields)
 
-    fn = shard_map(body, mesh=mesh,
+    fn = shard_map(_named(body, "halo_allgather"), mesh=mesh,
                    in_specs=(P(axis),) * (4 + nfields),
                    out_specs=(P(axis),) * nfields)
     return jax.jit(fn)
@@ -348,7 +356,8 @@ def build_fused_substep_program(mesh, axis: str, *, mode: str,
         out["time"] = st.time
         return {k: v[None] for k, v in out.items()}, changed, met
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P()),
+    fn = shard_map(_named(body, "fused_final" if final else "fused_substep"),
+                   mesh=mesh, in_specs=(P(axis), P(axis), P()),
                    out_specs=(P(axis), P(axis), P(axis)))
     return jax.jit(fn, donate_argnums=(0,))
 
@@ -618,7 +627,8 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                "cells": met_w[None]}
         return ({k: v[None] for k, v in out.items()}, cnt_out, met)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
+    fn = shard_map(_named(body, "cycle_scan"), mesh=mesh,
+                   in_specs=(P(axis), P(axis), P(axis)),
                    out_specs=(P(axis), P(axis), P(axis)), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
@@ -779,7 +789,8 @@ def build_plan_program(mesh, axis: str, *, mode: str,
                  "hist": hist[None]}
         return upd, scal, flags
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P()),
+    fn = shard_map(_named(body, "segment_plan"), mesh=mesh,
+                   in_specs=(P(axis), P(axis), P()),
                    out_specs=(P(axis), P(axis), P(axis)), check_vma=False)
     return jax.jit(fn)
 
@@ -840,54 +851,58 @@ class CollectiveTransport(Transport):
         if self._edges is None:
             raise RuntimeError("CollectiveTransport.exchange before "
                                "prepare(edges)")
-        tr = self.tracer
-        t0 = tr.now() if tr.enabled else 0.0
         nranks = self.nranks
-        nrows = int(np.shape(fields[0][0])[0])
-        meta = tuple((tuple(np.shape(f[0])[1:]),
-                      np.dtype(jnp.asarray(f[0]).dtype).name)
-                     for f in fields)
-        stacked = [jnp.stack([jnp.asarray(fr) for fr in f]) for f in fields]
-        if self.mode == "ppermute":
-            B = self.buckets.fit(("edge", stream), slots.max_edge_slots)
-            pack, unpack, valid = pack_rounds(self.rounds, slots, nranks, B)
-            key = ("ppermute", nranks, nrows, B, self._perms_sig, meta)
-            prog = self.programs.get(key, lambda: build_permute_program(
-                self.mesh, self.axis, self.rounds, nrows, B, len(fields)))
-            outs = prog(jnp.asarray(pack), jnp.asarray(unpack),
-                        jnp.asarray(valid), *stacked)
-            bkt = B
-        else:
-            Bo = self.buckets.fit(("ag_out", stream),
-                                  slots.max_rank_exports(nranks))
-            Bi = self.buckets.fit(("ag_in", stream),
-                                  slots.max_rank_imports(nranks))
-            pack, usrc, urows, valid = pack_allgather(slots, nranks, Bo, Bi)
-            key = ("allgather", nranks, nrows, Bo, Bi, meta)
-            prog = self.programs.get(key, lambda: build_allgather_program(
-                self.mesh, self.axis, nrows, Bo, Bi, len(fields)))
-            outs = prog(jnp.asarray(pack), jnp.asarray(usrc),
-                        jnp.asarray(urows), jnp.asarray(valid), *stacked)
-            bkt = max(Bo, Bi)
-        self.exchanges += 1
-        self.shipped_rows += slots.total
-        # normalise placement: slicing a mesh-sharded output yields arrays
-        # committed to individual devices, which would make every
-        # downstream phase program recompile per device. Round-tripping
-        # through host memory (what the host transport does anyway) keeps
-        # the phase programs' compile count identical across transports.
-        # This round trip — device→host→device of every full field — is
-        # exactly the residual overhead the fused device-resident path
-        # (residency="device") removes; host_bytes measures it.
-        outs_h = [np.asarray(out) for out in outs]
-        self.host_bytes += 2 * sum(o.nbytes for o in outs_h)
-        if tr.enabled:
-            # outs_h materialisation above is the sync point: the whole
-            # collective (pack + wire + scatter) has completed by now, so
-            # the span covers the one program as a task on every rank's row
-            tr.record_all(range(nranks), label or "exchange", t0,
-                          stream=stream, mode=self.mode, bucket=bkt,
-                          units=slots.total, kind="collective", collective=1)
+        # the outs_h materialisation below is the sync point: when the span
+        # closes the whole collective (pack + wire + scatter) has
+        # completed, so it covers the one program as a task on every
+        # rank's row
+        with self.tracer.span(label or "exchange", ranks=range(nranks),
+                              stream=stream, mode=self.mode,
+                              units=slots.total, kind="collective",
+                              collective=1) as sp:
+            nrows = int(np.shape(fields[0][0])[0])
+            meta = tuple((tuple(np.shape(f[0])[1:]),
+                          np.dtype(jnp.asarray(f[0]).dtype).name)
+                         for f in fields)
+            stacked = [jnp.stack([jnp.asarray(fr) for fr in f])
+                       for f in fields]
+            if self.mode == "ppermute":
+                B = self.buckets.fit(("edge", stream), slots.max_edge_slots)
+                pack, unpack, valid = pack_rounds(self.rounds, slots, nranks,
+                                                  B)
+                key = ("ppermute", nranks, nrows, B, self._perms_sig, meta)
+                prog = self.programs.get(key, lambda: build_permute_program(
+                    self.mesh, self.axis, self.rounds, nrows, B,
+                    len(fields)))
+                outs = prog(jnp.asarray(pack), jnp.asarray(unpack),
+                            jnp.asarray(valid), *stacked)
+                sp.set(bucket=B)
+            else:
+                Bo = self.buckets.fit(("ag_out", stream),
+                                      slots.max_rank_exports(nranks))
+                Bi = self.buckets.fit(("ag_in", stream),
+                                      slots.max_rank_imports(nranks))
+                pack, usrc, urows, valid = pack_allgather(slots, nranks, Bo,
+                                                          Bi)
+                key = ("allgather", nranks, nrows, Bo, Bi, meta)
+                prog = self.programs.get(key, lambda: build_allgather_program(
+                    self.mesh, self.axis, nrows, Bo, Bi, len(fields)))
+                outs = prog(jnp.asarray(pack), jnp.asarray(usrc),
+                            jnp.asarray(urows), jnp.asarray(valid), *stacked)
+                sp.set(bucket=max(Bo, Bi))
+            self.exchanges += 1
+            self.shipped_rows += slots.total
+            # normalise placement: slicing a mesh-sharded output yields
+            # arrays committed to individual devices, which would make every
+            # downstream phase program recompile per device. Round-tripping
+            # through host memory (what the host transport does anyway)
+            # keeps the phase programs' compile count identical across
+            # transports. This round trip — device→host→device of every
+            # full field — is exactly the residual overhead the fused
+            # device-resident path (residency="device") removes;
+            # host_bytes measures it.
+            outs_h = [np.asarray(out) for out in outs]
+            self.host_bytes += 2 * sum(o.nbytes for o in outs_h)
         return [[jnp.asarray(o[r]) for r in range(nranks)] for o in outs_h]
 
     def stats(self) -> Dict[str, object]:

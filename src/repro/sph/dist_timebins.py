@@ -583,29 +583,27 @@ class DistTimeBinSimulation(TimeBinSimulation):
 
     def _cycle_prologue(self) -> Dict[str, object]:
         """Plan the cycle and open it on the global mirror (host side)."""
-        tr = self.tracer
-        t0 = tr.now() if tr.enabled else 0.0
-        dt_max_c, depth = self._plan_cycle()
-        nsub = 1 << depth
-        nreal = int(np.asarray(self.state.cells.mask).sum())
-        bins_host = np.asarray(self.state.bins)
-        mask_host = np.asarray(self.state.cells.mask)
-        m_h = np.asarray(self.state.cells.mass * self.state.cells.mask)
-        # fixed-shape tree fold (timebins.mass_weighted_mean_u): the same
-        # reduction order the device plan program reproduces, so the
-        # host- and device-derived schedules agree bit for bit
-        u_floor = float(mass_weighted_mean_u(m_h,
-                                             np.asarray(self.state.cells.u)))
-        hist = np.bincount(bins_host[mask_host > 0], minlength=depth + 1)
-        # opening half-kick on the global mirror, then scatter to ranks
-        self.state = self._jit_start(self.state, jnp.float32(dt_max_c))
-        plan = self._get_plan()
-        if tr.enabled:
-            tr.fence(self.state.cells.pos)
-            # planning runs once on the host for everyone — one task on
-            # every rank's row, like SWIFT's tree-build
-            tr.record_all(range(plan.nranks), "plan", t0, units=nreal,
-                          collective=1)
+        # planning runs once on the host for everyone — one task on every
+        # rank's row, like SWIFT's tree-build. No fence: the opening kick
+        # is only dispatched here; the host-scheduled bodies wait on it
+        with self.tracer.span("plan", ranks=range(self.nranks),
+                              collective=1) as sp:
+            dt_max_c, depth = self._plan_cycle()
+            nsub = 1 << depth
+            nreal = int(np.asarray(self.state.cells.mask).sum())
+            sp.set(units=nreal)
+            bins_host = np.asarray(self.state.bins)
+            mask_host = np.asarray(self.state.cells.mask)
+            m_h = np.asarray(self.state.cells.mass * self.state.cells.mask)
+            # fixed-shape tree fold (timebins.mass_weighted_mean_u): the
+            # same reduction order the device plan program reproduces, so
+            # the host- and device-derived schedules agree bit for bit
+            u_floor = float(mass_weighted_mean_u(
+                m_h, np.asarray(self.state.cells.u)))
+            hist = np.bincount(bins_host[mask_host > 0], minlength=depth + 1)
+            # opening half-kick on the global mirror, then scatter to ranks
+            self.state = self._jit_start(self.state, jnp.float32(dt_max_c))
+            plan = self._get_plan()
         return {"dt_max_c": dt_max_c, "depth": depth, "nsub": nsub,
                 "dt_min": dt_max_c / nsub, "nreal": nreal,
                 "bins_host": bins_host, "mask_host": mask_host,
@@ -695,10 +693,10 @@ class DistTimeBinSimulation(TimeBinSimulation):
         mask_host, u_floor = ctx["mask_host"], ctx["u_floor"]
         nreal = ctx["nreal"]
         tr = self.tracer
-        t0 = tr.now() if tr.enabled else 0.0
-        states = self._scatter_state(plan)
-        if tr.enabled:
-            tr.record_all(range(plan.nranks), "scatter", t0, collective=1)
+        with tr.span("scatter", ranks=range(plan.nranks), collective=1):
+            # the prologue's opening kick lands here in the task plot
+            tr.fence(self.state.cells.pos)
+            states = self._scatter_state(plan)
 
         updates = 0
         pair_tasks = 0
@@ -950,10 +948,8 @@ class DistTimeBinSimulation(TimeBinSimulation):
             attribute_cells(self._select_rank_pairs(plan, None)[0],
                             list(plan.cut) if plan.cut else [], 1.0)
 
-        tg = tr.now() if tr.enabled else 0.0
-        self._gather_state(plan, states)
-        if tr.enabled:
-            tr.record_all(range(plan.nranks), "gather", tg, collective=1)
+        with tr.span("gather", ranks=range(plan.nranks), collective=1):
+            self._gather_state(plan, states)
         if dm_on:
             self._mirror_metrics_finish(plan, met_counts, met_values)
             self.device_cell_work_last = {
@@ -1145,11 +1141,11 @@ class DistTimeBinSimulation(TimeBinSimulation):
         mask_host, u_floor = ctx["mask_host"], ctx["u_floor"]
         nreal = ctx["nreal"]
         tr = self.tracer
-        t0 = tr.now() if tr.enabled else 0.0
-        res = self._scatter_resident(plan)
-        if tr.enabled:
+        with tr.span("scatter", ranks=range(plan.nranks), collective=1):
+            # the prologue's opening kick lands here in the task plot
+            tr.fence(self.state.cells.pos)
+            res = self._scatter_resident(plan)
             tr.fence(res["pos"])
-            tr.record_all(range(plan.nranks), "scatter", t0, collective=1)
 
         updates = 0
         pair_tasks = 0
@@ -1242,20 +1238,20 @@ class DistTimeBinSimulation(TimeBinSimulation):
                        "dt_max": jnp.float32(dt_max_c),
                        "depth": jnp.int32(depth),
                        "u_floor": jnp.float32(u_floor)}
-            ts = tr.now() if tr.enabled else 0.0
-            changed = run_fused(tables, sig, scalars, final=False)
+            f_attrs = {}
             if tr.enabled:
-                # the fused program is one task on every rank's row; fence
-                # so its device time lands inside this span, not the next
-                tr.fence(res["pos"])
-                tr.record_all(
-                    range(plan.nranks), "fused_substep", ts,
+                f_attrs = dict(
                     level=level, bucket=sig[3],
                     units=int((active_cells[self._ci]
                                | active_cells[self._cj]).sum()),
                     slots=slots.total,
-                    active_frac=float(active_p.sum()) / max(nreal, 1),
-                    collective=1)
+                    active_frac=float(active_p.sum()) / max(nreal, 1))
+            # the fused program is one task on every rank's row; fence so
+            # its device time lands inside this span, not the next
+            with tr.span("fused_substep", ranks=range(plan.nranks),
+                         collective=1, **f_attrs):
+                changed = run_fused(tables, sig, scalars, final=False)
+                tr.fence(res["pos"])
             changed_h = np.asarray(changed)
             self.transfers.record("flags", changed_h.nbytes, boundary=False)
             if changed_h.any():
@@ -1296,13 +1292,11 @@ class DistTimeBinSimulation(TimeBinSimulation):
                    "dt_max": jnp.float32(dt_max_c),
                    "depth": jnp.int32(depth),
                    "u_floor": jnp.float32(u_floor)}
-        ts = tr.now() if tr.enabled else 0.0
-        run_fused(tables, sig, scalars, final=True)
-        if tr.enabled:
+        with tr.span("fused_final", ranks=range(plan.nranks), level=0,
+                     bucket=sig[3], units=len(self._ci), slots=slots.total,
+                     active_frac=1.0, collective=1):
+            run_fused(tables, sig, scalars, final=True)
             tr.fence(res["pos"])
-            tr.record_all(range(plan.nranks), "fused_final", ts,
-                          level=0, bucket=sig[3], units=len(self._ci),
-                          slots=slots.total, active_frac=1.0, collective=1)
         updates += nreal
         pair_tasks += len(self._ci)
 
@@ -1314,10 +1308,8 @@ class DistTimeBinSimulation(TimeBinSimulation):
             self.device_metrics_last = None
             self.device_cell_work_last = None
 
-        tg = tr.now() if tr.enabled else 0.0
-        self._gather_resident(plan, res)
-        if tr.enabled:
-            tr.record_all(range(plan.nranks), "gather", tg, collective=1)
+        with tr.span("gather", ranks=range(plan.nranks), collective=1):
+            self._gather_resident(plan, res)
         return {"updates": updates, "pair_tasks": pair_tasks,
                 "force_substeps": force_substeps,
                 "cycle_exported": cycle_exported,
@@ -1474,27 +1466,99 @@ class DistTimeBinSimulation(TimeBinSimulation):
         and the segment replays on the host-scheduled ladder —
         bitwise-recoverable by the residency conformance contract.
         """
+        tr = self.tracer
+        ranks = range(self.nranks)
         K_cycles = self.segment_cycles
         stash = self.state
+        # host phases tile the cycle, each one task on every rank's row;
+        # none fences: ``wait`` is the one place the host blocks on the
+        # device, at the boundary pull it needs anyway
         ctx = self._cycle_prologue()
         plan: RankPlan = ctx["plan"]
         nsub_static = ctx["nsub"]
-        res = self._scatter_resident(plan)
-        tables, consts, sig = self._segment_tables(plan)
-        cyc_prog = self._cycle_scan_program(sig, nsub_static)
-        self.program_keys.add(("cycle_scan", ctx["depth"], sig[3]))
-        plan_prog = self._plan_program(sig, nsub_static) \
-            if K_cycles > 1 else None
-        if plan_prog is not None:
-            self.program_keys.add(("segment_plan", ctx["depth"], sig[3]))
-        scalars = self._place_scalars({
-            "dt_max": np.full(plan.nranks, ctx["dt_max_c"], np.float32),
-            "depth": np.full(plan.nranks, ctx["depth"], np.int32),
-            "nsub": np.full(plan.nranks, ctx["nsub"], np.int32),
-            "u_floor": np.full(plan.nranks, ctx["u_floor"], np.float32)})
+        with tr.span("scatter", ranks=ranks, collective=1):
+            res = self._scatter_resident(plan)
+        with tr.span("tables", ranks=ranks, collective=1):
+            tables, consts, sig = self._segment_tables(plan)
+            cyc_prog = self._cycle_scan_program(sig, nsub_static)
+            self.program_keys.add(("cycle_scan", ctx["depth"], sig[3]))
+            plan_prog = self._plan_program(sig, nsub_static) \
+                if K_cycles > 1 else None
+            if plan_prog is not None:
+                self.program_keys.add(("segment_plan", ctx["depth"], sig[3]))
+            scalars = self._place_scalars({
+                "dt_max": np.full(plan.nranks, ctx["dt_max_c"], np.float32),
+                "depth": np.full(plan.nranks, ctx["depth"], np.int32),
+                "nsub": np.full(plan.nranks, ctx["nsub"], np.int32),
+                "u_floor": np.full(plan.nranks, ctx["u_floor"],
+                                   np.float32)})
+        with tr.span("launch", ranks=ranks, collective=1):
+            per_cnt, per_met, per_scal, per_flags = self._launch_segment(
+                res, tables, consts, scalars, cyc_prog, plan_prog)
+        # ---- ONE boundary pull: every cycle's counters, metrics rows,
+        # device-planned scalars and sentinel flags
+        with tr.span("wait", ranks=ranks, collective=1):
+            pulled_cnt = [{k: np.asarray(v) for k, v in c.items()}
+                          for c in per_cnt]
+            pulled_met = [(np.asarray(m["counts"]), np.asarray(m["values"]),
+                           np.asarray(m["cells"])) for m in per_met]
+            pulled_scal = [{k: np.asarray(v) for k, v in s.items()}
+                           for s in per_scal]
+            pulled_flags = [{k: np.asarray(v) for k, v in f.items()}
+                            for f in per_flags]
+            nbytes = sum(a.nbytes for grp in pulled_cnt
+                         for a in grp.values())
+            nbytes += sum(c.nbytes + v.nbytes + w.nbytes
+                          for c, v, w in pulled_met)
+            nbytes += sum(a.nbytes for grp in pulled_scal
+                          for a in grp.values())
+            nbytes += sum(a.nbytes for grp in pulled_flags
+                          for a in grp.values())
+            self.transfers.record("segment_stats", nbytes, boundary=True)
+            self.segments += 1
+
+            mci = dmetrics.COUNT_INDEX
+            sentinels = sum(
+                int(c[:, mci["flag_nan"]].sum() + c[:, mci["flag_inf"]].sum()
+                    + c[:, mci["flag_neg_rho"]].sum())
+                for c, _, _ in pulled_met)
+            crossed = sum(int(f["crossed"][0]) for f in pulled_flags)
+            over = sum(int(f["capacity"][0]) for f in pulled_flags)
+        if sentinels or crossed or over:
+            # sentinel trip: discard the segment (the flagged program's
+            # interior state is garbage by contract), restore the
+            # pre-segment state and replay host-scheduled — bitwise
+            # identical to the reference ladder, NaNs included
+            self.segment_aborts += 1
+            self.state = stash
+            return self._replay_segment_host(K_cycles)
+
+        with tr.span("gather", ranks=ranks, collective=1):
+            self._gather_resident(plan, res)
+            # the segment's device buffers are released here: left to the
+            # frame's teardown, outside every phase, they cost ~14 ms a
+            # cycle on four chips
+            del res, tables, consts, scalars, per_cnt, per_met, per_scal, \
+                per_flags
+        with tr.span("repartition", ranks=ranks, collective=1):
+            depth_last = int(pulled_scal[-1]["depth"][0])
+            self._maybe_repartition(np.asarray(self.state.bins),
+                                    np.asarray(self.state.cells.mask),
+                                    depth_last)
+        if self.rebin_each_cycle:
+            with tr.span("rebin", units=ctx["nreal"]):
+                self._rebin_state()
+        with tr.span("stats", ranks=ranks, collective=1):
+            return self._segment_stats(ctx, plan, pulled_cnt, pulled_met,
+                                       pulled_scal, pulled_flags)
+
+    def _launch_segment(self, res: ResidentBuffers, tables, consts, scalars,
+                        cyc_prog, plan_prog) -> Tuple[List, List, List, List]:
+        """Dispatch the segment's programs, device to device: each cycle's
+        counters, metrics rows, scalars and flags, still on the device."""
         names = self._CELL_FIELDS + self._AUX_FIELDS + ("time",)
         per_cnt, per_met, per_scal, per_flags = [], [], [scalars], []
-        for j in range(K_cycles):
+        for j in range(self.segment_cycles):
             if j > 0:
                 state_in = {nm: res[nm] for nm in names}
                 upd, scalars, flags = plan_prog(state_in, tables, consts)
@@ -1506,50 +1570,15 @@ class DistTimeBinSimulation(TimeBinSimulation):
             res.update(out_state)
             per_cnt.append(cnt)
             per_met.append(met)
-        # ---- ONE boundary pull: every cycle's counters, metrics rows,
-        # device-planned scalars and sentinel flags
-        pulled_cnt = [{k: np.asarray(v) for k, v in c.items()}
-                      for c in per_cnt]
-        pulled_met = [(np.asarray(m["counts"]), np.asarray(m["values"]),
-                       np.asarray(m["cells"])) for m in per_met]
-        pulled_scal = [{k: np.asarray(v) for k, v in s.items()}
-                       for s in per_scal]
-        pulled_flags = [{k: np.asarray(v) for k, v in f.items()}
-                        for f in per_flags]
-        nbytes = sum(a.nbytes for grp in pulled_cnt for a in grp.values())
-        nbytes += sum(c.nbytes + v.nbytes + w.nbytes
-                      for c, v, w in pulled_met)
-        nbytes += sum(a.nbytes for grp in pulled_scal for a in grp.values())
-        nbytes += sum(a.nbytes for grp in pulled_flags
-                      for a in grp.values())
-        self.transfers.record("segment_stats", nbytes, boundary=True)
-        self.segments += 1
+        return per_cnt, per_met, per_scal, per_flags
 
-        mci = dmetrics.COUNT_INDEX
-        sentinels = sum(
-            int(c[:, mci["flag_nan"]].sum() + c[:, mci["flag_inf"]].sum()
-                + c[:, mci["flag_neg_rho"]].sum())
-            for c, _, _ in pulled_met)
-        crossed = sum(int(f["crossed"][0]) for f in pulled_flags)
-        over = sum(int(f["capacity"][0]) for f in pulled_flags)
-        if sentinels or crossed or over:
-            # sentinel trip: discard the segment (the flagged program's
-            # interior state is garbage by contract), restore the
-            # pre-segment state and replay host-scheduled — bitwise
-            # identical to the reference ladder, NaNs included
-            self.segment_aborts += 1
-            self.state = stash
-            return self._replay_segment_host(K_cycles)
-
-        self._gather_resident(plan, res)
-        depth_last = int(pulled_scal[-1]["depth"][0])
-        self._maybe_repartition(np.asarray(self.state.bins),
-                                np.asarray(self.state.cells.mask),
-                                depth_last)
-        if self.rebin_each_cycle:
-            with self.tracer.span("rebin", units=ctx["nreal"]):
-                self._rebin_state()
-
+    def _segment_stats(self, ctx: Dict[str, object], plan: RankPlan,
+                       pulled_cnt: List[Dict], pulled_met: List[Tuple],
+                       pulled_scal: List[Dict], pulled_flags: List[Dict]
+                       ) -> List[Dict]:
+        """Per-cycle stats of a finished segment from its boundary pull,
+        and the engine's counters advanced by them."""
+        K_cycles = self.segment_cycles
         nreal = ctx["nreal"]
         cut_slots = plan.cut_slots
         self.halo_log = []      # per-sub-step log is host-side only

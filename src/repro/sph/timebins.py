@@ -502,6 +502,8 @@ class TimeBinSimulation:
     sub-steps and cycles.
     """
 
+    tracer = NULL_TRACER        # rebound when observe=True
+
     def __init__(self, pos, vel, mass, u, h, *, box: float,
                  cfg: SPHConfig = SPHConfig(),
                  dt_max: Optional[float] = None,
@@ -550,7 +552,6 @@ class TimeBinSimulation:
         self.particle_updates = 0       # force evaluations actually received
         self.global_equiv_updates = 0   # what global-dt would have performed
         self.substeps = 0
-        self.tracer = NULL_TRACER       # rebound when observe=True
         self.cycle_index = 0
         # device-metrics carry (single rank): rows built from the host
         # scalars the ladder already pulls (nact, nlive) — no extra sync
@@ -564,14 +565,18 @@ class TimeBinSimulation:
 
     # ------------------------------------------------------------- plumbing
     def _rebin(self, pos, vel, mass, u, h):
-        self.cells, self.perm = bin_particles(self.spec, pos, vel, mass, u, h)
-        if self.cells.mass.shape[1] != self.spec.capacity:
-            object.__setattr__(self.spec, "capacity",
-                               self.cells.mass.shape[1])
-        self.pairs = build_pair_list(self.spec)
-        self._ci = np.asarray(self.pairs.ci)
-        self._cj = np.asarray(self.pairs.cj)
-        self._shift = np.asarray(self.pairs.shift)
+        tr = self.tracer
+        with tr.span("rebin.bin_particles"):
+            self.cells, self.perm = bin_particles(self.spec, pos, vel, mass,
+                                                  u, h)
+            if self.cells.mass.shape[1] != self.spec.capacity:
+                object.__setattr__(self.spec, "capacity",
+                                   self.cells.mass.shape[1])
+        with tr.span("rebin.pair_list"):
+            self.pairs = build_pair_list(self.spec)
+            self._ci = np.asarray(self.pairs.ci)
+            self._cj = np.asarray(self.pairs.cj)
+            self._shift = np.asarray(self.pairs.shift)
 
     def _flatten_aux(self, arr, fill) -> np.ndarray:
         valid = self.perm >= 0
@@ -583,36 +588,42 @@ class TimeBinSimulation:
 
     def _rebin_state(self):
         """Re-bin particles into cells, carrying the full multi-dt state
-        (no extra force pass: accel/rho/omega/bins ride along)."""
+        (no extra force pass: accel/rho/omega/bins ride along). Its phases
+        are the ``rebin.*`` spans: pull and flatten, bin, pair list,
+        upload."""
+        tr = self.tracer
         st = self.state
-        flat = unbin(st.cells, self.perm, self.n)
-        aux = {
-            "accel": self._flatten_aux(st.accel, 0.0),
-            "dudt": self._flatten_aux(st.dudt, 0.0),
-            "rho": self._flatten_aux(st.rho, 1.0),
-            "omega": self._flatten_aux(st.omega, 1.0),
-            "bins": self._flatten_aux(st.bins, 0),
-            "t_start": self._flatten_aux(st.t_start, 0.0),
-        }
+        with tr.span("rebin.unbin"):
+            flat = unbin(st.cells, self.perm, self.n)
+            aux = {
+                "accel": self._flatten_aux(st.accel, 0.0),
+                "dudt": self._flatten_aux(st.dudt, 0.0),
+                "rho": self._flatten_aux(st.rho, 1.0),
+                "omega": self._flatten_aux(st.omega, 1.0),
+                "bins": self._flatten_aux(st.bins, 0),
+                "t_start": self._flatten_aux(st.t_start, 0.0),
+            }
         self._rebin(flat["pos"], flat["vel"], flat["mass"], flat["u"],
                     flat["h"])
-        valid = self.perm >= 0
-        idx = self.perm[valid]
+        with tr.span("rebin.upload"):
+            valid = self.perm >= 0
+            idx = self.perm[valid]
 
-        def take(a, fill):
-            out = np.full(self.perm.shape + a.shape[1:], fill, dtype=a.dtype)
-            out[valid] = a[idx]
-            return out
+            def take(a, fill):
+                out = np.full(self.perm.shape + a.shape[1:], fill,
+                              dtype=a.dtype)
+                out[valid] = a[idx]
+                return out
 
-        self.state = TimeBinState(
-            cells=self.cells,
-            accel=jnp.asarray(take(aux["accel"], 0.0)),
-            dudt=jnp.asarray(take(aux["dudt"], 0.0)),
-            rho=jnp.asarray(take(aux["rho"], 1.0)),
-            omega=jnp.asarray(take(aux["omega"], 1.0)),
-            bins=jnp.asarray(take(aux["bins"], 0)),
-            t_start=jnp.asarray(take(aux["t_start"], 0.0)),
-            time=st.time)
+            self.state = TimeBinState(
+                cells=self.cells,
+                accel=jnp.asarray(take(aux["accel"], 0.0)),
+                dudt=jnp.asarray(take(aux["dudt"], 0.0)),
+                rho=jnp.asarray(take(aux["rho"], 1.0)),
+                omega=jnp.asarray(take(aux["omega"], 1.0)),
+                bins=jnp.asarray(take(aux["bins"], 0)),
+                t_start=jnp.asarray(take(aux["t_start"], 0.0)),
+                time=st.time)
 
     def _pair_subset(self, active_cells: np.ndarray
                      ) -> Tuple[PairList, jax.Array, int]:

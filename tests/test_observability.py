@@ -48,26 +48,93 @@ def test_span_records_attrs_and_ctx():
     assert tr.ranks() == [0, 1]
 
 
-def test_record_all_duplicates_collective_interval():
+def test_span_ranks_duplicates_collective_interval():
     tr = Tracer()
-    t0 = tr.now()
-    tr.record_all(range(3), "exchange1", t0, units=10, collective=1)
+    with tr.span("exchange1", ranks=range(3), units=10, collective=1) as sp:
+        sp.set(bucket=8)                          # known only inside
     spans = tr.spans
     assert [s.rank for s in spans] == [0, 1, 2]
     assert len({(s.t0, s.t1) for s in spans}) == 1   # same interval per rank
-    assert all(s.attrs["collective"] == 1 for s in spans)
+    assert all(s.attrs["collective"] == 1 and s.attrs["bucket"] == 8
+               for s in spans)
+    # a mapping of rank to attrs adds them on that rank's row alone
+    tr.clear()
+    with tr.span("fleet_step", ranks={4: {"request_id": "a"},
+                                      7: {"request_id": "b"}}, step=2):
+        pass
+    assert [(s.rank, s.attrs) for s in tr.spans] == [
+        (4, {"step": 2, "request_id": "a"}),
+        (7, {"step": 2, "request_id": "b"})]
+    assert len({(s.t0, s.t1) for s in tr.spans}) == 1
 
 
 def test_null_tracer_is_inert_but_timed_measures():
     assert not NULL_TRACER.enabled
     with NULL_TRACER.span("x", rank=0):
         pass
-    NULL_TRACER.record_all(range(4), "y", 0.0)
+    with NULL_TRACER.span("y", ranks=range(4)) as sp:
+        sp.set(bucket=8)
     assert NULL_TRACER.fence("payload") == "payload"
     with NULL_TRACER.timed("wall") as sp:
         time.sleep(0.001)
     assert sp.elapsed >= 0.001                    # "wall" stats still work
     assert NULL_TRACER.spans == []
+
+
+def test_enabled_span_opens_engine_annotation(monkeypatch):
+    """Every enabled span/timed is a ``TraceAnnotation("engine:<name>")``
+    nested as the spans are (one per span, whatever its ``ranks``); the
+    null tracer opens none."""
+    from repro.observability import tracer as tracer_mod
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(self.name)
+
+        def __exit__(self, *exc):
+            opened.append("/" + self.name)
+
+    monkeypatch.setattr(tracer_mod, "_TraceAnnotation", Annotation)
+    tr = Tracer()
+    with tr.timed("cycle"):
+        with tr.span("plan", ranks=range(4)):
+            pass
+    assert opened == ["engine:cycle", "engine:plan", "/engine:plan",
+                      "/engine:cycle"]
+    opened.clear()
+    with NULL_TRACER.timed("cycle"):
+        with NULL_TRACER.span("plan", ranks=range(4)):
+            pass
+    assert opened == []
+
+
+def test_spans_reach_the_profilers_trace(tmp_path):
+    """On a real profile the spans are host events of the profiler's own
+    trace, on its clock, child inside parent."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("rebin", ranks=range(2)):
+            with tr.span("rebin.unbin"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {e.name: (e.start_ns, e.end_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for e in line.events
+              if e.name.startswith("engine:")}
+    assert set(events) == {"engine:rebin", "engine:rebin.unbin"}
+    (a, b), (c, d) = events["engine:rebin"], events["engine:rebin.unbin"]
+    assert a <= c <= d <= b
 
 
 def test_enabled_span_overhead_under_5us():
@@ -93,7 +160,8 @@ def _toy_tracer() -> Tracer:
             pass
         with tr.span("force", rank=r):
             pass
-    tr.record_all(range(2), "exchange1", tr.now(), collective=1)
+    with tr.span("exchange1", ranks=range(2), collective=1):
+        pass
     return tr
 
 
@@ -186,6 +254,184 @@ def test_tracing_mints_no_extra_programs():
     assert traced.engine.probe.total_compiles() \
         == plain.engine.probe.total_compiles()
     assert traced.engine.probe.counts() == plain.engine.probe.counts()
+
+
+# ---------------------------------- device-scheduled cycle: host phases
+# the spans that tile a device-scheduled cycle, in order, and the rebin's
+DEVICE_PHASES = ("plan", "scatter", "tables", "launch", "wait", "gather",
+                 "repartition", "rebin", "stats")
+REBIN_PARTS = ("rebin.unbin", "rebin.bin_particles", "rebin.pair_list",
+               "rebin.upload")
+
+
+def _device_schedule_spec(ranks: int) -> SimulationSpec:
+    return _timebin_spec("sedov", backend="distributed", ranks=ranks,
+                         transport="collective", residency="device",
+                         schedule="device", segment_cycles=1, max_depth=2,
+                         observe=True)
+
+
+_FOUR_RANK_SPANS = """
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path[:0] = [{src!r}, {tests!r}]
+import jax
+jax.config.update("jax_default_matmul_precision", "float32")
+from repro.sph import build_simulation
+from test_observability import _device_schedule_spec
+sim = build_simulation(_device_schedule_spec(4))
+for _ in range(3):
+    sim.step()
+print(json.dumps([[s.name, s.rank, s.t0, s.t1]
+                  for s in sim.observer.tracer.spans]))
+"""
+
+
+@pytest.fixture(scope="module")
+def device_schedule_run():
+    """A traced one-rank device-scheduled run of three cycles, with every
+    ``jax.block_until_ready`` made inside ``_run_segment`` counted."""
+    import jax
+    sim = build_simulation(_device_schedule_spec(1))
+    eng = sim.engine
+    inside, fences = [], []
+    real_fence, real_segment = jax.block_until_ready, eng._run_segment
+
+    def fence(x):
+        if inside:
+            fences.append(x)
+        return real_fence(x)
+
+    def run_segment():
+        inside.append(1)
+        try:
+            return real_segment()
+        finally:
+            inside.pop()
+
+    jax.block_until_ready = fence
+    eng._run_segment = run_segment
+    try:
+        for _ in range(3):
+            sim.step()
+    finally:
+        jax.block_until_ready = real_fence
+        del eng._run_segment
+    return sim, fences
+
+
+def _spans_of(sim_or_ranks):
+    """(name, rank, t0, t1) of a run's spans: the one-rank run in this
+    process, or a four-rank run on four emulated devices."""
+    if not isinstance(sim_or_ranks, int):
+        return [(s.name, s.rank, s.t0, s.t1)
+                for s in sim_or_ranks.observer.tracer.spans]
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    script = _FOUR_RANK_SPANS.format(src=os.path.join(here, "..", "src"),
+                                     tests=here)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return [tuple(x) for x in json.loads(proc.stdout.splitlines()[-1])]
+
+
+@pytest.mark.parametrize("ranks", [1, 4])
+def test_device_schedule_phases_tile_the_cycle(ranks, device_schedule_run):
+    """Each cycle holds every host phase once, in order, each on every
+    rank's row (the rebin and its parts on rank 0's), and the phases
+    leave under 5% of the cycle unaccounted for."""
+    spans = _spans_of(device_schedule_run[0] if ranks == 1 else ranks)
+    cycles = [(t0, t1) for name, _, t0, t1 in spans if name == "cycle"]
+    assert len(cycles) == 3
+    self_s = total_s = 0.0
+    for i, (c0, c1) in enumerate(cycles):
+        rows = {}
+        for name, rank, t0, t1 in spans:
+            if name != "cycle" and c0 <= t0 and t1 <= c1:
+                rows.setdefault((name, t0, t1), set()).add(rank)
+        order = [k[0] for k in sorted(rows, key=lambda k: k[1])]
+        assert [n for n in order if "." not in n] == list(DEVICE_PHASES)
+        assert sorted(n for n in order if "." in n) == sorted(REBIN_PARTS)
+        for (name, _, _), got in rows.items():
+            want = {0} if name.startswith("rebin") else set(range(ranks))
+            assert got == want, name
+        if i:               # the first cycle's launch is mostly compile
+            self_s += (c1 - c0) - sum(t1 - t0 for (n, t0, t1) in rows
+                                      if "." not in n)
+            total_s += c1 - c0
+    assert 0.0 <= self_s < 0.05 * total_s, (self_s, total_s)
+
+
+def test_device_schedule_never_fences(device_schedule_run):
+    """With the tracer on, nothing inside ``_run_segment`` calls
+    ``jax.block_until_ready``; the host-scheduled ladder still fences
+    (so the counter would see one)."""
+    import jax
+    sim, fences = device_schedule_run
+    assert sim.observer.tracer.enabled
+    assert sim.engine.segments == 3 and sim.engine.segment_aborts == 0
+    assert fences == []
+    host = build_simulation(_timebin_spec(
+        "sedov", backend="distributed", ranks=1, transport="collective",
+        residency="device", schedule="host", max_depth=2, observe=True))
+    real_fence, seen = jax.block_until_ready, []
+
+    def fence(x):
+        seen.append(x)
+        return real_fence(x)
+
+    jax.block_until_ready = fence
+    try:
+        host.step()
+    finally:
+        jax.block_until_ready = real_fence
+    assert seen
+
+
+def test_observer_counts_waiting_as_idle():
+    """``wait`` (the host blocked on the device), ``observe`` and the
+    dotted parts of a task are no rank's busy work; the phases are."""
+    from repro.observability import RunObserver, Span
+    obs = RunObserver(ObserveSpec(enabled=True, metrics=False,
+                                  device_metrics=False))
+    obs.tracer.spans.extend([
+        Span("cycle", 0, 0.0, 1.0, None),
+        Span("plan", 0, 0.0, 0.1, {"collective": 1}),
+        Span("plan", 1, 0.0, 0.1, {"collective": 1}),
+        Span("wait", 0, 0.1, 0.6, {"collective": 1}),
+        Span("wait", 1, 0.1, 0.6, {"collective": 1}),
+        Span("rebin", 0, 0.6, 0.9, None),
+        Span("rebin.unbin", 0, 0.6, 0.7, None),
+        Span("observe", 0, 1.0, 1.1, None)])
+    rec = obs.end_cycle(object(), {"wall": 1.0})
+    assert rec["rank_busy"] == {0: pytest.approx(0.4), 1: pytest.approx(0.1)}
+    assert rec["dead_frac"] == pytest.approx(0.75)
+    assert rec["phase_wall"]["wait"] == pytest.approx(1.0)
+
+
+def test_device_programs_carry_their_names(device_schedule_run):
+    """The cycle scan compiles as ``jit_cycle_scan`` and an exchange as
+    ``jit_halo_permute``: the module names a device trace prefixes every
+    operation with."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+    from repro.sph.collectives import build_permute_program
+    sim, _ = device_schedule_run
+    programs = sim.engine._transport.programs._programs
+    names = {key[0]: prog.__name__ for key, prog in programs.items()}
+    assert names["cycle_scan"] == "cycle_scan"
+    mesh = Mesh(np.array(jax.devices()[:1]), ("r",))
+    prog = build_permute_program(mesh, "r", [[(0, 0)]], nrows=4, bucket=2,
+                                 nfields=1)
+    idx = jnp.zeros((1, 1, 2), jnp.int32)
+    text = prog.lower(idx, idx, jnp.zeros((1, 1, 2), jnp.float32),
+                      jnp.zeros((1, 4), jnp.float32)).as_text()
+    assert text.startswith("module @jit_halo_permute")
 
 
 # -------------------------------------------- ledger fidelity + sinks e2e
