@@ -400,9 +400,9 @@ class FleetRunner:
         t_start = time.perf_counter()
         for n in range(max_steps):
             # one batched program: a task on every live request's row
-            with tr.span("fleet_step",
-                         ranks={m.req.row: {"request_id": m.req.request_id}
-                                for m in group if not m.done},
+            rows = ({m.req.row: {"request_id": m.req.request_id}
+                     for m in group if not m.done} if tr.enabled else None)
+            with tr.span("fleet_step", ranks=rows,
                          signature=sig_key, step=n, batch=len(group),
                          bucket=bucket):
                 if spec.dt is not None:

@@ -109,6 +109,24 @@ class RunObserver:
 
     # ---------------------------------------------------------- per cycle
     def end_cycle(self, sim, stats: Dict[str, Any]) -> Dict[str, Any]:
+        """Close the cycle's record. The observer's own cost is the
+        ``observe`` span, in the trace and in this cycle's ``phase_wall``
+        (never in a rank's busy time)."""
+        with self.tracer.span("observe") as sp:
+            rec = self._cycle_record(sim, stats)
+        # the observe span is this cycle's: the next cycle starts after it
+        self._span_mark = len(self.tracer.spans)
+        if "phase_wall" in rec:
+            rec["phase_wall"]["observe"] = sp.elapsed
+            rec["phase_count"]["observe"] = 1
+        self._update_registry(rec)
+        if self.spec.metrics:
+            rec["metrics"] = self.registry.snapshot()
+            self.records.append(jsonify(rec))
+        self.cycle += 1
+        return rec
+
+    def _cycle_record(self, sim, stats: Dict[str, Any]) -> Dict[str, Any]:
         eng = getattr(sim, "engine", sim)
         self._cycle_marks.append((self.cycle, self._span_mark))
         spans = self.tracer.spans[self._span_mark:]
@@ -309,12 +327,6 @@ class RunObserver:
         rec["observed_units"] = (
             {k: cm.observed_units(k) for k in cm.observed}
             if hasattr(cm, "observed_units") else {})
-
-        self._update_registry(rec)
-        if self.spec.metrics:
-            rec["metrics"] = self.registry.snapshot()
-            self.records.append(jsonify(rec))
-        self.cycle += 1
         return rec
 
     # ------------------------------------------------- cost attribution

@@ -347,9 +347,7 @@ class _SimulationBase:
         """Advance one step/cycle; closes the observer's cycle record."""
         stats = self._step_impl()
         if self.observer is not None:
-            # tracing's own per-cycle cost, visible in the trace
-            with self._tracer.span("observe"):
-                self.observer.end_cycle(self, stats)
+            self.observer.end_cycle(self, stats)
         return stats
 
     def _init_observer(self) -> None:

@@ -413,6 +413,27 @@ def test_observer_counts_waiting_as_idle():
     assert rec["phase_wall"]["wait"] == pytest.approx(1.0)
 
 
+def test_observe_span_belongs_to_its_cycle():
+    """``end_cycle`` times itself as the ``observe`` span: the record of
+    the cycle it closes carries that span's seconds, and the next cycle's
+    record does not count it again."""
+    from repro.observability import RunObserver, Span
+    obs = RunObserver(ObserveSpec(enabled=True, metrics=False,
+                                  device_metrics=False))
+    obs.tracer.spans.append(Span("plan", 0, 0.0, 0.1, None))
+    rec = obs.end_cycle(object(), {"wall": 1.0})
+    own = obs.tracer.spans[-1]
+    assert own.name == "observe"
+    assert rec["phase_wall"]["observe"] == own.dur
+    assert rec["phase_count"]["observe"] == 1
+    assert rec["rank_busy"] == {0: pytest.approx(0.1)}
+    obs.tracer.spans.append(Span("plan", 0, 2.0, 2.3, None))
+    rec2 = obs.end_cycle(object(), {"wall": 1.0})
+    assert rec2["phase_count"] == {"plan": 1, "observe": 1}
+    assert rec2["phase_wall"]["plan"] == pytest.approx(0.3)
+    assert rec2["phase_wall"]["observe"] == obs.tracer.spans[-1].dur
+
+
 def test_device_programs_carry_their_names(device_schedule_run):
     """The cycle scan compiles as ``jit_cycle_scan`` and an exchange as
     ``jit_halo_permute``: the module names a device trace prefixes every
