@@ -13,9 +13,11 @@ buys on identical physics, in two regimes:
   exists for (the SWIFT strong-scaling limit, where control-plane
   overhead per step is the whole game) — expect multi-× speedups.
 * ``deep`` — n_side=6, max_depth=4: a real ladder. The compiled scan
-  runs every trip over the full-touch pair table (dead trips compute and
-  discard), while the host scheduler dispatches per-level *compacted*
-  programs — so on a compute-bound CPU the host path stays ahead. The
+  skips its dead trips but runs every live one over the full-touch pair
+  table (too small for a compacted bucket), while the host scheduler
+  dispatches per-level *compacted* programs — on a compute-bound CPU the
+  host path was ahead while the scan still ran its dead trips
+  (``BENCH_fused_cycles.json``). The
   regime is reported, not hidden: it bounds where ``schedule="device"``
   should be switched on today.
 

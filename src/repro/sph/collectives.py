@@ -367,6 +367,50 @@ def build_fused_substep_program(mesh, axis: str, *, mode: str,
 # (same value the host planners use in timebins.limit_neighbour_bins)
 _NEG_INF_BIN = -10 ** 6
 _SCAN_UNROLL = False
+# A sparse trip runs its pair passes over a compacted bucket of 1/32 of the
+# padded pair table; a table whose bucket would hold fewer than
+# _COMPACT_MIN slots runs every live trip over the whole table.
+_COMPACT_SHIFT = 5
+_COMPACT_MIN = 64
+# the cycle scan's per-trip branches, chosen rank-uniformly
+SKIP, COMPACT, FULL = 0, 1, 2
+
+
+def compact_bucket(npairs: int) -> int:
+    """Slots of the compacted pair bucket of a padded table of ``npairs``
+    pairs (a power of two): 0 where the table is too small for one."""
+    slots = npairs >> _COMPACT_SHIFT
+    return slots if slots >= _COMPACT_MIN else 0
+
+
+def _compact_pairs(pairs: PairList, pm, kind, nslots: int, nint: int,
+                   ncut: int):
+    """The live pairs of ``pm`` packed into ``nslots`` slots, in original
+    pair-list order, with their interior/cut split.
+
+    ``kind`` (npairs,) is 1 at the interior positions, 2 at the cut ones.
+    Returns the compacted :class:`PairList`, its mask (padding slots 0, so
+    they add exact ±0.0) and interior/cut positions into it, each padded to
+    ``nint``/``ncut`` slots — the operands :func:`_split_force_pass` takes
+    for the whole table. Every row then folds the same contributions in the
+    same order as over the whole table, less the exact zeros. The caller
+    guarantees at most ``nslots`` live pairs.
+    """
+    live = pm > 0
+    (pos,) = jnp.nonzero(live, size=nslots, fill_value=0)
+    filled = jnp.arange(nslots) < jnp.sum(live)
+    pm_c = jnp.where(filled, pm[pos], 0.0)
+    kind_c = jnp.where(filled, kind[pos], 0)
+
+    def split(k, size):
+        sel = kind_c == k
+        (at,) = jnp.nonzero(sel, size=size, fill_value=0)
+        valid = (jnp.arange(size) < jnp.sum(sel)).astype(pm.dtype)
+        return at.astype(jnp.int32), valid
+
+    pairs_c = PairList(ci=pairs.ci[pos], cj=pairs.cj[pos],
+                       shift=pairs.shift[pos])
+    return (pairs_c, pm_c) + split(1, nint) + split(2, ncut)
 
 
 def build_cycle_scan_program(mesh, axis: str, *, mode: str,
@@ -390,44 +434,59 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
 
     * the active level is ``max(depth − tz[n], 0)`` via a static
       trailing-zeros table;
-    * the wake floor is recomputed from the live bins by pair scatter-max
-      (the host recomputes it only on deepen events; the per-trip recompute
-      reaches the same fixpoint values) and exchanged to halo rows over the
-      full cut, so replica activity masks agree with their owners;
+    * the wake floor is computed from the live bins by pair scatter-max
+      before the first trip and after each live one (the host recomputes it
+      only on deepen events; these recomputes reach the same fixpoint
+      values) and exchanged to halo rows over the full cut, so replica
+      activity masks agree with their owners;
     * the pair subset is the *static full-touch table* gated by a dynamic
       mask — a pair is live iff it touches an active cell, exactly the host
       selection rule — and exchange validity is the static full-cut table
       gated by receiver-row activity (activity-aware shipping);
-    * trips where no particle is active anywhere (``psum`` of the owned
-      active counts) are *dead*: every state field keeps its carry via a
-      ``where``, matching the host loop's ``continue`` (lazy drift
-      included — the drift span accumulates in a ``drifted_to`` carry);
-    * the final trip (n == nsub) runs the cycle-closing kick; interior and
-      final updates are computed side by side and merged with a ``where``,
-      so one compiled body serves both.
+    * each trip takes one of three branches, chosen from values every rank
+      shares (the ``psum`` of the owned active counts, the ``pmax`` of the
+      ranks' live-pair counts, ``n == nsub``), since the exchanges sit
+      inside them:
 
-    Padded pair slots contribute exact ±0.0 through the same masked
-    scatters as the host-scheduled fused path — the bitwise contract is
-    ``assert_array_equal`` (±0.0 and NaN compare equal), identical to the
-    existing residency conformance pin.
+      - ``SKIP``: no particle is active anywhere and the trip is not the
+        last — no pair-table work at all (no pair pass, no exchange, no
+        kick, no wake floor, no telemetry row); the state keeps its carry,
+        matching the host loop's ``continue`` (lazy drift included — the
+        drift span accumulates in a ``drifted_to`` carry);
+      - ``COMPACT``: an interior trip whose live pairs fit
+        :func:`compact_bucket` on every rank — the pair passes run over the
+        live pairs packed in original order (:func:`_compact_pairs`);
+      - ``FULL``: the last trip (n == nsub, the cycle-closing kick) or an
+        interior trip with more live pairs — the pair passes run over the
+        whole table, masked.
 
-    The scan is **fully unrolled** (``unroll=nsub_static``): XLA:CPU's
-    while-loop lowering of a rolled scan changes the force-reduction
-    codegen by ~1 ulp versus the straight-line per-sub-step programs,
-    which would break the bitwise contract. Unrolling recovers the exact
-    straight-line HLO; the ladder is short (2^depth trips), so program
-    size stays modest. ``_SCAN_UNROLL`` is a debug hook that swaps in a
-    literal Python loop over trips to separate scan-lowering effects from
+    Padded and dead pair slots contribute exact ±0.0 through the same masked
+    scatters as the host-scheduled fused path, and a compacted list keeps
+    the live pairs' order, so all three branches fold the same sums — the
+    bitwise contract is ``assert_array_equal`` (±0.0 and NaN compare
+    equal), identical to the existing residency conformance pin.
+
+    On the CPU the scan is **fully unrolled** (``unroll=nsub_static``):
+    XLA:CPU's while-loop lowering of a rolled scan changes the
+    force-reduction codegen by ~1 ulp versus the straight-line per-sub-step
+    programs, which would break the bitwise contract. Unrolling recovers
+    the exact straight-line HLO. On any other platform the scan stays
+    rolled: an unrolled trip carries two pair-pass bodies (~45 MB of v5e
+    code at n_side 60), and sixteen of them doubled the time a v5e takes to
+    load the cached program. ``_SCAN_UNROLL`` is a debug hook that swaps in
+    a literal Python loop over trips to separate scan-lowering effects from
     body bugs.
 
     Outputs: the updated state dict (donated buffers), a per-rank counter
     dict (owned active updates, owned live pair tasks, live interior trips,
-    exported slots, live trips, end-of-cycle time) and the cycle's
+    exported slots, live trips, pair slots the pair passes ran over,
+    compacted and skipped trips, end-of-cycle time) and the cycle's
     accumulated device-metrics row — counters and health sentinels
     (NaN/Inf/neg-rho flags) included, so the segment driver's one boundary
     pull sees everything.
     """
     perms = [list(rnd) for rnd in rounds]
+    unroll = nsub_static if mesh.devices.flat[0].platform == "cpu" else 1
     tz_np = trailing_zeros_table(nsub_static)
     v_acc = np.asarray(dmetrics._V_ACCUM)
     v_sum = jnp.asarray(v_acc == "sum")
@@ -454,7 +513,7 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
 
     def fold_values(acc, row, live):
         """Live-gated fold of one metrics value row per ``_V_ACCUM``
-        (dmetrics.combine is unconditional — a dead trip's garbage row
+        (dmetrics.combine is unconditional — a skipped trip's zero row
         must not leak into last/max/min columns)."""
         upd_sum = acc + jnp.where(live, row, 0.0)
         upd_last = jnp.where(live, row, acc)
@@ -488,30 +547,50 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
             accel=blk["accel"], dudt=blk["dudt"], rho=blk["rho"],
             omega=blk["omega"], bins=blk["bins"], t_start=blk["t_start"],
             time=blk["time"])
+        B = int(ci.shape[0])
+        S = compact_bucket(B)
+        full_split = (tbl["int_pos"], tbl["int_valid"], tbl["cut_pos"],
+                      tbl["cut_valid"])
+        if S:
+            kind = jnp.zeros((B,), jnp.int32)
+            kind = kind.at[tbl["int_pos"]].max(
+                jnp.where(tbl["int_valid"] > 0, 1, 0))
+            kind = kind.at[tbl["cut_pos"]].max(
+                jnp.where(tbl["cut_valid"] > 0, 2, 0))
+            S_int = min(S, int(tbl["int_pos"].shape[0]))
+            S_cut = min(S, int(tbl["cut_pos"].shape[0]))
+        branch_slots = jnp.asarray([0, S, B], jnp.int32)
         cnt0 = {k: jnp.zeros((), jnp.int32)
                 for k in ("updates", "pair_tasks", "force_substeps",
-                          "exported", "live_trips")}
+                          "exported", "live_trips", "pair_slots",
+                          "compact_trips", "skipped_trips")}
         met_c0 = jnp.zeros((len(dmetrics.COUNT_COLUMNS),), jnp.int32)
         met_v0 = jnp.zeros((len(dmetrics.VALUE_COLUMNS),), jnp.float32)
         met_v0 = met_v0.at[dmetrics.VALUE_INDEX["min_rho"]].set(jnp.inf)
         met_w0 = jnp.zeros((nrows, dmetrics.N_CELL_COLS), jnp.float32)
 
-        def trip(carry, n):
-            st, drifted_to, cnt, met_c, met_v, met_w = carry
-            mask = st.cells.mask
-            maskb = mask > 0
-            level = jnp.maximum(depth - tz[n], 0)
-            is_final = n == nsub_dyn
-            # ---- wake floor from the live bins (host _wake_floor)
-            deep = jnp.max(jnp.where(maskb, st.bins, _NEG_INF_BIN), axis=1)
+        mask = st0.cells.mask
+        maskb = mask > 0
+
+        def wake_floor(bins):
+            """Host ``_wake_floor`` from the live bins, exchanged to halo
+            rows over the full cut (owned rows' stencils are complete —
+            every pair touching an owned cell is in the touch table). The
+            host recomputes it only on deepen events; a skipped trip leaves
+            the bins, so the floor is recomputed after each live trip and
+            carried — the same fixpoint values."""
+            deep = jnp.max(jnp.where(maskb, bins, _NEG_INF_BIN), axis=1)
             nb = deep
             nb = nb.at[ci].max(jnp.where(pmask > 0, deep[cj], _NEG_INF_BIN))
             nb = nb.at[cj].max(jnp.where(pmask > 0, deep[ci], _NEG_INF_BIN))
             wake_own = jnp.maximum(nb - bin_delta, 0).astype(jnp.int32)
-            # full-cut exchange: halo rows take their owner's wake floor
-            # (owned rows' stencils are complete — every pair touching an
-            # owned cell is in the touch table)
             (wake,) = xchg(tbl, [wake_own], tbl["e_valid"])
+            return wake
+
+        def trip(carry, n):
+            st, drifted_to, wake, cnt, met_c, met_v, met_w = carry
+            level = jnp.maximum(depth - tz[n], 0)
+            is_final = n == nsub_dyn
             # ---- activity (host substep_active_mask / final mask)
             sub_act = ((st.bins >= level) | (st.bins < wake[:, None])
                        ) & maskb
@@ -520,104 +599,150 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
             glob_act = jax.lax.psum(jnp.sum(sub_act[:K]).astype(jnp.int32),
                                     axis)
             live = ((glob_act > 0) | is_final) & (n <= nsub_dyn)
-            # ---- lazy drift of everything since the last live trip
-            kdt = (n - drifted_to).astype(jnp.float32) * dt_min
-            std = _drift(st, kdt, box=box)
-            # ---- density + exchange 1 + split force (as the fused path,
-            # with the static tables gated by this trip's activity)
-            pm = jnp.where(is_final, pmask,
-                           pmask * jnp.maximum(row_act[ci], row_act[cj]))
-            rho, om, pr, cs = _substep_density_phase(std, pairs, pm,
-                                                     active, cfg=cfg)
-            ev = recv_valid(tbl, row_act, is_final)
-            rho2, om2, pr2, cs2 = xchg(tbl, [rho, om, pr, cs], ev)
-            dv, du = _split_force_pass(
-                std.cells, pairs, pm, (rho, pr, om, cs),
-                (rho2, pr2, om2, cs2), tbl["int_pos"], tbl["int_valid"],
-                tbl["cut_pos"], tbl["cut_valid"], cfg=cfg)
-            # ---- interior and final kicks, merged by where
-            stF, kickedF = _apply_force_kick(
-                std, sub_act.astype(fdt), dv, du, rho2, om2, wake, dt_max,
-                depth, u_floor, cfg=cfg)
-            stL = _apply_final_kick(std, dv, du, rho2, om2, dt_max, cfg=cfg)
-            stK = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(is_final, a, b), stL, stF)
-            # ---- exchange 2: kicked state -> replicas. Unlike the host
-            # ladder this also runs on the final trip (full validity), so
-            # halo replicas enter the next cycle of a K>1 segment current;
-            # owned rows are untouched by construction.
-            vel, uu, bb, ts, ac, dd = xchg(
-                tbl, [stK.cells.vel, stK.cells.u, stK.bins, stK.t_start,
-                      stK.accel, stK.dudt], ev)
-            stN = stK._replace(cells=stK.cells._replace(vel=vel, u=uu),
-                               bins=bb, t_start=ts, accel=ac, dudt=dd)
-            # ---- counters (owned partial sums; the driver psums on host)
-            live32 = live.astype(jnp.int32)
-            n_upd = jnp.where(is_final, jnp.sum(maskb[:K]),
-                              jnp.sum(sub_act[:K])).astype(jnp.int32)
-            n_pair = jnp.sum((pm > 0) & (tbl["own_pair"] > 0)
-                             ).astype(jnp.int32)
-            n_slots = jnp.sum(ev > 0).astype(jnp.int32)
+
+            def run():
+                # lazy drift of everything since the last live trip
+                kdt = (n - drifted_to).astype(jnp.float32) * dt_min
+                std = _drift(st, kdt, box=box)
+                # the static tables gated by this trip's activity
+                pm = jnp.where(is_final, pmask,
+                               pmask * jnp.maximum(row_act[ci], row_act[cj]))
+                ev = recv_valid(tbl, row_act, is_final)
+
+                def update(pairs_, pm_, split, closing):
+                    """Density + exchange 1 + split force (as the fused
+                    path) over one pair list, the kick, then exchange 2."""
+                    rho, om, pr, cs = _substep_density_phase(
+                        std, pairs_, pm_, active, cfg=cfg)
+                    rho2, om2, pr2, cs2 = xchg(tbl, [rho, om, pr, cs], ev)
+                    dv, du = _split_force_pass(
+                        std.cells, pairs_, pm_, (rho, pr, om, cs),
+                        (rho2, pr2, om2, cs2), *split, cfg=cfg)
+
+                    def interior_kick():
+                        return _apply_force_kick(
+                            std, sub_act.astype(fdt), dv, du, rho2, om2,
+                            wake, dt_max, depth, u_floor, cfg=cfg)
+
+                    def final_kick():
+                        stL = _apply_final_kick(std, dv, du, rho2, om2,
+                                                dt_max, cfg=cfg)
+                        return stL, jnp.sum((active > 0) & maskb
+                                            ).astype(jnp.int32)
+
+                    stK, kicked = (jax.lax.cond(is_final, final_kick,
+                                                interior_kick)
+                                   if closing else interior_kick())
+                    # exchange 2: kicked state -> replicas. Unlike the host
+                    # ladder this also runs on the final trip (full
+                    # validity), so halo replicas enter the next cycle of a
+                    # K>1 segment current; owned rows are untouched by
+                    # construction.
+                    vel, uu, bb, ts, ac, dd = xchg(
+                        tbl, [stK.cells.vel, stK.cells.u, stK.bins,
+                              stK.t_start, stK.accel, stK.dudt], ev)
+                    return stK._replace(
+                        cells=stK.cells._replace(vel=vel, u=uu), bins=bb,
+                        t_start=ts, accel=ac, dudt=dd), kicked
+
+                def full():
+                    return update(pairs, pm, full_split, closing=True)
+
+                def compact():
+                    pairs_c, pm_c, *split_c = _compact_pairs(
+                        pairs, pm, kind, S, S_int, S_cut)
+                    return update(pairs_c, pm_c, split_c, closing=False)
+
+                # every rank takes the same branch: the exchanges sit inside
+                if S:
+                    nlive = jax.lax.pmax(jnp.sum(pm > 0).astype(jnp.int32),
+                                         axis)
+                    branch = jnp.where(~is_final & (nlive <= S), COMPACT,
+                                       FULL)
+                    stN, kicked = jax.lax.cond(branch == COMPACT, compact,
+                                               full)
+                else:
+                    branch = jnp.int32(FULL)
+                    stN, kicked = full()
+                # counters (owned partial sums; the driver psums on host)
+                n_upd = jnp.where(is_final, jnp.sum(maskb[:K]),
+                                  jnp.sum(sub_act[:K])).astype(jnp.int32)
+                n_pair = jnp.sum((pm > 0) & (tbl["own_pair"] > 0)
+                                 ).astype(jnp.int32)
+                n_slots = jnp.sum(ev > 0).astype(jnp.int32)
+                # telemetry row (mirrors build_fused_substep_program)
+                deepened = jnp.where(is_final, 0,
+                                     jnp.sum(stN.bins[:K] != st.bins[:K])
+                                     ).astype(jnp.int32)
+                woken = jnp.where(is_final, 0, jnp.sum(wake > level)
+                                  ).astype(jnp.int32)
+                nexch = jnp.where(is_final, 1, 2)
+                slot_bytes = jnp.where(is_final, 4 * cap * 4,
+                                       (4 + 10) * cap * 4)
+                mrow_c, mrow_v = dmetrics.measure_substep(
+                    mask=stN.cells.mask[:K], active=active[:K],
+                    vel=stN.cells.vel[:K], u=stN.cells.u[:K],
+                    mass=stN.cells.mass[:K], rho=stN.rho[:K],
+                    live_pairs=jnp.sum(pm),
+                    pair_int=jnp.sum(jnp.where(tbl["int_valid"] > 0,
+                                               pm[tbl["int_pos"]], 0.0)
+                                     ).astype(jnp.int32),
+                    pair_cut=jnp.sum(jnp.where(tbl["cut_valid"] > 0,
+                                               pm[tbl["cut_pos"]], 0.0)
+                                     ).astype(jnp.int32),
+                    exch_slots=n_slots * nexch,
+                    exch_bytes=n_slots * slot_bytes,
+                    deepened=deepened, woken=woken, kicked=kicked)
+                mrow_w = dmetrics.measure_cells(
+                    nrows=nrows, K=K, mask=stN.cells.mask[:K], pmask=pm,
+                    ci=ci, cj=cj,
+                    exch_rows=(tbl["e_unpack"] if mode == "ppermute"
+                               else tbl["e_urows"]),
+                    exch_valid=ev, nexch=nexch)
+                return (stN, wake_floor(stN.bins), branch,
+                        (n_upd, n_pair, n_slots), (mrow_c, mrow_v, mrow_w))
+
+            def skip():
+                # no pair pass, no exchange, no kick: every state carry,
+                # the wake floor included, stays bit-identical
+                zero = jnp.zeros((), jnp.int32)
+                return (st, wake, jnp.int32(SKIP), (zero, zero, zero),
+                        (jnp.zeros_like(met_c), jnp.zeros_like(met_v),
+                         jnp.zeros_like(met_w)))
+
+            stN, wake_new, branch, (n_upd, n_pair, n_slots), \
+                (mrow_c, mrow_v, mrow_w) = jax.lax.cond(live, run, skip)
             cnt_new = {
-                "updates": cnt["updates"] + live32 * n_upd,
-                "pair_tasks": cnt["pair_tasks"] + live32 * n_pair,
+                "updates": cnt["updates"] + n_upd,
+                "pair_tasks": cnt["pair_tasks"] + n_pair,
                 "force_substeps": cnt["force_substeps"]
                 + (live & ~is_final).astype(jnp.int32),
-                "exported": cnt["exported"] + live32 * n_slots,
-                "live_trips": cnt["live_trips"] + live32,
+                "exported": cnt["exported"] + n_slots,
+                "live_trips": cnt["live_trips"] + live.astype(jnp.int32),
+                "pair_slots": cnt["pair_slots"] + branch_slots[branch],
+                "compact_trips": cnt["compact_trips"]
+                + (branch == COMPACT).astype(jnp.int32),
+                "skipped_trips": cnt["skipped_trips"]
+                + ((branch == SKIP) & (n <= nsub_dyn)).astype(jnp.int32),
             }
-            # ---- telemetry row (mirrors build_fused_substep_program)
-            deepened = jnp.where(is_final, 0,
-                                 jnp.sum(bb[:K] != st.bins[:K])
-                                 ).astype(jnp.int32)
-            woken = jnp.where(is_final, 0, jnp.sum(wake > level)
-                              ).astype(jnp.int32)
-            nexch = jnp.where(is_final, 1, 2)
-            slot_bytes = jnp.where(is_final, 4 * cap * 4,
-                                   (4 + 10) * cap * 4)
-            kicked = jnp.where(
-                is_final,
-                jnp.sum((active > 0) & maskb).astype(jnp.int32), kickedF)
-            mrow_c, mrow_v = dmetrics.measure_substep(
-                mask=stN.cells.mask[:K], active=active[:K],
-                vel=stN.cells.vel[:K], u=stN.cells.u[:K],
-                mass=stN.cells.mass[:K], rho=stN.rho[:K],
-                live_pairs=jnp.sum(pm),
-                pair_int=jnp.sum(jnp.where(tbl["int_valid"] > 0,
-                                           pm[tbl["int_pos"]], 0.0)
-                                 ).astype(jnp.int32),
-                pair_cut=jnp.sum(jnp.where(tbl["cut_valid"] > 0,
-                                           pm[tbl["cut_pos"]], 0.0)
-                                 ).astype(jnp.int32),
-                exch_slots=n_slots * nexch,
-                exch_bytes=n_slots * slot_bytes,
-                deepened=deepened, woken=woken, kicked=kicked)
-            mrow_w = dmetrics.measure_cells(
-                nrows=nrows, K=K, mask=stN.cells.mask[:K], pmask=pm,
-                ci=ci, cj=cj,
-                exch_rows=(tbl["e_unpack"] if mode == "ppermute"
-                           else tbl["e_urows"]),
-                exch_valid=ev, nexch=nexch)
-            met_c_new = met_c + jnp.where(live, mrow_c, 0)
+            met_c_new = met_c + mrow_c
             met_v_new = fold_values(met_v, mrow_v, live)
-            met_w_new = met_w + jnp.where(live, mrow_w, 0.0)
-            # ---- dead trips keep every carry bit-identical
-            stO = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(live, new, old), stN, st)
+            met_w_new = met_w + mrow_w
             drifted_new = jnp.where(live, n, drifted_to)
-            return (stO, drifted_new, cnt_new, met_c_new, met_v_new,
-                    met_w_new), None
+            return (stN, drifted_new, wake_new, cnt_new, met_c_new,
+                    met_v_new, met_w_new), None
 
         xs = jnp.arange(1, nsub_static + 1, dtype=jnp.int32)
-        carry0 = (st0, jnp.int32(0), cnt0, met_c0, met_v0, met_w0)
+        carry0 = (st0, jnp.int32(0), wake_floor(st0.bins), cnt0, met_c0,
+                  met_v0, met_w0)
         if _SCAN_UNROLL:        # debug hook: straight-line trips
             carry = carry0
             for n in range(1, nsub_static + 1):
                 carry, _ = trip(carry, jnp.int32(n))
-            stE, _, cnt, met_c, met_v, met_w = carry
+            stE, _, _, cnt, met_c, met_v, met_w = carry
         else:
-            (stE, _, cnt, met_c, met_v, met_w), _ = jax.lax.scan(
-                trip, carry0, xs, unroll=nsub_static)
+            (stE, _, _, cnt, met_c, met_v, met_w), _ = jax.lax.scan(
+                trip, carry0, xs, unroll=unroll)
         out = {k: getattr(stE.cells, k) for k in STATE_CELL_FIELDS}
         out.update({k: getattr(stE, k) for k in STATE_AUX_FIELDS})
         out["time"] = stE.time

@@ -1549,8 +1549,8 @@ class DistTimeBinSimulation(TimeBinSimulation):
             with tr.span("rebin", units=ctx["nreal"]):
                 self._rebin_state()
         with tr.span("stats", ranks=ranks, collective=1):
-            return self._segment_stats(ctx, plan, pulled_cnt, pulled_met,
-                                       pulled_scal, pulled_flags)
+            return self._segment_stats(ctx, plan, sig[3], pulled_cnt,
+                                       pulled_met, pulled_scal, pulled_flags)
 
     def _launch_segment(self, res: ResidentBuffers, tables, consts, scalars,
                         cyc_prog, plan_prog) -> Tuple[List, List, List, List]:
@@ -1573,11 +1573,13 @@ class DistTimeBinSimulation(TimeBinSimulation):
         return per_cnt, per_met, per_scal, per_flags
 
     def _segment_stats(self, ctx: Dict[str, object], plan: RankPlan,
-                       pulled_cnt: List[Dict], pulled_met: List[Tuple],
-                       pulled_scal: List[Dict], pulled_flags: List[Dict]
-                       ) -> List[Dict]:
+                       npairs: int, pulled_cnt: List[Dict],
+                       pulled_met: List[Tuple], pulled_scal: List[Dict],
+                       pulled_flags: List[Dict]) -> List[Dict]:
         """Per-cycle stats of a finished segment from its boundary pull,
-        and the engine's counters advanced by them."""
+        and the engine's counters advanced by them. ``npairs`` is the
+        padded length of a rank's pair table: ``pair_slots``, the slots the
+        cycle's pair passes ran over, is at most ``substeps`` times it."""
         K_cycles = self.segment_cycles
         nreal = ctx["nreal"]
         cut_slots = plan.cut_slots
@@ -1616,6 +1618,10 @@ class DistTimeBinSimulation(TimeBinSimulation):
                 "global_equiv_pair_tasks": nsub_j * len(self._ci),
                 "halo_exported_slots": exported_j,
                 "halo_full_slots": full_j,
+                "pair_slots": int(cnt["pair_slots"][0]),
+                "pair_table_slots": npairs,
+                "compact_trips": int(cnt["compact_trips"][0]),
+                "skipped_trips": int(cnt["skipped_trips"][0]),
                 "nranks": plan.nranks,
                 "residency": self.residency,
                 "schedule": "device",

@@ -19,7 +19,8 @@ from repro.kernels.sph_pair.kernel import (density_pair_pallas,
                                            force_pair_pallas)
 from repro.sph import SPHConfig
 from repro.sph.cellgrid import PairList, ParticleCells
-from repro.sph.collectives import build_fused_substep_program
+from repro.sph.collectives import (build_cycle_scan_program,
+                                   build_fused_substep_program)
 from repro.sph.engine import _density_pass
 
 C = 32                  # cell capacity of the kernels at their real width
@@ -142,3 +143,36 @@ def test_fused_substep_compiles_for_v5e(topo, nranks):
     txt = prog.lower(*args).compile().as_text()
     if nranks == 4:
         assert "collective-permute" in txt or "all-gather" in txt
+
+
+def _scan_shapes(nranks):
+    if nranks == 1:
+        return [], dict(nrows=343, K=343, B=8192, Bi=8192, Bc=1, R=0, Be=1)
+    ring = [[(r, (r + s) % nranks) for r in range(nranks)]
+            for s in (1, nranks - 1)]
+    return ring, dict(nrows=256, K=96, B=4096, Bi=2048, Bc=4096, R=2, Be=64)
+
+
+@pytest.mark.parametrize("nranks", [1, 4])
+def test_cycle_scan_compiles_rolled_for_v5e(topo, nranks):
+    """The cycle scan compiles for the chip with its trips rolled into one
+    loop: each trip's skip, compact and full branches appear once, not
+    once per trip of the 16."""
+    mesh = Mesh(np.array(topo.devices[:nranks]), ("ranks",))
+    rounds, shapes = _scan_shapes(nranks)
+    prog = build_cycle_scan_program(
+        mesh, "ranks", mode="ppermute", rounds=rounds,
+        nrows=shapes["nrows"], K=shapes["K"],
+        cfg=SPHConfig(alpha_visc=1.0, cfl=0.15), box=1.0, nsub_static=16,
+        bin_delta=2)
+    state, tables, _ = _fused_args(mesh, nranks, **shapes)
+    sh = NamedSharding(mesh, P("ranks"))
+    del tables["wake"]
+    tables["own_pair"] = _sds((nranks, shapes["B"]), jnp.float32, sh)
+    tables["rowcell"] = _sds((nranks, shapes["nrows"]), jnp.int32, sh)
+    scalars = {k: _sds((nranks,), d, sh) for k, d in (
+        ("dt_max", jnp.float32), ("depth", jnp.int32), ("nsub", jnp.int32),
+        ("u_floor", jnp.float32))}
+    txt = prog.lower(state, tables, scalars).compile().as_text()
+    assert " while(" in txt
+    assert txt.count(" conditional(") == 3
