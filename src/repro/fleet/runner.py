@@ -101,7 +101,7 @@ class TransferBufferPool:
 class _Member:
     """One request's host-side engine bookkeeping inside a batch."""
     req: FleetRequest
-    box: float
+    box: tuple                      # the cube's edge, thrice
     n: int
     gspec: Any
     cells: Any
@@ -114,19 +114,25 @@ class _Member:
 
     @property
     def shape_key(self) -> tuple:
-        return (self.gspec.ncells_side, self.cells.mass.shape[1],
-                float(self.box), int(np.asarray(self.pairs.ci).shape[0]))
+        return (self.gspec.dims, self.cells.mass.shape[1],
+                self.box, int(np.asarray(self.pairs.ci).shape[0]))
 
 
 def _build_member(req: FleetRequest) -> _Member:
     """Host-side admission of one request: IC → grid → cells → initial
     state, exactly the single-run engine's construction path (eager
     ``init_state`` so lane 0 of a batch is bitwise the single run)."""
-    from ..sph.cellgrid import bin_particles, build_pair_list, choose_grid
+    from ..sph.cellgrid import (bin_particles, box_lengths, build_pair_list,
+                                choose_grid)
     from ..sph.engine import init_state
     spec = req.spec
     ic = make_ic(spec.scenario, **dict(spec.scenario_params))
-    box = float(ic["box"])
+    if np.ndim(ic["box"]) != 0:
+        raise ValueError(
+            f"the fleet batches cubic boxes of one edge length; scenario "
+            f"{spec.scenario!r} has a box of three, {tuple(ic['box'])!r}: "
+            f"run it through build_simulation")
+    box = box_lengths(ic["box"])
     n = len(ic["pos"])
     gspec = choose_grid(box, float(np.max(ic["h"])), n,
                         capacity_margin=spec.capacity_margin)
@@ -321,7 +327,7 @@ class FleetRunner:
         import jax.numpy as jnp
         from ..sph.engine import cfl_timestep_particles, step
         ndev = self._ndev_for(bucket)
-        box = float(shape_key[2])
+        box = shape_key[2]
         cfg = spec.physics
 
         def build_step():

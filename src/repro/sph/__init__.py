@@ -17,7 +17,8 @@ from .cellgrid import (GridSpec, PairList, ParticleCells, bin_particles,
 from .engine import (SPHConfig, SPHState, Simulation, build_taskgraph,
                      cfl_timestep, compute_accelerations, init_state, step)
 from .engine import cfl_timestep_particles
-from .ic import clustered_ic, kelvin_helmholtz_ic, sedov_ic, uniform_ic
+from .ic import (clustered_ic, kelvin_helmholtz_ic, sedov_ic, sodshock_ic,
+                 uniform_ic)
 from .physics import (GAMMA, cfl_timestep_block, density_block, eos_pressure,
                       force_block, ghost_update, smoothing_length_update,
                       sound_speed)
@@ -37,7 +38,8 @@ __all__ = [
     "build_pair_list", "choose_grid", "unbin",
     "SPHConfig", "SPHState", "Simulation", "build_taskgraph", "cfl_timestep",
     "cfl_timestep_particles", "compute_accelerations", "init_state", "step",
-    "clustered_ic", "kelvin_helmholtz_ic", "sedov_ic", "uniform_ic",
+    "clustered_ic", "kelvin_helmholtz_ic", "sedov_ic", "sodshock_ic",
+    "uniform_ic",
     "GAMMA", "cfl_timestep_block", "density_block", "eos_pressure",
     "force_block", "ghost_update", "smoothing_length_update", "sound_speed",
     "dw_dh", "get_kernel", "w_cubic", "w_wendland_c2",

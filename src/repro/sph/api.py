@@ -71,7 +71,8 @@ def register_scenario(name: str):
     """Register an initial-condition factory under ``name``.
 
     The factory must return the standard IC dict: ``pos`` (n, 3), ``vel``,
-    ``mass``, ``u``, ``h`` arrays plus the scalar ``box``.
+    ``mass``, ``u``, ``h`` arrays plus ``box``, the periodic box's edge:
+    one length (a cube) or three (x, y, z).
     """
     def deco(fn):
         SCENARIOS[name] = fn
@@ -96,6 +97,7 @@ def _register_builtin_scenarios():
     SCENARIOS.setdefault("clustered", ic.clustered_ic)
     SCENARIOS.setdefault("sedov", ic.sedov_ic)
     SCENARIOS.setdefault("kelvin_helmholtz", ic.kelvin_helmholtz_ic)
+    SCENARIOS.setdefault("sodshock", ic.sodshock_ic)
 
 
 _register_builtin_scenarios()
@@ -414,7 +416,7 @@ class _LocalGlobal(_SimulationBase):
         self.spec = spec
         with _engine_layer():
             self.engine = _Engine(ic["pos"], ic["vel"], ic["mass"], ic["u"],
-                                  ic["h"], box=float(ic["box"]),
+                                  ic["h"], box=ic["box"],
                                   cfg=spec.physics,
                                   capacity_margin=spec.capacity_margin,
                                   rebin_every=spec.rebin_every)
@@ -483,7 +485,7 @@ class _LocalTimeBin(_SimulationBase):
         with _engine_layer():
             self.engine = TimeBinSimulation(
                 ic["pos"], ic["vel"], ic["mass"], ic["u"], ic["h"],
-                box=float(ic["box"]), cfg=spec.physics, dt_max=spec.dt_max,
+                box=ic["box"], cfg=spec.physics, dt_max=spec.dt_max,
                 max_depth=spec.max_depth, bin_delta=spec.bin_delta,
                 depth_headroom=spec.depth_headroom,
                 capacity_margin=spec.capacity_margin)
@@ -511,10 +513,11 @@ class _DistGlobal(_SimulationBase):
     def __init__(self, spec: SimulationSpec, ic: Dict[str, np.ndarray]):
         import jax
         from jax.sharding import Mesh
-        from .cellgrid import bin_particles, build_pair_list, choose_grid
+        from .cellgrid import (bin_particles, box_lengths, build_pair_list,
+                               choose_grid)
         from .distributed import DistSimulation
         self.spec = spec
-        self.box = float(ic["box"])
+        self.box = box_lengths(ic["box"])
         n = len(ic["pos"])
         ndev = spec.ranks or len(jax.devices())
         if ndev > len(jax.devices()):
@@ -638,7 +641,7 @@ class _DistTimeBin(_SimulationBase):
         nranks = spec.ranks if spec.ranks is not None else len(jax.devices())
         self.engine = DistTimeBinSimulation(
             ic["pos"], ic["vel"], ic["mass"], ic["u"], ic["h"],
-            box=float(ic["box"]), cfg=spec.physics, nranks=nranks,
+            box=ic["box"], cfg=spec.physics, nranks=nranks,
             activity_aware=spec.activity_aware_halos,
             repartition_threshold=spec.repartition_threshold,
             seed=spec.seed, dt_max=spec.dt_max, max_depth=spec.max_depth,

@@ -14,12 +14,16 @@ The half-stencil pair list realises SWIFT's symmetric pair tasks: each
 unordered neighbouring cell pair appears exactly once, with the periodic
 image shift carried alongside so the kernel can work with plain Euclidean
 distances (see physics.py docstring).
+
+The periodic box has an edge length per axis (``box_lengths``), and the grid
+a cell count per axis: a 2 × 0.5 × 0.5 shock tube is 4·n × n × n cells. A
+cubic box builds exactly the arrays of a one-length box.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
 
 import numpy as np
 import jax
@@ -44,35 +48,64 @@ class PairList(NamedTuple):
     shift: jax.Array   # (npairs, 3) float
 
 
+Box = Tuple[float, float, float]
+
+
+def box_lengths(box: Union[float, Sequence[float]]) -> Box:
+    """The periodic box's edge lengths along x, y, z, from one length (a
+    cube) or three; a tuple, so program caches can key on it."""
+    lengths = np.ravel(np.asarray(box, np.float64))
+    if lengths.size == 1:
+        lengths = np.repeat(lengths, 3)
+    if lengths.size != 3 or not np.all(lengths > 0):
+        raise ValueError(f"a box is one positive length or three, got "
+                         f"{box!r}")
+    return tuple(float(x) for x in lengths)
+
+
+def per_axis(values: Sequence[float]) -> np.ndarray:
+    """``values`` as a float32 (3,) array that broadcasts per axis against
+    (…, 3) positions; for a cube every element computes the bits the one
+    length did."""
+    return np.asarray(values, np.float32)
+
+
+def wrap_positions(pos, box):
+    """Traced positions (…, 3) wrapped into the periodic box."""
+    return jnp.mod(pos, per_axis(box_lengths(box)))
+
+
 @dataclass(frozen=True)
 class GridSpec:
-    box: float
-    ncells_side: int
+    box: Box
+    dims: Tuple[int, int, int]     # cells along x, y, z
     capacity: int
 
     @property
     def ncells(self) -> int:
-        return self.ncells_side ** 3
+        return int(np.prod(self.dims))
 
     @property
-    def cell_size(self) -> float:
-        return self.box / self.ncells_side
+    def cell_size(self) -> Box:
+        return tuple(L / n for L, n in zip(self.box, self.dims))
 
 
-def choose_grid(box: float, h_max: float, num_particles: int, *,
+def choose_grid(box, h_max: float, num_particles: int, *,
                 capacity_margin: float = 2.5,
                 min_capacity: int = 8) -> GridSpec:
-    """Pick cells/side so cell edge ≥ h_max, and a padded capacity sized for
-    the mean occupancy with head-room (clustered ICs are rebalanced by the
-    recursive split in SWIFT; here extra-dense cells simply raise capacity)."""
-    ncells_side = max(int(np.floor(box / max(h_max, 1e-12))), 1)
-    ncells = ncells_side ** 3
+    """Pick cells per axis so every cell edge is ≥ h_max, and a padded
+    capacity sized for the mean occupancy with head-room (clustered ICs are
+    rebalanced by the recursive split in SWIFT; here extra-dense cells
+    simply raise capacity)."""
+    box = box_lengths(box)
+    dims = tuple(max(int(np.floor(L / max(h_max, 1e-12))), 1) for L in box)
+    ncells = int(np.prod(dims))
     mean_occ = num_particles / ncells
     cap = int(np.ceil(mean_occ * capacity_margin))
     cap = max(cap, min_capacity)
     # round capacity up to a multiple of 8 (TPU sublane)
     cap = ((cap + 7) // 8) * 8
-    return GridSpec(box=box, ncells_side=ncells_side, capacity=cap)
+    return GridSpec(box=box, dims=dims, capacity=cap)
 
 
 def bin_particles(spec: GridSpec, pos: np.ndarray, vel: np.ndarray,
@@ -85,12 +118,17 @@ def bin_particles(spec: GridSpec, pos: np.ndarray, vel: np.ndarray,
     Raises if a cell overflows and ``grow`` is False; otherwise capacity is
     grown to fit (keeps physics exact for pathological clustering).
     """
-    n = len(pos)
-    posw = np.mod(pos, spec.box)
-    idx3 = np.floor(posw / spec.cell_size).astype(np.int64)
-    idx3 = np.clip(idx3, 0, spec.ncells_side - 1)
-    flat = (idx3[:, 0] * spec.ncells_side + idx3[:, 1]) * spec.ncells_side \
-        + idx3[:, 2]
+    # lengths in the positions' own dtype, as in the device program's
+    # crossing check; clipped and flattened in place (this runs every cycle)
+    posw = np.mod(pos, np.asarray(spec.box, pos.dtype))
+    idx3 = np.floor(posw / np.asarray(spec.cell_size, pos.dtype)
+                    ).astype(np.int64)
+    np.minimum(idx3, np.asarray(spec.dims) - 1, out=idx3)
+    np.maximum(idx3, 0, out=idx3)
+    _, ny, nz = spec.dims
+    flat = idx3[:, 0] * (ny * nz)
+    flat += idx3[:, 1] * nz
+    flat += idx3[:, 2]
     counts = np.bincount(flat, minlength=spec.ncells)
     cap = spec.capacity
     if counts.max() > cap:
@@ -146,16 +184,19 @@ _HALF_STENCIL = [(dz, dy, dx)
 
 def build_pair_list(spec: GridSpec, *, include_self: bool = True) -> PairList:
     """Half-stencil periodic cell-pair list with image shifts."""
-    ns = spec.ncells_side
-    box = spec.box
+    nx, ny, nz = spec.dims
+    Lx, Ly, Lz = spec.box
+    # only an axis of one cell can wrap a neighbour onto its own cell;
+    # tested once here, not per entry (this loop runs every rebin)
+    tiny = min(spec.dims) <= 2
     ci_l, cj_l, sh_l = [], [], []
 
     def flat(i, j, k):
-        return (i * ns + j) * ns + k
+        return (i * ny + j) * nz + k
 
-    for i in range(ns):
-        for j in range(ns):
-            for k in range(ns):
+    for i in range(nx):
+        for j in range(ny):
+            for k in range(nz):
                 c = flat(i, j, k)
                 if include_self:
                     ci_l.append(c)
@@ -165,17 +206,15 @@ def build_pair_list(spec: GridSpec, *, include_self: bool = True) -> PairList:
                     ii, jj, kk = i + dz, j + dy, k + dx
                     # periodic wrap + record the image shift of cell j
                     # relative to cell i (added to x_j to undo the wrap)
-                    sz = -box if ii >= ns else (box if ii < 0 else 0.0)
-                    sy = -box if jj >= ns else (box if jj < 0 else 0.0)
-                    sx = -box if kk >= ns else (box if kk < 0 else 0.0)
-                    n2 = flat(ii % ns, jj % ns, kk % ns)
-                    if ns <= 2 and n2 == c:
-                        continue   # tiny grids: neighbour wraps onto self
+                    sz = -Lx if ii >= nx else (Lx if ii < 0 else 0.0)
+                    sy = -Ly if jj >= ny else (Ly if jj < 0 else 0.0)
+                    sx = -Lz if kk >= nz else (Lz if kk < 0 else 0.0)
+                    n2 = flat(ii % nx, jj % ny, kk % nz)
+                    if tiny and n2 == c:
+                        continue
                     ci_l.append(c)
                     cj_l.append(n2)
-                    # shift applied to j positions: j sits at i + offset, so
-                    # the unwrapped j position is x_j − (sz, sy, sx)… sign
-                    # convention: pos_j_eff = pos_j + shift
+                    # the unwrapped j position is pos_j + shift
                     sh_l.append((-sz, -sy, -sx))
     return PairList(ci=jnp.asarray(np.array(ci_l, dtype=np.int32)),
                     cj=jnp.asarray(np.array(cj_l, dtype=np.int32)),

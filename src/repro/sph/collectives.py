@@ -59,7 +59,8 @@ from ..distributed.transport import (BucketPolicy, CompileProbe, ProgramCache,
                                      ShipSlots, Transport, pack_allgather,
                                      pack_rounds)
 from ..observability import device_metrics as dmetrics
-from .cellgrid import PairList, ParticleCells
+from .cellgrid import (PairList, ParticleCells, box_lengths, per_axis,
+                       wrap_positions)
 from .physics import force_block, sound_speed
 from .timebins import (STATE_AUX_FIELDS, STATE_CELL_FIELDS, TimeBinState,
                        _apply_final_kick, _apply_force_kick, _cycle_start,
@@ -233,7 +234,7 @@ def _split_force_pass(cells: ParticleCells, pairs: PairList, pair_mask,
 # --------------------------------------------------- fused sub-step programs
 def build_fused_substep_program(mesh, axis: str, *, mode: str,
                                 rounds: Sequence[Sequence[Tuple[int, int]]],
-                                nrows: int, K: int, cfg, box: float,
+                                nrows: int, K: int, cfg, box,
                                 final: bool = False):
     """Compile one whole force sub-step as a single shard_map program.
 
@@ -415,7 +416,7 @@ def _compact_pairs(pairs: PairList, pm, kind, nslots: int, nint: int,
 
 def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                              rounds: Sequence[Sequence[Tuple[int, int]]],
-                             nrows: int, K: int, cfg, box: float,
+                             nrows: int, K: int, cfg, box,
                              nsub_static: int, bin_delta: int,
                              activity_aware: bool = True):
     """Compile one WHOLE cycle — every sub-step — as a single lax.scan.
@@ -479,11 +480,12 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
 
     Outputs: the updated state dict (donated buffers), a per-rank counter
     dict (owned active updates, owned live pair tasks, live interior trips,
-    exported slots, live trips, pair slots the pair passes ran over,
-    compacted and skipped trips, end-of-cycle time) and the cycle's
-    accumulated device-metrics row — counters and health sentinels
-    (NaN/Inf/neg-rho flags) included, so the segment driver's one boundary
-    pull sees everything.
+    exported slots, live trips, pair slots the pair passes ran over, the
+    particle–neighbour entries with W(r, h_i) > 0 the closing trip's
+    density pass summed over the rank's rows, compacted and skipped trips,
+    end-of-cycle time) and the cycle's accumulated device-metrics row —
+    counters and health sentinels (NaN/Inf/neg-rho flags) included, so the
+    segment driver's one boundary pull sees everything.
     """
     perms = [list(rnd) for rnd in rounds]
     unroll = nsub_static if mesh.devices.flat[0].platform == "cpu" else 1
@@ -563,7 +565,7 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
         cnt0 = {k: jnp.zeros((), jnp.int32)
                 for k in ("updates", "pair_tasks", "force_substeps",
                           "exported", "live_trips", "pair_slots",
-                          "compact_trips", "skipped_trips")}
+                          "pair_hits", "compact_trips", "skipped_trips")}
         met_c0 = jnp.zeros((len(dmetrics.COUNT_COLUMNS),), jnp.int32)
         met_v0 = jnp.zeros((len(dmetrics.VALUE_COLUMNS),), jnp.float32)
         met_v0 = met_v0.at[dmetrics.VALUE_INDEX["min_rho"]].set(jnp.inf)
@@ -611,9 +613,15 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
 
                 def update(pairs_, pm_, split, closing):
                     """Density + exchange 1 + split force (as the fused
-                    path) over one pair list, the kick, then exchange 2."""
-                    rho, om, pr, cs = _substep_density_phase(
-                        std, pairs_, pm_, active, cfg=cfg)
+                    path) over one pair list, the kick, then exchange 2;
+                    with ``closing`` (the full branch) also the density
+                    pass's W > 0 entries, a sibling reduction of its sums
+                    that every full-table trip computes and the cycle's
+                    last trip alone keeps."""
+                    rho, om, pr, cs, *hits = _substep_density_phase(
+                        std, pairs_, pm_, active, cfg=cfg, hits=closing)
+                    hits = (jnp.where(is_final, hits[0], 0) if closing
+                            else jnp.zeros((), jnp.int32))
                     rho2, om2, pr2, cs2 = xchg(tbl, [rho, om, pr, cs], ev)
                     dv, du = _split_force_pass(
                         std.cells, pairs_, pm_, (rho, pr, om, cs),
@@ -643,7 +651,7 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                               stK.t_start, stK.accel, stK.dudt], ev)
                     return stK._replace(
                         cells=stK.cells._replace(vel=vel, u=uu), bins=bb,
-                        t_start=ts, accel=ac, dudt=dd), kicked
+                        t_start=ts, accel=ac, dudt=dd), kicked, hits
 
                 def full():
                     return update(pairs, pm, full_split, closing=True)
@@ -659,11 +667,11 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                                          axis)
                     branch = jnp.where(~is_final & (nlive <= S), COMPACT,
                                        FULL)
-                    stN, kicked = jax.lax.cond(branch == COMPACT, compact,
-                                               full)
+                    stN, kicked, n_hits = jax.lax.cond(branch == COMPACT,
+                                                       compact, full)
                 else:
                     branch = jnp.int32(FULL)
-                    stN, kicked = full()
+                    stN, kicked, n_hits = full()
                 # counters (owned partial sums; the driver psums on host)
                 n_upd = jnp.where(is_final, jnp.sum(maskb[:K]),
                                   jnp.sum(sub_act[:K])).astype(jnp.int32)
@@ -700,17 +708,18 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                                else tbl["e_urows"]),
                     exch_valid=ev, nexch=nexch)
                 return (stN, wake_floor(stN.bins), branch,
-                        (n_upd, n_pair, n_slots), (mrow_c, mrow_v, mrow_w))
+                        (n_upd, n_pair, n_slots, n_hits),
+                        (mrow_c, mrow_v, mrow_w))
 
             def skip():
                 # no pair pass, no exchange, no kick: every state carry,
                 # the wake floor included, stays bit-identical
                 zero = jnp.zeros((), jnp.int32)
-                return (st, wake, jnp.int32(SKIP), (zero, zero, zero),
+                return (st, wake, jnp.int32(SKIP), (zero, zero, zero, zero),
                         (jnp.zeros_like(met_c), jnp.zeros_like(met_v),
                          jnp.zeros_like(met_w)))
 
-            stN, wake_new, branch, (n_upd, n_pair, n_slots), \
+            stN, wake_new, branch, (n_upd, n_pair, n_slots, n_hits), \
                 (mrow_c, mrow_v, mrow_w) = jax.lax.cond(live, run, skip)
             cnt_new = {
                 "updates": cnt["updates"] + n_upd,
@@ -720,6 +729,7 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                 "exported": cnt["exported"] + n_slots,
                 "live_trips": cnt["live_trips"] + live.astype(jnp.int32),
                 "pair_slots": cnt["pair_slots"] + branch_slots[branch],
+                "pair_hits": cnt["pair_hits"] + n_hits,
                 "compact_trips": cnt["compact_trips"]
                 + (branch == COMPACT).astype(jnp.int32),
                 "skipped_trips": cnt["skipped_trips"]
@@ -760,8 +770,9 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
 
 def build_plan_program(mesh, axis: str, *, mode: str,
                        rounds: Sequence[Sequence[Tuple[int, int]]],
-                       nrows: int, K: int, cfg, box: float,
-                       ncells_side: int, max_depth: int, bin_delta: int,
+                       nrows: int, K: int, cfg, box,
+                       dims: Tuple[int, int, int], max_depth: int,
+                       bin_delta: int,
                        depth_headroom: int, nsub_static: int,
                        dt_max_static: Optional[float] = None):
     """Compile the between-cycles prologue of a K>1 device segment.
@@ -789,7 +800,9 @@ def build_plan_program(mesh, axis: str, *, mode: str,
     resident buffers stay live.
     """
     perms = [list(rnd) for rnd in rounds]
-    cell_size = box / ncells_side
+    box = box_lengths(box)
+    cell_size = per_axis(tuple(L / n for L, n in zip(box, dims)))
+    _, ny, nz = dims
 
     def xchg_full(tbl, fields):
         if mode == "ppermute":
@@ -811,11 +824,10 @@ def build_plan_program(mesh, axis: str, *, mode: str,
         ci, cj, pmask = tbl["ci"], tbl["cj"], tbl["pmask"]
 
         # ---- crossing sentinel (cellgrid.bin_particles' id math)
-        posw = jnp.mod(pos, box)
+        posw = wrap_positions(pos, box)
         idx3 = jnp.floor(posw / cell_size).astype(jnp.int32)
-        idx3 = jnp.clip(idx3, 0, ncells_side - 1)
-        cellid = (idx3[..., 0] * ncells_side + idx3[..., 1]) * ncells_side \
-            + idx3[..., 2]
+        idx3 = jnp.clip(idx3, 0, jnp.asarray(dims, jnp.int32) - 1)
+        cellid = (idx3[..., 0] * ny + idx3[..., 1]) * nz + idx3[..., 2]
         crossed = jax.lax.psum(
             jnp.sum((cellid[:K] != tbl["rowcell"][:K, None]) & maskb[:K]
                     ).astype(jnp.int32), axis)

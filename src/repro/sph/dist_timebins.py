@@ -234,7 +234,7 @@ class DistTimeBinSimulation(TimeBinSimulation):
     each cycle so long runs stay bounded).
     """
 
-    def __init__(self, pos, vel, mass, u, h, *, box: float,
+    def __init__(self, pos, vel, mass, u, h, *, box,
                  cfg: SPHConfig = SPHConfig(),
                  nranks: int = 1,
                  activity_aware: bool = True,
@@ -1438,7 +1438,7 @@ class DistTimeBinSimulation(TimeBinSimulation):
         return t.programs.get(key, lambda: build_plan_program(
             t.mesh, t.axis, mode=t.mode, rounds=t.rounds, nrows=nrows, K=K,
             cfg=self.cfg, box=self.box,
-            ncells_side=self.spec.ncells_side, max_depth=self.max_depth,
+            dims=self.spec.dims, max_depth=self.max_depth,
             bin_delta=self.bin_delta, depth_headroom=self.depth_headroom,
             nsub_static=nsub_static, dt_max_static=self.dt_max))
 
@@ -1579,7 +1579,10 @@ class DistTimeBinSimulation(TimeBinSimulation):
         """Per-cycle stats of a finished segment from its boundary pull,
         and the engine's counters advanced by them. ``npairs`` is the
         padded length of a rank's pair table: ``pair_slots``, the slots the
-        cycle's pair passes ran over, is at most ``substeps`` times it."""
+        cycle's pair passes ran over, is at most ``substeps`` times it.
+        Each slot is a (``capacity`` × ``capacity``) block per direction;
+        ``pair_hits`` entries of the closing trip's table had
+        W(r, h_i) > 0 in its density pass."""
         K_cycles = self.segment_cycles
         nreal = ctx["nreal"]
         cut_slots = plan.cut_slots
@@ -1620,6 +1623,8 @@ class DistTimeBinSimulation(TimeBinSimulation):
                 "halo_full_slots": full_j,
                 "pair_slots": int(cnt["pair_slots"][0]),
                 "pair_table_slots": npairs,
+                "pair_hits": int(cnt["pair_hits"][0]),
+                "capacity": int(ctx["mask_host"].shape[1]),
                 "compact_trips": int(cnt["compact_trips"][0]),
                 "skipped_trips": int(cnt["skipped_trips"][0]),
                 "nranks": plan.nranks,

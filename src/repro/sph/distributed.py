@@ -36,7 +36,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core import CostModel, decompose_cells
-from .cellgrid import GridSpec, PairList, ParticleCells
+from .cellgrid import GridSpec, PairList, ParticleCells, wrap_positions
 from .engine import SPHConfig, build_taskgraph
 from .physics import density_block, force_block, ghost_update
 
@@ -310,7 +310,7 @@ def _safe_halo_fields(h_rho, h_om):
     return h_rho, h_om
 
 
-def make_dist_step(mesh: Mesh, plan: DistPlan, cfg: SPHConfig, box: float,
+def make_dist_step(mesh: Mesh, plan: DistPlan, cfg: SPHConfig, box,
                    *, axis: str = "data", halo: str = "allgather"):
     """Build the jitted distributed KDK step (and force initialiser).
 
@@ -353,7 +353,7 @@ def make_dist_step(mesh: Mesh, plan: DistPlan, cfg: SPHConfig, box: float,
         mask3 = cells.mask[..., None]
         v_half = cells.vel + 0.5 * dt * accel
         u_half = jnp.maximum(cells.u + 0.5 * dt * dudt, 1e-12)
-        pos = jnp.mod(cells.pos + dt * v_half * mask3, box)
+        pos = wrap_positions(cells.pos + dt * v_half * mask3, box)
         cells = cells._replace(pos=pos, vel=v_half, u=u_half)
         dv, du, rho = local_forces(cells, ex_slots, ex_valid, im_flat,
                                    im_valid, p_recv, p_src, p_shift, p_w,

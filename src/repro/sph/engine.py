@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from ..core import CostModel, TaskGraph
 from ..observability.tracer import NULL_TRACER
 from .cellgrid import GridSpec, PairList, ParticleCells, bin_particles, \
-    build_pair_list, choose_grid, unbin
+    box_lengths, build_pair_list, choose_grid, unbin, wrap_positions
 from .physics import GAMMA, DensityResult, ForceResult, cfl_timestep_block, \
     density_block, force_block, ghost_update, smoothing_length_update
 from .smoothing import get_kernel
@@ -55,18 +55,28 @@ class SPHConfig:
 
 # --------------------------------------------------------------- wave passes
 def _density_pass(cells: ParticleCells, pairs: PairList, cfg: SPHConfig,
-                  pair_mask: Optional[jax.Array] = None):
+                  pair_mask: Optional[jax.Array] = None, *,
+                  hits: bool = False):
     """All density_self/density_pair tasks as two batched ops.
 
     ``pair_mask`` (npairs,) zeroes the contributions of masked pair tasks —
     used by the time-bin engine, which pads level-restricted pair lists to
     fixed power-of-two lengths so sub-steps reuse compiled programs.
+
+    With ``hits`` a fourth value is returned: the particle–neighbour entries
+    with W(r, h_i) > 0 summed into real particles' densities (int32),
+    reduced straight from the pair blocks' neighbour counts, beside the
+    sums and without a scatter of its own.
     """
     if cfg.use_pallas:
         from ..kernels.sph_pair import ops as pair_ops
-        return pair_ops.density_pairs(cells, pairs, kernel=cfg.kernel,
-                                      interpret=cfg.pallas_interpret,
-                                      pair_mask=pair_mask)
+        out = pair_ops.density_pairs(cells, pairs, kernel=cfg.kernel,
+                                     interpret=cfg.pallas_interpret,
+                                     pair_mask=pair_mask)
+        if hits:
+            nngb = jnp.where(cells.mask > 0, out[2], 0.0)
+            return (*out, jnp.sum(nngb.astype(jnp.int32)))
+        return out
 
     pos_i = cells.pos[pairs.ci]                        # (P, C, 3)
     pos_j = cells.pos[pairs.cj] + pairs.shift[:, None, :]
@@ -91,6 +101,11 @@ def _density_pass(cells: ParticleCells, pairs: PairList, cfg: SPHConfig,
     rho = scatter(dij.rho, dji.rho)
     drho_dh = scatter(dij.drho_dh, dji.drho_dh)
     nngb = scatter(dij.nngb, dji.nngb)
+    if hits:
+        count = lambda n, k, w: jnp.sum(  # noqa: E731
+            jnp.sum(n * k * w, axis=-1).astype(jnp.int32))
+        return rho, drho_dh, nngb, (count(dij.nngb, k_i, live)
+                                    + count(dji.nngb, k_j, notself * live))
     return rho, drho_dh, nngb
 
 
@@ -157,7 +172,7 @@ def init_state(cells: ParticleCells, pairs: PairList,
                     time=jnp.zeros((), cells.pos.dtype))
 
 
-def step(state: SPHState, pairs: PairList, dt, box: float,
+def step(state: SPHState, pairs: PairList, dt, box,
          cfg: SPHConfig) -> SPHState:
     """One KDK leapfrog step (kick and drift are SWIFT's integrator tasks)."""
     cells = state.cells
@@ -166,7 +181,7 @@ def step(state: SPHState, pairs: PairList, dt, box: float,
     v_half = cells.vel + 0.5 * dt * state.accel
     u_half = jnp.maximum(cells.u + 0.5 * dt * state.dudt, 1e-12)
     # D: drift
-    pos = jnp.mod(cells.pos + dt * v_half * mask3, box)
+    pos = wrap_positions(cells.pos + dt * v_half * mask3, box)
     cells = cells._replace(pos=pos, vel=v_half, u=u_half)
     # re-evaluate forces at the new positions
     dv, du, rho, nngb = compute_accelerations(cells, pairs, cfg)
@@ -200,7 +215,7 @@ def cfl_timestep(state: SPHState, cfg: SPHConfig) -> jax.Array:
 
 
 @functools.lru_cache(maxsize=None)
-def shared_step_program(box: float, cfg: SPHConfig):
+def shared_step_program(box, cfg: SPHConfig):
     """One jitted step program per (box, physics config), shared by every
     :class:`Simulation` instance. A per-instance ``jax.jit(partial(...))``
     gives each engine its own jit cache, so a fleet of same-signature
@@ -318,7 +333,7 @@ class Simulation:
        SimulationSpec(integrator="global", backend="local"))``.
     """
 
-    def __init__(self, pos, vel, mass, u, h, *, box: float,
+    def __init__(self, pos, vel, mass, u, h, *, box,
                  cfg: SPHConfig = SPHConfig(),
                  capacity_margin: float = 3.0,
                  rebin_every: int = 1):
@@ -328,7 +343,7 @@ class Simulation:
                 "use repro.sph.build_simulation(SimulationSpec(...)) "
                 "(integrator='global', backend='local')",
                 DeprecationWarning, stacklevel=2)
-        self.box = float(box)
+        self.box = box_lengths(box)
         self.cfg = cfg
         self.n = len(pos)
         self.rebin_every = rebin_every

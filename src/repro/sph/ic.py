@@ -75,6 +75,57 @@ def sedov_ic(n_side: int, *, box: float = 1.0, e0: float = 1.0,
     return ic
 
 
+def _lattice(counts, spacing, origin_x, rng, jitter):
+    """Jittered lattice of ``counts`` points along x, y, z at ``spacing``,
+    starting at x = ``origin_x``; the jitter is ``jitter`` of the spacing."""
+    axes = [(np.arange(c) + 0.5) * spacing for c in counts]
+    pos = np.stack(np.meshgrid(*axes, indexing="ij"), -1).reshape(-1, 3)
+    pos[:, 0] += origin_x
+    return pos + jitter * spacing * rng.standard_normal(pos.shape)
+
+
+def sodshock_ic(n_side: int, *, rho_l: float = 1.0, p_l: float = 1.0,
+                rho_r: float = 0.125, p_r: float = 0.1,
+                gamma: float = 5.0 / 3.0, jitter: float = 0.02,
+                seed: int = 0,
+                n_target: float = 48.0) -> Dict[str, np.ndarray]:
+    """SWIFT's 3-D Sod shock tube (``examples/HydroTests/SodShock_3D``).
+
+    A periodic 2 × 0.5 × 0.5 box: gas at rest at density ``rho_l`` and
+    pressure ``p_l`` on x ∈ [0, 1), at ``rho_r`` and ``p_r`` on x ∈ [1, 2),
+    so there are two interfaces, x = 1 and the periodic one at x = 0 ≡ 2.
+    Each half is a jittered lattice (SWIFT tiles two glass cubes): the left
+    2·n_side × n_side × n_side, the right at twice the spacing, which holds
+    the published density ratio 8 with particles of one mass. Each half's
+    ``h`` follows its own spacing as :func:`uniform_ic` sets it, so the
+    sparse side's is twice the dense side's.
+    """
+    if n_side % 2:
+        raise ValueError(f"sodshock needs an even n_side (the sparse "
+                         f"lattice has half its count per axis), got "
+                         f"{n_side}")
+    rng = np.random.default_rng(seed)
+    box = (2.0, 0.5, 0.5)
+    halves = []
+    for n, x0, rho, press in ((n_side, 0.0, rho_l, p_l),
+                              (n_side // 2, 1.0, rho_r, p_r)):
+        spacing = 0.5 / n
+        pos = _lattice((2 * n, n, n), spacing, x0, rng, jitter)
+        count = len(pos)
+        halves.append({
+            "pos": np.mod(pos, box),
+            "mass": np.full(count, rho * 0.25 / count),
+            "u": np.full(count, press / ((gamma - 1.0) * rho)),
+            "h": np.full(count, spacing
+                         * (3.0 * n_target / (4.0 * np.pi)) ** (1 / 3)),
+        })
+    ic = {k: np.concatenate([hv[k] for hv in halves]).astype(np.float32)
+          for k in halves[0]}
+    ic["vel"] = np.zeros_like(ic["pos"])
+    ic["box"] = box
+    return ic
+
+
 def kelvin_helmholtz_ic(n_side: int, *, box: float = 1.0,
                         v_shear: float = 0.5, u0: float = 1.0,
                         perturb: float = 0.05, modes: int = 2,

@@ -1,11 +1,11 @@
 """O(N²) brute-force SPH oracle (and bulk-synchronous baseline stand-in).
 
 Direct evaluation of eqs. (2)–(4) over all particle pairs with periodic
-minimum-image distances. This is the ground truth the cell/task engine and
-the Pallas kernels are validated against, and doubles as the
-"traditional code" baseline in ``benchmarks/baseline_compare.py`` (GADGET-2
-fills that role in the paper; an O(N²)-masked dense evaluation is its
-honest stand-in at test scale).
+minimum-image distances, per axis of the box. This is the ground truth the
+cell/task engine and the Pallas kernels are validated against, and doubles
+as the "traditional code" baseline in ``benchmarks/baseline_compare.py``
+(GADGET-2 fills that role in the paper; an O(N²)-masked dense evaluation is
+its honest stand-in at test scale).
 """
 
 from __future__ import annotations
@@ -15,11 +15,13 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from .cellgrid import box_lengths, per_axis
 from .physics import EPS, GAMMA, eos_pressure, sound_speed
 from .smoothing import get_kernel
 
 
 def _min_image(dx, box):
+    box = per_axis(box_lengths(box))
     return dx - box * jnp.round(dx / box)
 
 

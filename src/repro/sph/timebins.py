@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from ..observability import device_metrics as dmetrics
 from ..observability.tracer import NULL_TRACER
 from .cellgrid import GridSpec, PairList, ParticleCells, bin_particles, \
-    build_pair_list, choose_grid, unbin
+    box_lengths, build_pair_list, choose_grid, unbin, wrap_positions
 from .engine import SPHConfig, _density_pass, _force_pass
 from .physics import cfl_timestep_block, ghost_update
 
@@ -323,16 +323,17 @@ def _cycle_start(state: TimeBinState, dt_max, *, cfg: SPHConfig
     return state._replace(cells=cells, t_start=t_start)
 
 
-def _drift(state: TimeBinState, dt_min, *, box: float) -> TimeBinState:
+def _drift(state: TimeBinState, dt_min, *, box) -> TimeBinState:
     """Drift *all* particles: position-only prediction for inactive ones."""
     cells = state.cells
-    pos = jnp.mod(cells.pos + dt_min * cells.vel * cells.mask[..., None], box)
+    pos = wrap_positions(
+        cells.pos + dt_min * cells.vel * cells.mask[..., None], box)
     return state._replace(cells=cells._replace(pos=pos),
                           time=state.time + dt_min)
 
 
 def _substep_density_phase(state: TimeBinState, pairs: PairList, pair_mask,
-                           active, *, cfg: SPHConfig):
+                           active, *, cfg: SPHConfig, hits: bool = False):
     """Density half of a bin-boundary update (the paper's first comm phase).
 
     Computes fresh rho/omega for the ``active`` particles (stored values are
@@ -341,11 +342,15 @@ def _substep_density_phase(state: TimeBinState, pairs: PairList, pair_mask,
     distributed engine inserts the rho/press halo exchange between this
     phase and :func:`_substep_force_phase`; the single-host engine composes
     them back-to-back inside one jitted program.
+
+    With ``hits`` a fifth value is returned: the particle–neighbour entries
+    with W(r, h_i) > 0 that the pass summed into the real particles' sums
+    (int32, ``engine._density_pass``).
     """
     cells = state.cells
     mask = cells.mask
-    rho_new, drho_dh, nngb = _density_pass(cells, pairs, cfg,
-                                           pair_mask=pair_mask)
+    sums = _density_pass(cells, pairs, cfg, pair_mask=pair_mask, hits=hits)
+    rho_new, drho_dh = sums[0], sums[1]
     rho_new = jnp.where(mask > 0, rho_new, 1.0)
     drho_dh = jnp.where(mask > 0, drho_dh, 0.0)
     rho = jnp.where(active > 0, rho_new, state.rho)
@@ -353,6 +358,8 @@ def _substep_density_phase(state: TimeBinState, pairs: PairList, pair_mask,
                                         gamma=cfg.gamma)
     omega = jnp.where(active > 0, omega_new, state.omega)
     press = jnp.where(mask > 0, press, 0.0)
+    if hits:
+        return rho, omega, press, cs, sums[3]
     return rho, omega, press, cs
 
 
@@ -475,7 +482,7 @@ def _force_final(state: TimeBinState, pairs: PairList, pair_mask, dt_max,
 
 
 @functools.lru_cache(maxsize=None)
-def shared_timebin_programs(box: float, cfg: SPHConfig) -> Dict[str, object]:
+def shared_timebin_programs(box, cfg: SPHConfig) -> Dict[str, object]:
     """The five jitted ladder programs per (box, physics config), shared by
     every :class:`TimeBinSimulation` instance (same rationale as
     ``engine.shared_step_program``: a fleet of same-signature requests must
@@ -504,7 +511,7 @@ class TimeBinSimulation:
 
     tracer = NULL_TRACER        # rebound when observe=True
 
-    def __init__(self, pos, vel, mass, u, h, *, box: float,
+    def __init__(self, pos, vel, mass, u, h, *, box,
                  cfg: SPHConfig = SPHConfig(),
                  dt_max: Optional[float] = None,
                  max_depth: int = MAX_DEPTH_DEFAULT,
@@ -519,7 +526,7 @@ class TimeBinSimulation:
                 "deprecated; use repro.sph.build_simulation("
                 "SimulationSpec(...)) (integrator='timebin', "
                 "backend='local')", DeprecationWarning, stacklevel=2)
-        self.box = float(box)
+        self.box = box_lengths(box)
         self.cfg = cfg
         self.n = len(pos)
         self.dt_max = dt_max

@@ -11,7 +11,7 @@ from repro.core import (CostModel, bin_occupancy_imbalance, insert_comm_tasks,
                         rank_bin_occupancy, TaskGraph)
 from repro.sph import (SCENARIOS, SimulationProtocol, SimulationSpec, SPHConfig,
                        build_simulation, kelvin_helmholtz_ic, make_ic,
-                       register_scenario, sedov_ic)
+                       register_scenario, sedov_ic, sodshock_ic)
 from repro.sph.dist_timebins import build_rank_plan, halo_export_schedule
 
 
@@ -71,6 +71,27 @@ def test_kelvin_helmholtz_ic_structure():
     assert np.abs(ic["vel"][:, 2]).max() < 0.5 * 0.5  # but subdominant
     # uniform density: one equal-mass lattice
     assert np.allclose(ic["mass"], ic["mass"][0])
+
+
+def test_sodshock_ic_structure():
+    ic = make_ic("sodshock", n_side=14, seed=1)
+    assert ic["box"] == (2.0, 0.5, 0.5)
+    n = len(ic["pos"])
+    assert n == 2 * 14 ** 3 + 2 * 7 ** 3
+    assert np.all(ic["pos"] >= 0) and np.all(ic["pos"] < ic["box"])
+    left = np.arange(n) < 2 * 14 ** 3
+    # the published states on two lattices of one particle mass
+    np.testing.assert_allclose(ic["mass"], ic["mass"][0])
+    np.testing.assert_allclose(ic["mass"].sum(), 0.5 * (1.0 + 0.125) / 2,
+                               rtol=1e-6)
+    for side, rho, press in ((left, 1.0, 1.0), (~left, 0.125, 0.1)):
+        np.testing.assert_allclose(ic["u"][side], press / (rho * 2 / 3),
+                                   rtol=1e-6)
+    np.testing.assert_allclose(ic["h"][~left], 2 * ic["h"][left][0])
+    assert np.all(ic["vel"] == 0)
+    assert np.mean(ic["pos"][left, 0] < 1.0) > 0.99
+    with pytest.raises(ValueError, match="even n_side"):
+        sodshock_ic(7)
 
 
 # ------------------------------------------------------------- the quadrants

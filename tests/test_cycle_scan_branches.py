@@ -42,6 +42,13 @@ CASES = {
         scenario_params={"n_side": 12, "v_shear": 0.5, "seed": 0},
         physics=SPHConfig(alpha_visc=1.0, cfl=0.2), dt_max=0.05,
         max_depth=2),
+    # the shock tube's 2 x 0.5 x 0.5 box, 12 x 3 x 3 cells: the dense half
+    # in bin 2, the sparse half in bin 1; each live trip holds the dense
+    # half's pairs, far more than the 64-slot bucket, the rest skip
+    "sod_box": dict(
+        scenario="sodshock", scenario_params={"n_side": 14, "seed": 0},
+        physics=SPHConfig(alpha_visc=0.8, cfl=0.1), dt_max=0.02,
+        max_depth=4),
 }
 
 
@@ -93,15 +100,23 @@ def test_cycle_scan_branches_bitwise_one_rank(case):
         assert s["pair_slots"] == trips_full * table \
             + s["compact_trips"] * bucket
         assert s["compact_trips"] + trips_full == s["force_substeps"]
+        # the closing trip's density entries with W > 0: at most the
+        # entries of the whole table it evaluated
+        assert 0 < s["pair_hits"] \
+            <= 2 * s["pair_table_slots"] * s["capacity"] ** 2
         full += trips_full
     compact = sum(s["compact_trips"] for s in stats)
     skipped = sum(s["skipped_trips"] for s in stats)
     if case == "sedov_sparse":
         assert compact >= 1 and skipped >= 1
         assert full > NCYCLES          # an interior trip overflowed
-    else:
+    elif case == "kh_dense":
         assert compact == 0 and skipped == 0
         assert full == sum(s["substeps"] for s in stats)
+    else:
+        assert stats[0]["bin_hist"].tolist() == [0, 686, 5488, 0, 0]
+        assert compact == 0 and skipped >= NCYCLES
+        assert full == sum(s["force_substeps"] for s in stats)
 
 
 _FOUR_RANK_BRANCHES = """
@@ -124,7 +139,7 @@ def stats(self, ctx, plan, npairs, cnt, *rest):
         for c in cnt)
     return real(self, ctx, plan, npairs, cnt, *rest)
 DistTimeBinSimulation._segment_stats = stats
-kw = dict(CASES["sedov_sparse"], integrator="timebin")
+kw = dict(CASES[{case!r}], integrator="timebin")
 out = {{"ranks": ranks, "runs": [], "unequal": []}}
 snaps = []
 for spec in (SimulationSpec(**kw, backend="local"),
@@ -151,20 +166,24 @@ print(json.dumps(out))
 """
 
 
+def _four_ranks(case: str) -> dict:
+    here = os.path.dirname(os.path.abspath(__file__))
+    script = _FOUR_RANK_BRANCHES.format(
+        src=os.path.join(here, "..", "src"), tests=here, case=case)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.splitlines()[-1])
+
+
 def test_cycle_scan_branches_four_ranks():
     """Four ranks on four emulated devices, the bucket widened to a quarter
     of each rank's table (the production 1/32 would need n_side ~40 to hold
     the 27-cell hot core): every rank takes the same branch on every trip,
     the compacted trips carry cut pairs too (the hot core straddles the
     ranks), and the state at each boundary is bitwise the host ladder's."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    script = _FOUR_RANK_BRANCHES.format(
-        src=os.path.join(here, "..", "src"), tests=here)
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, "-c", script], env=env,
-                          capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    out = json.loads(proc.stdout.splitlines()[-1])
+    out = _four_ranks("sedov_sparse")
     assert len(out["ranks"]) == NCYCLES
     for per_rank in out["ranks"]:
         for k, vals in per_rank.items():
@@ -176,3 +195,20 @@ def test_cycle_scan_branches_four_ranks():
             assert g[k] == w[k], (c, k)
     assert sum(g["compact_trips"] for g in got) >= 1
     assert sum(g["skipped_trips"] for g in got) >= 1
+
+
+def test_cycle_scan_branches_four_ranks_long_box():
+    """The shock tube's 2 x 0.5 x 0.5 box over four ranks of its 12 x 3 x 3
+    cells: the partition, halos and cut pairs of a grid that is not a cube,
+    bitwise the host ladder's at each boundary, every rank on one branch."""
+    out = _four_ranks("sod_box")
+    assert len(out["ranks"]) == NCYCLES
+    for per_rank in out["ranks"]:
+        for k, vals in per_rank.items():
+            assert len(vals) == 4 and len(set(vals)) == 1, (k, vals)
+    assert out["unequal"] == []
+    want, got = out["runs"]
+    for c, (w, g) in enumerate(zip(want, got)):
+        for k in ("updates", "pair_tasks", "force_substeps"):
+            assert g[k] == w[k], (c, k)
+    assert all(g["skipped_trips"] >= 1 for g in got)

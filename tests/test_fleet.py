@@ -239,6 +239,17 @@ class TestBatchedParity:
                     np.asarray(r.result.particles[k]),
                     np.asarray(ref.particles[k]))
 
+    def test_box_of_three_lengths_is_refused(self):
+        """The batched path stacks cubic grids of one edge length; a shock
+        tube's 2 x 0.5 x 0.5 box is refused with a clear error."""
+        spec = SimulationSpec(scenario="sodshock",
+                              scenario_params={"n_side": 8})
+        runner = FleetRunner(fleet_devices=1)
+        req = runner.submit(spec, n_steps=1)
+        with pytest.raises(ValueError, match="cubic boxes of one edge"):
+            runner.drain()
+        assert req.state is RequestState.FAILED
+
     def test_timebin_quadrant_served_sequentially(self):
         kw = dict(SCENARIOS["sedov"])
         spec = SimulationSpec(integrator="timebin", **kw)
