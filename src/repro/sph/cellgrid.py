@@ -137,18 +137,19 @@ def bin_particles(spec: GridSpec, pos: np.ndarray, vel: np.ndarray,
                 f"cell overflow: max occupancy {counts.max()} > capacity {cap}")
         cap = int(((counts.max() + 7) // 8) * 8)
     perm = np.full((spec.ncells, cap), -1, dtype=np.int64)
-    slot = np.zeros(spec.ncells, dtype=np.int64)
+    # one scatter: the stably sorted particles of a cell take its slots
+    # 0, 1, … in index order (slot = rank in the sort − the cell's start)
     order = np.argsort(flat, kind="stable")
-    for p in order:
-        c = flat[p]
-        perm[c, slot[c]] = p
-        slot[c] += 1
+    cell = flat[order]
+    starts = np.cumsum(counts) - counts
+    perm[cell, np.arange(len(order)) - starts[cell]] = order
+    valid = perm >= 0
+    idx = perm[valid]
 
     def take(arr, fill):
         out = np.full((spec.ncells, cap) + arr.shape[1:], fill,
                       dtype=np.float32)
-        valid = perm >= 0
-        out[valid] = arr[perm[valid]]
+        out[valid] = arr[idx]
         return out
 
     cells = ParticleCells(
@@ -157,7 +158,7 @@ def bin_particles(spec: GridSpec, pos: np.ndarray, vel: np.ndarray,
         mass=jnp.asarray(take(mass.astype(np.float32)[:, None], 0.0)[..., 0]),
         u=jnp.asarray(take(u.astype(np.float32)[:, None], 0.0)[..., 0]),
         h=jnp.asarray(take(h.astype(np.float32)[:, None], 1e-6)[..., 0]),
-        mask=jnp.asarray((perm >= 0).astype(np.float32)),
+        mask=jnp.asarray(valid.astype(np.float32)),
     )
     return cells, perm
 
@@ -187,7 +188,7 @@ def build_pair_list(spec: GridSpec, *, include_self: bool = True) -> PairList:
     nx, ny, nz = spec.dims
     Lx, Ly, Lz = spec.box
     # only an axis of one cell can wrap a neighbour onto its own cell;
-    # tested once here, not per entry (this loop runs every rebin)
+    # tested once here, not per entry
     tiny = min(spec.dims) <= 2
     ci_l, cj_l, sh_l = [], [], []
 

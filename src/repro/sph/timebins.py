@@ -541,6 +541,10 @@ class TimeBinSimulation:
         h_max = float(np.max(h))
         self.spec = choose_grid(self.box, h_max, self.n,
                                 capacity_margin=capacity_margin)
+        # the pair list depends on the grid's dims and box alone, which no
+        # rebin changes: it is built once, and this counts the builds
+        self.pair_list_builds = 0
+        self._pairs_key = None
         self._rebin(np.asarray(pos), np.asarray(vel), np.asarray(mass),
                     np.asarray(u), np.asarray(h))
         progs = shared_timebin_programs(self.box, cfg)
@@ -580,10 +584,16 @@ class TimeBinSimulation:
                 object.__setattr__(self.spec, "capacity",
                                    self.cells.mass.shape[1])
         with tr.span("rebin.pair_list"):
-            self.pairs = build_pair_list(self.spec)
-            self._ci = np.asarray(self.pairs.ci)
-            self._cj = np.asarray(self.pairs.cj)
-            self._shift = np.asarray(self.pairs.shift)
+            # keyed on the grid, not the spec: a rebin grows the spec's
+            # capacity in place, so its hash is not stable
+            key = (self.spec.dims, self.spec.box)
+            if key != self._pairs_key:
+                self.pairs = build_pair_list(self.spec)
+                self._ci = np.asarray(self.pairs.ci)
+                self._cj = np.asarray(self.pairs.cj)
+                self._shift = np.asarray(self.pairs.shift)
+                self._pairs_key = key
+                self.pair_list_builds += 1
 
     def _flatten_aux(self, arr, fill) -> np.ndarray:
         valid = self.perm >= 0
