@@ -1,4 +1,4 @@
-"""Host vs collective vs fused-resident wire cost per force sub-step (Sedov).
+"""Host vs collective vs device-resident wire cost per force sub-step (Sedov).
 
 The distributed time-bin engine runs the same physics over three execution
 paths (bit-for-bit identical states, asserted below):
@@ -6,16 +6,15 @@ paths (bit-for-bit identical states, asserted below):
 * ``transport="host"`` — numpy row copies between per-rank phase programs;
 * ``transport="collective"`` — shard_map/ppermute exchange programs, but
   rank states still round-trip through host between the phase programs;
-* ``transport="collective", residency="device"`` — the fused path: states
-  stay resident on the mesh for the whole cycle and each force sub-step is
-  one compiled program.
+* ``transport="collective", residency="device"`` — the fused path, the
+  device cycle scan: states stay resident on the mesh for the whole cycle
+  and each cycle is one compiled program that schedules its own sub-steps.
 
 For each path the benchmark reports wall time per cycle / per force
 sub-step and the **host-transfer bytes** per force sub-step: for the first
 two, the full-field device→host→device round trips their wires pay
 (``transport.stats()["host_bytes"]``); for the fused path, the transfer
-probe's intra-cycle ledger — control tables and flags only, with
-``state_bytes`` asserted 0.
+probe's intra-cycle ledger — empty, with ``state_bytes`` asserted 0.
 
 Every path gets the same fixed warm-up (``max_warm`` cycles — enough for
 the program caches to quiesce at the default size), so all paths are
@@ -131,8 +130,7 @@ def run(n_side=8, ncycles=3, nranks=4, max_warm=8) -> list:
             extra = (f";mode={t['mode']};rounds={t['rounds']};"
                      f"programs={t['programs']}")
         if label == "fused":
-            extra += (f";intra_state_bytes={r['intra_cycle_state_bytes']};"
-                      f"bins_refreshes={t['bins_refreshes']}")
+            extra += f";intra_state_bytes={r['intra_cycle_state_bytes']}"
         rows.append({
             "name": f"transport/{label}/us_per_force_substep",
             "us_per_call": round(r["wall_per_force_substep_us"], 1),

@@ -1,7 +1,8 @@
 """Per-phase timings of one traced Sedov run + tracer overhead (task plots).
 
 Runs the distributed time-bin engine (4 emulated ranks, collective
-transport, device residency) with ``observe=True`` for a few cycles and
+transport, device residency: the cycle scan) with ``observe=True`` for a
+few cycles and
 reports the median per-span wall time of every traced phase — the numbers
 behind the task-timeline plot — plus the cost of the tracer itself
 (median seconds per recorded span, measured over 20k no-payload spans).
@@ -9,10 +10,12 @@ behind the task-timeline plot — plus the cost of the tracer itself
 Results land in ``benchmarks/results/observability_bench.json`` and, as
 the repo-level benchmark artifact, in ``BENCH_observability.json`` at the
 repo root (per-phase medians, run provenance, metrics schema version).
-Since schema v3 the artifact also carries the attribution column: the
-per-cell work vectors' share of the single per-cycle metrics pull, the
-cost-calibration fit residual, and the repartition advisor's
-advised-vs-current imbalance (which must never regress).
+Since schema v3 the artifact also carries the attribution column: what
+the single per-cycle pull carries beyond the metrics row (the per-cell
+work vectors and the scan's counters — on the cycle scan the row rides in
+each segment's ``segment_stats`` pull), the cost-calibration fit residual,
+and the repartition advisor's advised-vs-current imbalance (which must
+never regress).
 
 Under ``JAX_PLATFORMS=cpu`` the measurement runs in a subprocess with
 4 emulated CPU devices; on an accelerator it runs in this process,
@@ -75,8 +78,9 @@ out = {
     "force_substeps": rec.get("force_substeps"),
     "device_imbalance": rec.get("device_imbalance"),
     "device_phase_units": rec.get("device_phase_units"),
-    "metrics_pulls": tstats["boundary_events"].get("metrics", 0),
-    "metrics_pull_bytes": tstats["boundary_bytes"].get("metrics", 0),
+    # the metrics row rides in each segment's one stats pull
+    "metrics_pulls": tstats["boundary_events"].get("segment_stats", 0),
+    "metrics_pull_bytes": tstats["boundary_bytes"].get("segment_stats", 0),
     "cell_work": rec.get("cell_work"),
     "cost_calibration": rec.get("cost_calibration"),
     "advisor": rec.get("advisor"),

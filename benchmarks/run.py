@@ -57,8 +57,6 @@ def main() -> None:
         ("kernels_bench", "kernels_bench"),
         ("halo_transport (host vs collective vs fused wire)",
          "halo_transport"),
-        ("fused_cycles (host- vs device-scheduled segments)",
-         "fused_cycles"),
         ("observability (task plots)", "observability_bench"),
         ("fleet_throughput (batched serving)", "fleet_throughput"),
     ]

@@ -28,9 +28,9 @@ the compiler. This module provides the generic machinery for that:
   per-field accounting of every byte the engine moves across the
   host↔device boundary (split into cycle-*boundary* traffic — scatter and
   gather — and *intra-cycle* traffic), and the named stacked device buffers
-  the fused device-resident engine keeps on the mesh between exchanges.
-  The transfer probe is the ``CompileProbe`` of the wire: tests assert the
-  fused path's intra-cycle traffic carries **zero** dynamical-state bytes.
+  the device-resident cycle scan keeps on the mesh for a segment. The
+  transfer probe is the ``CompileProbe`` of the wire: tests assert the
+  scan's intra-segment traffic is **empty**.
 * :func:`make_transport` — factory over ``"host" | "collective"`` (the
   collective implementation lives in ``repro.sph.collectives``; imported
   lazily so this layer stays free of SPH specifics).
@@ -50,11 +50,31 @@ from ..observability.tracer import NULL_TRACER
 TRANSPORTS = ("host", "collective")
 RESIDENCIES = ("host", "device")
 
+
+def schedule_of(residency: str, schedule: Optional[str] = None) -> str:
+    """Who drives a cycle's sub-step ladder, which the residency decides:
+    the host loop for host-resident state, the device cycle scan for
+    device-resident state. ``schedule``, where given, must agree."""
+    want = "device" if residency == "device" else "host"
+    if schedule is None or schedule == want:
+        return want
+    if schedule not in ("host", "device"):
+        raise ValueError(f"schedule must be 'host' or 'device', "
+                         f"got {schedule!r}")
+    if schedule == "host":
+        raise ValueError(
+            "residency='device' always runs the device cycle scan: the "
+            "host-scheduled fused sub-step ladder is gone, so "
+            "schedule='host' needs residency='host'")
+    raise ValueError(
+        "schedule='device' derives the sub-step schedule from the "
+        "device-resident bins array; it requires residency='device'")
+
+
 # the dynamical per-particle state of the time-bin engine: the arrays whose
-# intra-cycle host↔device movement the fused device-resident path eliminates.
-# ``bins`` is deliberately *not* here — it is the schedule (1 int32/particle)
-# and its host mirror is refreshed only on deepening/wake-up events, which
-# the TransferProbe counts separately.
+# intra-cycle host↔device movement the device-resident cycle scan
+# eliminates. ``bins`` is deliberately *not* here — it is the schedule
+# (1 int32/particle), which the TransferProbe counts separately.
 DYNAMIC_STATE_FIELDS = ("pos", "vel", "mass", "u", "h", "mask", "accel",
                         "dudt", "rho", "omega", "t_start", "time")
 
@@ -221,10 +241,9 @@ class TransferProbe:
     under a field name, tagged as cycle-``boundary`` traffic (the scatter at
     cycle start / gather at cycle end) or intra-cycle traffic. Tests assert
     the residency discipline on the *measured* ledger instead of trusting
-    the control flow: the fused device-resident path must show zero
-    intra-cycle bytes for every :data:`DYNAMIC_STATE_FIELDS` entry, with
-    only control-plane traffic (index ``tables``, ``flags``, and ``bins``
-    mirror refreshes on wake events) in between.
+    the control flow: the device-resident cycle scan must show no
+    intra-cycle bytes at all, none for any :data:`DYNAMIC_STATE_FIELDS`
+    entry in particular.
     """
 
     def __init__(self):
@@ -257,7 +276,7 @@ class TransferProbe:
 
 
 class ResidentBuffers:
-    """Named stacked device buffers of the fused device-resident engine.
+    """Named stacked device buffers of the device-resident cycle scan.
 
     Holds one ``(nranks, …)`` mesh-sharded array per state field for the
     duration of a cycle. The only mutation path is :meth:`update` with the
@@ -447,7 +466,7 @@ class HostTransport(Transport):
     ``host_bytes`` counts what this wire costs beyond the copies
     themselves: every exchanged field makes a device→host→device round
     trip of its *full* per-rank arrays (not just the shipped rows) — the
-    overhead the device-resident fused path exists to eliminate.
+    overhead the device-resident cycle scan exists to eliminate.
     """
 
     kind = "host"

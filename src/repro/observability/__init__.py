@@ -21,7 +21,7 @@ SWIFT, arXiv:2305.13380). This package is that loop for the XLA substrate:
   :class:`~repro.core.cost_model.CostModel`.
 * :mod:`~repro.observability.device_metrics` — the in-program telemetry
   carry (fixed-shape per-rank counter/value rows computed *inside* the
-  fused programs, accumulated on device, pulled once per cycle).
+  cycle scan, accumulated on device, pulled once per cycle).
 * :mod:`~repro.observability.flight` — last-K-cycles flight recorder +
   post-mortem dump bundles, written on any health-sentinel trip.
 

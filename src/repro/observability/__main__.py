@@ -11,7 +11,9 @@ the per-cycle metrics log, validates the trace against the minimal schema,
 and asserts the record's byte/compile counters agree exactly with the
 engine's ``TransferProbe``/``CompileProbe``. With device metrics enabled
 (the default) it additionally checks the in-program telemetry row: per-rank
-per-phase work present, exactly one ledgered ``metrics`` pull per cycle.
+per-phase work present, exactly one ledgered pull per cycle (the segment's
+``segment_stats`` pull on the device-resident cycle scan, ``metrics`` on
+the host-resident ladder).
 Exit status 0 means every check passed.
 
 The ``dump`` subcommand exercises the flight recorder end-to-end:
@@ -127,12 +129,19 @@ def main(argv=None) -> int:
     if rows != set(range(args.ranks)):
         failures.append(f"expected one row per rank 0..{args.ranks - 1}, "
                         f"got {sorted(rows)}")
-    # one phase-program slice per force sub-step on every rank
-    per_sub = ("fused_substep", "fused_final") \
-        if args.residency == "device" else ("density", "force")
     nsub = sum(r["force_substeps"] for r in obs.records)
     for r in sorted(rows):
-        got = sum(1 for e in xs if e["tid"] == r and e["name"] in per_sub)
+        if args.residency == "device":
+            # one ``launch`` of the cycle_scan program per cycle
+            got = sum(1 for e in xs if e["tid"] == r
+                      and e["name"] == "launch")
+            if got != args.cycles:
+                failures.append(f"rank {r}: {got} cycle_scan launches != "
+                                f"{args.cycles} cycles")
+            continue
+        # one phase-program slice per force sub-step
+        got = sum(1 for e in xs
+                  if e["tid"] == r and e["name"] in ("density", "force"))
         if got < nsub:
             failures.append(f"rank {r}: {got} phase slices < "
                             f"{nsub} force sub-steps")
@@ -195,9 +204,11 @@ def main(argv=None) -> int:
                 failures.append("device_imbalance missing")
             if "health" not in rec:
                 failures.append("health block missing")
-        pulls = eng.transfers.stats()["boundary_events"].get("metrics", 0)
+        # the device path's row rides in each segment's one stats pull
+        pull = "segment_stats" if args.residency == "device" else "metrics"
+        pulls = eng.transfers.stats()["boundary_events"].get(pull, 0)
         if pulls != args.cycles:
-            failures.append(f"{pulls} ledgered metrics pulls != "
+            failures.append(f"{pulls} ledgered {pull} pulls != "
                             f"{args.cycles} cycles (pull-once contract)")
 
     summary = {

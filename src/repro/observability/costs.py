@@ -2,13 +2,14 @@
 
 SWIFT refines its domain decomposition with *measured* task costs (§3.2:
 "after a task has been executed, its effective computational cost is
-computed and used"). Fully fused runs never execute tasks one at a time,
-so there is nothing to time individually — but the compiled programs do
+computed and used"). The device cycle scan never executes tasks one at
+a time, so there is nothing to time individually — but the compiled
+programs do
 attribute their work to cells (``device_metrics.measure_cells``), and the
 once-per-cycle metrics pull delivers a per-cell units vector per task
 kind. This module closes the loop on the host:
 
-* :class:`TaskCostLedger` — accumulates per-cycle (units-by-kind, fused
+* :class:`TaskCostLedger` — accumulates per-cycle (units-by-kind, program
   wall seconds) samples, keeps the direct per-kind ``CostModel.observe``
   stream flowing (so ``measured_vs_modelled`` reports from cycle one),
   and periodically runs the joint :meth:`CostModel.calibrate` fit that
@@ -51,9 +52,9 @@ class TaskCostLedger:
     """Sliding window of measured (units-by-kind, seconds) cycle samples
     feeding :meth:`CostModel.calibrate`.
 
-    ``record`` is called once per cycle on fused paths with the aggregate
-    work units (from the per-cell vectors' totals) and the deduped fused
-    program wall. It apportions the wall across kinds by unit share and
+    ``record`` is called once per cycle on the cycle scan with the
+    aggregate work units (from the per-cell vectors' totals) and the
+    program's wall as the host sees it (its ``launch`` + ``wait`` spans). It apportions the wall across kinds by unit share and
     feeds ``CostModel.observe`` — the same information the pre-calibration
     heuristic provided, so ``cost_ratios``/``observed_units`` behave
     identically — then refits the joint per-kind rates over the window.
@@ -65,7 +66,7 @@ class TaskCostLedger:
         self.cm = cost_model
         self.samples: deque = deque(maxlen=int(window))
         self.refit_every = max(int(refit_every), 1)
-        # the first cycle's fused wall is dominated by XLA compiles —
+        # the first cycle's program wall is dominated by XLA compiles —
         # feed it to observe() (pre-existing behaviour) but keep it out
         # of the calibration window, like any benchmark warmup
         self.skip_first = max(int(skip_first), 0)

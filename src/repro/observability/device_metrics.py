@@ -1,9 +1,9 @@
-"""Device-side telemetry rows: in-program counters for fused programs.
+"""Device-side telemetry rows: in-program counters for compiled programs.
 
 PR-5's tracer times phase *programs* from the host, fencing at every
 phase boundary. That goes blind exactly where the engines are headed:
-once a whole force sub-step is one fused shard_map program (and a fleet
-batch one ``jit(vmap(step))``), the host sees a single opaque span.
+once a whole cycle is one compiled shard_map scan (and a fleet batch one
+``jit(vmap(step))``), the host sees a single opaque span.
 Following SWIFT's rule that every task reports its own cost from inside
 the runtime (arXiv:1606.02738 §4) — and the in-kernel per-bin counter
 idiom of task-based runtimes — this module defines a ``DeviceMetrics``
@@ -24,8 +24,9 @@ extra output whose reductions only read values the physics already
 computes), so enabling device metrics adds **zero compiles** per shape
 signature and is bitwise invisible to the state — both pinned in
 ``tests/test_observability.py`` / ``tests/test_conformance.py``.
-Accumulation across sub-steps happens on device (eager adds on the tiny
-rows); the accumulated row is pulled **once per cycle** and ledgered
+Accumulation across sub-steps happens on device (inside the cycle
+scan's carry); the accumulated row is pulled **once per cycle** and
+ledgered
 through the engine's :class:`~repro.distributed.transport.TransferProbe`.
 
 Nothing here imports jax at module scope (package rule: the CLI must be
@@ -264,28 +265,6 @@ def cell_work_record(cell_work: Optional[Dict[str, object]]) \
         "totals": [float(x) for x in cells.sum(axis=0).tolist()],
         "ncells": int(cells.shape[0]),
     }
-
-
-def combine(acc, row, xp=np):
-    """Fold one sub-step row into a cycle accumulator.
-
-    Counts add; work-unit values add; fingerprint values take the
-    latest/extremum per ``_V_ACCUM``. Works on numpy (host paths) and,
-    with ``xp=jax.numpy``, on device arrays (eager adds on the tiny
-    rows — no host sync, no registered program).
-    """
-    counts, values = acc
-    rc, rv = row
-    counts = counts + xp.asarray(rc, counts.dtype)
-    rv = xp.asarray(rv, values.dtype)
-    sel_sum = xp.asarray([a == "sum" for a in _V_ACCUM])
-    sel_last = xp.asarray([a == "last" for a in _V_ACCUM])
-    sel_max = xp.asarray([a == "max" for a in _V_ACCUM])
-    out = xp.where(sel_sum, values + rv,
-                   xp.where(sel_last, rv,
-                            xp.where(sel_max, xp.maximum(values, rv),
-                                     xp.minimum(values, rv))))
-    return counts, out
 
 
 def host_row(**named) -> Tuple[np.ndarray, np.ndarray]:

@@ -209,17 +209,11 @@ class RunObserver:
         transport = getattr(eng, "_transport", None)
         if transport is not None:
             rec["transport"] = transport.stats()
-        nbucket = 0
-        fused = getattr(eng, "_fused_buckets", None)
-        if fused is not None:
-            nbucket += len(fused.events)
-        if transport is not None and hasattr(transport, "buckets"):
-            nbucket += len(transport.buckets.events)
-        if fused is not None or transport is not None:
-            rec["bucket_events"] = nbucket
-        for k in ("bins_refreshes", "repartitions"):
-            if hasattr(eng, k):
-                rec[k] = getattr(eng, k)
+        if transport is not None:
+            buckets = getattr(transport, "buckets", None)
+            rec["bucket_events"] = len(buckets.events) if buckets else 0
+        if hasattr(eng, "repartitions"):
+            rec["repartitions"] = eng.repartitions
 
         # per-rank time-averaged work imbalance of the decomposition (the
         # repartition trigger's own metric, logged every cycle)
@@ -268,16 +262,17 @@ class RunObserver:
             tripped = bool(summary["tripped"]) or drift
             rec["health"] = {"flags": summary["flags"],
                              "energy_drift": drift, "tripped": tripped}
-            # fully fused runs have no per-phase spans — feed the cost
-            # ledger one aggregate (units-by-kind, fused wall) sample:
-            # it keeps CostModel.observe flowing (unit-share
-            # apportioning, so measured_vs_modelled refines from cycle
-            # one) and re-runs the joint CostModel.calibrate() fit over
-            # its sample window each cycle
+            # a device-scheduled cycle has no per-phase spans — feed the
+            # cost ledger one aggregate (units-by-kind, program wall)
+            # sample, the wall being the cycle program's time as the host
+            # sees it (``launch`` + ``wait``): it keeps CostModel.observe
+            # flowing (unit-share apportioning, so measured_vs_modelled
+            # refines from cycle one) and re-runs the joint
+            # CostModel.calibrate() fit over its sample window each cycle
             if "density" not in phase_wall and hasattr(cm, "observe"):
-                fused_wall = sum(dedup_wall.get(n, 0.0)
-                                 for n in ("fused_substep", "fused_final"))
-                if fused_wall > 0:
+                program_wall = sum(dedup_wall.get(n, 0.0)
+                                   for n in ("launch", "wait"))
+                if program_wall > 0:
                     if cell_work is not None:
                         totals = np.asarray(
                             cell_work["per_rank"], np.float64).sum(axis=0)
@@ -287,7 +282,7 @@ class RunObserver:
                         units = {k: float(du.get(k, 0.0))
                                  for k in ("density", "force", "exchange")}
                     rec["cost_calibration"] = self._get_ledger(cm).record(
-                        units, fused_wall)
+                        units, program_wall)
             self.flight.record(self.cycle, counts, values)
             if tripped:
                 reason = drift and "energy-drift" or next(
@@ -398,9 +393,8 @@ class RunObserver:
             reg.inc("halo_full_slots", rec.get("halo_full_slots", 0))
         if "bucket_events" in rec:
             reg.count("bucket_events", rec["bucket_events"])
-        for k in ("bins_refreshes", "repartitions"):
-            if k in rec:
-                reg.count(k, rec[k])
+        if "repartitions" in rec:
+            reg.count("repartitions", rec["repartitions"])
         du = rec.get("device_phase_units")
         if du:
             for kind, units in du.items():

@@ -170,7 +170,7 @@ class Tracer:
 
         ``ranks=range(n)`` records the one interval on every listed rank's
         row instead of ``rank``'s — how one collective program (an
-        exchange, a fused sub-step) shows up as a task on each rank's
+        exchange, a cycle-scan launch) shows up as a task on each rank's
         timeline. ``ranks={row: {"request_id": ...}}`` also gives each row
         attrs of its own."""
         if ranks is None:

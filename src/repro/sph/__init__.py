@@ -29,7 +29,7 @@ from .timebins import (TimeBinSimulation, TimeBinState, active_level,
 from .dist_timebins import (DistTimeBinSimulation, build_rank_plan,
                             halo_export_schedule)
 from .collectives import (CollectiveTransport, build_allgather_program,
-                          build_fused_substep_program, build_permute_program)
+                          build_permute_program)
 
 __all__ = [
     "SCENARIOS", "SimulationSpec", "SimulationProtocol", "build_simulation",
@@ -47,5 +47,5 @@ __all__ = [
     "bin_timestep", "cell_bin_histogram", "cell_max_bins", "timebin_init",
     "DistTimeBinSimulation", "build_rank_plan", "halo_export_schedule",
     "CollectiveTransport", "build_allgather_program",
-    "build_fused_substep_program", "build_permute_program",
+    "build_permute_program",
 ]

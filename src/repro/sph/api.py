@@ -29,8 +29,8 @@ integrator      backend       engine
                                (activity-aware halos over a rank partition;
                                wire via ``transport="host" | "collective"``,
                                state residency via ``residency="host" |
-                               "device"`` — device-resident fused sub-step
-                               programs)
+                               "device"`` — the host-resident ladder or
+                               the device cycle scan)
 ==============  ============  ===============================================
 
 The legacy constructors keep working as thin shims (they *are* the engine
@@ -48,6 +48,7 @@ from typing import Any, Callable, Dict, Mapping, Optional, Protocol, Tuple, \
 
 import numpy as np
 
+from ..distributed.transport import schedule_of
 from ..observability.observer import ObserveSpec
 from ..observability.tracer import NULL_TRACER
 from .engine import SPHConfig
@@ -216,26 +217,24 @@ class SimulationSpec:
     # collective lowering: "auto" | "ppermute" | "allgather".
     transport: str = "host"
     transport_mode: str = "auto"
-    # where the per-rank extended states live between exchanges:
-    # "host" — scattered to per-rank arrays each cycle, phase programs and
-    # exchanges dispatched from the host loop (the reference semantics);
-    # "device" — one stacked sharded buffer per field stays on the mesh
-    # for the whole cycle and every force sub-step runs as a single fused
-    # shard_map program (requires transport="collective"). Bit-for-bit
-    # identical trajectories either way (tests/test_conformance.py).
+    # where the per-rank extended states live, which also decides who
+    # drives the sub-step ladder:
+    # "host" — the host-resident ladder: per-rank arrays scattered each
+    # cycle, the host loop walks the 2**depth sub-steps and dispatches the
+    # phase programs and exchanges (the multi-rank CPU reference);
+    # "device" — the device cycle scan, the production path: one stacked
+    # sharded buffer per field stays on the mesh, and each cycle is one
+    # scanned shard_map program that derives activity masks, pair subsets
+    # and ship slots from the device-resident ``bins`` array, the host
+    # consulted only at segment boundaries and on a sentinel trip
+    # (requires transport="collective"). Bit-for-bit identical
+    # trajectories either way (tests/test_conformance.py).
     residency: str = "host"
-    # who drives the sub-step ladder of a device-resident cycle:
-    # "host" — the host loop walks the 2**depth sub-steps and uploads
-    # per-level control tables (the reference orchestration);
-    # "device" — the whole ladder lowers into one scanned shard_map
-    # segment that derives activity masks, pair subsets and ship slots
-    # from the device-resident ``bins`` array, with the host consulted
-    # only at segment boundaries and on a sentinel trip (requires
-    # residency="device"). ``segment_cycles`` fuses K consecutive cycles
-    # into one device segment (K = 1 → one cycle per segment).
-    # Bit-for-bit identical trajectories either way
-    # (tests/test_conformance.py).
-    schedule: str = "host"
+    # follows the residency: None is set to "host" for residency="host"
+    # and "device" for residency="device"; an explicit value must agree.
+    schedule: Optional[str] = None
+    # K consecutive cycles fused into one device segment (K = 1 → one
+    # cycle per segment); K > 1 needs the device schedule
     segment_cycles: int = 1
 
     # shared
@@ -281,16 +280,10 @@ class SimulationSpec:
         if self.residency == "device" and self.transport != "collective":
             raise ValueError(
                 "residency='device' keeps rank states on the mesh and "
-                "fuses the exchange into the sub-step programs; it "
-                "requires transport='collective'")
-        if self.schedule not in ("host", "device"):
-            raise ValueError(f"schedule must be 'host' or 'device', "
-                             f"got {self.schedule!r}")
-        if self.schedule == "device" and self.residency != "device":
-            raise ValueError(
-                "schedule='device' derives the sub-step schedule from the "
-                "device-resident bins array; it requires "
-                "residency='device'")
+                "fuses the exchange into the cycle scan; it requires "
+                "transport='collective'")
+        object.__setattr__(self, "schedule",
+                           schedule_of(self.residency, self.schedule))
         if int(self.segment_cycles) < 1:
             raise ValueError(f"segment_cycles must be >= 1, "
                              f"got {self.segment_cycles!r}")
@@ -311,7 +304,10 @@ class SimulationSpec:
             object.__setattr__(self, "observe", ob)
 
     def with_(self, **changes) -> "SimulationSpec":
-        """A copy with the given fields replaced (specs are frozen)."""
+        """A copy with the given fields replaced (specs are frozen). A new
+        residency re-derives the schedule unless one is given."""
+        if "residency" in changes:
+            changes.setdefault("schedule", None)
         return dataclasses.replace(self, **changes)
 
     def program_signature(self) -> tuple:

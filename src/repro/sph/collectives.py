@@ -28,18 +28,21 @@ every build is registered with the engine's :class:`~repro.distributed.
 transport.CompileProbe` — the bucket hysteresis guarantees the cache stays
 small across sub-steps and cycles.
 
-**Fused sub-step programs** (:func:`build_fused_substep_program`): the
-device-resident lowering goes further and compiles a *whole force sub-step*
-— drift, density phase, exchange 1, force phase, kick and exchange 2 — into
-one shard_map program over the stacked per-rank extended states, so the
-state never leaves the mesh between cycle boundaries. The force pair pass
-is split into **interior** pairs (both rows owned — their inputs cannot be
+**The cycle scan** (:func:`build_cycle_scan_program`): the device-resident
+lowering compiles a *whole cycle* — every sub-step's drift, density phase,
+exchange 1, force phase, kick and exchange 2 — into one shard_map
+``lax.scan`` over the stacked per-rank extended states, which derives the
+sub-step schedule from the device-resident ``bins`` array, so the state
+never leaves the mesh between segment boundaries. The force pair pass is
+split into **interior** pairs (both rows owned — their inputs cannot be
 touched by exchange 1, so their per-pair math is scheduled against the
 exchange rounds instead of behind them) and **cut** pairs (one row is a
 halo replica — they wait for the exchanged densities); the two subsets'
 contributions are re-assembled *in original pair-list order* and applied in
-a single scatter, which keeps the fused program bit-for-bit identical to
-the unsplit host-wire phases (:func:`_split_force_pass`).
+a single scatter, which keeps the scan bit-for-bit identical to the
+unsplit host-wire phases (:func:`_split_force_pass`).
+:func:`build_plan_program` plans the further cycles of a K-cycle segment
+on device.
 """
 
 from __future__ import annotations
@@ -66,7 +69,7 @@ from .timebins import (STATE_AUX_FIELDS, STATE_CELL_FIELDS, TimeBinState,
                        _apply_final_kick, _apply_force_kick, _cycle_start,
                        _drift, _substep_density_phase, assign_bins,
                        mass_weighted_mean_u, speed_norm,
-                       substep_active_mask, trailing_zeros_table)
+                       trailing_zeros_table)
 
 
 # ------------------------------------------------------- in-block row copies
@@ -231,138 +234,6 @@ def _split_force_pass(cells: ParticleCells, pairs: PairList, pair_mask,
     return dv, du
 
 
-# --------------------------------------------------- fused sub-step programs
-def build_fused_substep_program(mesh, axis: str, *, mode: str,
-                                rounds: Sequence[Sequence[Tuple[int, int]]],
-                                nrows: int, K: int, cfg, box,
-                                final: bool = False):
-    """Compile one whole force sub-step as a single shard_map program.
-
-    The device-resident engine's unit of work: drift → density phase →
-    exchange 1 (rho, omega, press, cs) → split force pass → kick/deepen →
-    exchange 2 (vel, u, bins, t_start, accel, dudt), all over the stacked
-    per-rank extended states, which stay on the mesh. With ``final=True``
-    the program is the cycle-closing boundary instead: every particle
-    active, closing kick only, no exchange 2.
-
-    Inputs are three pytrees — ``state`` (stacked per-rank field dict,
-    sharded over ``axis`` and donated so buffers are reused in place),
-    ``tables`` (pair lists, interior/cut split positions, wake floors and
-    exchange index tables for this sub-step) and ``scalars`` (replicated
-    dt/level/…). Returns the updated state dict, a per-rank ``changed``
-    flag (1 iff any owned row's bin deepened — the only signal the host
-    needs mid-cycle: it triggers a bins-mirror refresh; the dynamical
-    state never leaves the device until the cycle gather), and a per-rank
-    :mod:`~repro.observability.device_metrics` row — the in-program
-    telemetry counters. The row is an **unconditional** third output:
-    its reductions only add consumers to values the physics already
-    computes (never producers), so instrumented and uninstrumented runs
-    share this one compiled program per signature (zero extra compiles)
-    and the state output is bitwise unchanged — both conformance-pinned.
-    """
-    perms = [list(rnd) for rnd in rounds]
-
-    def xchg(tables, fields):
-        if mode == "ppermute":
-            return [_permute_copy(f, tables["e_pack"], tables["e_unpack"],
-                                  tables["e_valid"], perms, axis, nrows)
-                    for f in fields]
-        return [_allgather_copy(f, tables["e_pack"], tables["e_usrc"],
-                                tables["e_urows"], tables["e_valid"],
-                                axis, nrows) for f in fields]
-
-    def body(state, tables, scalars):
-        blk = {k: v[0] for k, v in state.items()}
-        tbl = {k: v[0] for k, v in tables.items()}
-        st = TimeBinState(
-            cells=ParticleCells(pos=blk["pos"], vel=blk["vel"],
-                                mass=blk["mass"], u=blk["u"], h=blk["h"],
-                                mask=blk["mask"]),
-            accel=blk["accel"], dudt=blk["dudt"], rho=blk["rho"],
-            omega=blk["omega"], bins=blk["bins"], t_start=blk["t_start"],
-            time=blk["time"])
-        st = _drift(st, scalars["dt_drift"], box=box)
-        pairs = PairList(ci=tbl["ci"], cj=tbl["cj"], shift=tbl["shift"])
-        pmask = tbl["pmask"]
-
-        if final:
-            active = st.cells.mask
-        else:
-            active = substep_active_mask(st, scalars["level"], tbl["wake"])
-        rho, om, pr, cs = _substep_density_phase(st, pairs, pmask, active,
-                                                 cfg=cfg)
-        rho2, om2, pr2, cs2 = xchg(tbl, [rho, om, pr, cs])
-        dv, du = _split_force_pass(
-            st.cells, pairs, pmask, (rho, pr, om, cs),
-            (rho2, pr2, om2, cs2), tbl["int_pos"], tbl["int_valid"],
-            tbl["cut_pos"], tbl["cut_valid"], cfg=cfg)
-        if final:
-            st = _apply_final_kick(st, dv, du, rho2, om2,
-                                   scalars["dt_max"], cfg=cfg)
-            changed = jnp.zeros((1,), jnp.int32)
-            kicked = jnp.sum((active > 0) & (st.cells.mask > 0))
-            deepened = jnp.zeros((), jnp.int32)
-            woken = jnp.zeros((), jnp.int32)
-            nexch = 1
-        else:
-            st, kicked = _apply_force_kick(st, active, dv, du, rho2, om2,
-                                           tbl["wake"], scalars["dt_max"],
-                                           scalars["depth"],
-                                           scalars["u_floor"], cfg=cfg)
-            vel, uu, bb, ts, ac, dd = xchg(
-                tbl, [st.cells.vel, st.cells.u, st.bins, st.t_start,
-                      st.accel, st.dudt])
-            deepened = jnp.sum(bb[:K] != blk["bins"][:K]
-                               ).astype(jnp.int32)
-            changed = (deepened > 0).astype(jnp.int32)[None]
-            woken = jnp.sum(tbl["wake"] > scalars["level"]
-                            ).astype(jnp.int32)
-            st = st._replace(cells=st.cells._replace(vel=vel, u=uu),
-                             bins=bb, t_start=ts, accel=ac, dudt=dd)
-            nexch = 2
-        # per-slot wire bytes are static: exchange 1 ships 4 (cap,)
-        # fields; exchange 2 ships vel/accel (cap, 3) + u/bins/t_start/
-        # dudt (cap,)
-        cap = int(st.cells.mass.shape[1])
-        slot_bytes = 4 * cap * 4
-        if nexch == 2:
-            slot_bytes += 10 * cap * 4
-        nslots = jnp.sum(tbl["e_valid"] > 0).astype(jnp.int32)
-        # telemetry covers the K *owned* rows only — halo mirrors belong
-        # to their owner's row, so per-rank work and the summed energy
-        # fingerprint match the host-path (no-halo) semantics exactly
-        met_counts, met_values = dmetrics.measure_substep(
-            mask=st.cells.mask[:K], active=active[:K],
-            vel=st.cells.vel[:K], u=st.cells.u[:K],
-            mass=st.cells.mass[:K], rho=st.rho[:K],
-            live_pairs=jnp.sum(pmask),
-            pair_int=jnp.sum(tbl["int_valid"] > 0),
-            pair_cut=jnp.sum(tbl["cut_valid"] > 0),
-            exch_slots=nslots * nexch, exch_bytes=nslots * slot_bytes,
-            deepened=deepened, woken=woken, kicked=kicked)
-        # per-cell attribution rides in the same unconditional output
-        # pytree (new dict key, same out_specs): owned-row sums equal the
-        # drift/density/force columns above, all-row exchange sums equal
-        # exchange_units — the identities the 4-rank acceptance pins
-        met_cells = dmetrics.measure_cells(
-            nrows=nrows, K=K, mask=st.cells.mask[:K], pmask=pmask,
-            ci=tbl["ci"], cj=tbl["cj"],
-            exch_rows=(tbl["e_unpack"] if mode == "ppermute"
-                       else tbl["e_urows"]),
-            exch_valid=tbl["e_valid"], nexch=nexch)
-        met = {"counts": met_counts[None], "values": met_values[None],
-               "cells": met_cells[None]}
-        out = {k: getattr(st.cells, k) for k in STATE_CELL_FIELDS}
-        out.update({k: getattr(st, k) for k in STATE_AUX_FIELDS})
-        out["time"] = st.time
-        return {k: v[None] for k, v in out.items()}, changed, met
-
-    fn = shard_map(_named(body, "fused_final" if final else "fused_substep"),
-                   mesh=mesh, in_specs=(P(axis), P(axis), P()),
-                   out_specs=(P(axis), P(axis), P(axis)))
-    return jax.jit(fn, donate_argnums=(0,))
-
-
 # ------------------------------------------------ device-scheduled segments
 # neutral element for integer scatter-max over possibly-empty stencils
 # (same value the host planners use in timebins.limit_neighbour_bins)
@@ -421,13 +292,12 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                              activity_aware: bool = True):
     """Compile one WHOLE cycle — every sub-step — as a single lax.scan.
 
-    The device-scheduled lowering (``schedule="device"``): where
-    :func:`build_fused_substep_program` compiles one sub-step and leaves the
-    ladder bookkeeping (active levels, pair subsets, ship sets, wake floors)
-    to a host loop, this program derives the entire schedule *inside* the
-    compiled program from the device-resident ``bins`` array, so the host
-    dispatches one call per cycle and reads nothing back until the segment
-    boundary.
+    The device-resident lowering (``residency="device"``): where the host
+    ladder leaves the bookkeeping (active levels, pair subsets, ship sets,
+    wake floors) to a host loop, this program derives the entire schedule
+    *inside* the compiled program from the device-resident ``bins`` array,
+    so the host dispatches one call per cycle and reads nothing back until
+    the segment boundary.
 
     Per scan trip n = 1..``nsub_static`` (the static ladder length;
     ``scalars["nsub"]`` may select a shorter dynamic ladder, later trips are
@@ -462,10 +332,10 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
         whole table, masked.
 
     Padded and dead pair slots contribute exact ±0.0 through the same masked
-    scatters as the host-scheduled fused path, and a compacted list keeps
-    the live pairs' order, so all three branches fold the same sums — the
-    bitwise contract is ``assert_array_equal`` (±0.0 and NaN compare
-    equal), identical to the existing residency conformance pin.
+    scatters as the host ladder's padded buckets, and a compacted list
+    keeps the live pairs' order, so all three branches fold the same sums —
+    the bitwise contract is ``assert_array_equal`` (±0.0 and NaN compare
+    equal) against the single-rank reference ladder.
 
     On the CPU the scan is **fully unrolled** (``unroll=nsub_static``):
     XLA:CPU's while-loop lowering of a rolled scan changes the
@@ -612,8 +482,8 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                 ev = recv_valid(tbl, row_act, is_final)
 
                 def update(pairs_, pm_, split, closing):
-                    """Density + exchange 1 + split force (as the fused
-                    path) over one pair list, the kick, then exchange 2;
+                    """Density + exchange 1 + split force (as the host
+                    phases) over one pair list, the kick, then exchange 2;
                     with ``closing`` (the full branch) also the density
                     pass's W > 0 entries, a sibling reduction of its sums
                     that every full-table trip computes and the cycle's
@@ -678,7 +548,8 @@ def build_cycle_scan_program(mesh, axis: str, *, mode: str,
                 n_pair = jnp.sum((pm > 0) & (tbl["own_pair"] > 0)
                                  ).astype(jnp.int32)
                 n_slots = jnp.sum(ev > 0).astype(jnp.int32)
-                # telemetry row (mirrors build_fused_substep_program)
+                # telemetry row (owned rows only: halo mirrors belong
+                # to their owner's row)
                 deepened = jnp.where(is_final, 0,
                                      jnp.sum(stN.bins[:K] != st.bins[:K])
                                      ).astype(jnp.int32)
@@ -786,7 +657,7 @@ def build_plan_program(mesh, axis: str, *, mode: str,
     * ``crossed``: any owned particle's cell id (identical f32 op sequence
       as ``cellgrid.bin_particles``) differs from its resident row's cell —
       the host epilogue's re-bin would have changed the layout, so the
-      segment must abort and replay host-scheduled;
+      segment must abort and replay on the host ladder;
     * ``capacity``: the new cycle wants more sub-steps than the compiled
       scan's static ladder (deepening beyond headroom) — same abort.
 
@@ -1035,8 +906,8 @@ class CollectiveTransport(Transport):
             # through host memory (what the host transport does anyway)
             # keeps the phase programs' compile count identical across
             # transports. This round trip — device→host→device of every
-            # full field — is exactly the residual overhead the fused
-            # device-resident path (residency="device") removes;
+            # full field — is exactly the residual overhead the
+            # device-resident cycle scan (residency="device") removes;
             # host_bytes measures it.
             outs_h = [np.asarray(out) for out in outs]
             self.host_bytes += 2 * sum(o.nbytes for o in outs_h)

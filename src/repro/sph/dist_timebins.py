@@ -47,6 +47,23 @@ CompileProbe` (``self.probe``) counts the real XLA compiles. With
 ``nranks=1`` the engine reduces to the single-host ladder bit-for-bit
 (asserted in ``tests/test_api.py``).
 
+The residency picks one of two lowerings of the cycle, both pinned bitwise
+to the single-rank :class:`~repro.sph.timebins.TimeBinSimulation`, the
+oracle (``tests/test_conformance.py``):
+
+* ``residency="host"`` — the **host-resident ladder**
+  (:meth:`DistTimeBinSimulation._cycle_substeps_host`): per-rank states,
+  the host loop walks the 2**depth sub-steps and dispatches the phase
+  programs and exchanges above. It is the multi-rank reference that runs
+  on one CPU device.
+* ``residency="device"`` — the **device cycle scan**, the production path
+  (:meth:`DistTimeBinSimulation._run_segment`): one stacked sharded buffer
+  per field stays on the mesh, and each cycle of a K-cycle segment is one
+  compiled ``lax.scan`` (``sph/collectives.build_cycle_scan_program``) that
+  derives its sub-step schedule from the device-resident bins. A segment
+  that trips a health, crossing or capacity flag is restored and replayed
+  on the host-resident ladder over the same collective transport.
+
 Repartitioning uses per-rank **bin occupancy**: the decomposition is
 retriggered when the time-averaged active work per rank
 (``core.decompose.timebin_node_weights``) drifts out of balance, and the
@@ -68,10 +85,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import CostModel, decompose_cells
 from ..core.decompose import timebin_node_weights
-from ..distributed.transport import (BucketPolicy, CompileProbe, RESIDENCIES,
+from ..distributed.transport import (CompileProbe, RESIDENCIES,
                                      ResidentBuffers, ShipSlots, TRANSPORTS,
                                      TransferProbe, make_transport, next_pow2,
-                                     pack_allgather, pack_rounds)
+                                     pack_allgather, pack_rounds, schedule_of)
 from ..observability import device_metrics as dmetrics
 from .cellgrid import PairList, ParticleCells
 from .engine import SPHConfig, build_taskgraph
@@ -79,8 +96,7 @@ from .timebins import (STATE_AUX_FIELDS, STATE_CELL_FIELDS,
                        TimeBinSimulation, TimeBinState, _final_force_phase,
                        _substep_density_phase, _substep_force_phase,
                        active_level, cell_bin_histogram,
-                       mass_weighted_mean_u, substep_active_mask,
-                       trailing_zeros_table)
+                       mass_weighted_mean_u, substep_active_mask)
 
 _PAD_H = 1e-6          # padded-slot smoothing length (division-safe)
 
@@ -244,7 +260,7 @@ class DistTimeBinSimulation(TimeBinSimulation):
                  transport: str = "host",
                  transport_mode: str = "auto",
                  residency: str = "host",
-                 schedule: str = "host",
+                 schedule: Optional[str] = None,
                  segment_cycles: int = 1,
                  **kw):
         if transport not in TRANSPORTS:
@@ -257,22 +273,15 @@ class DistTimeBinSimulation(TimeBinSimulation):
             if transport != "collective":
                 raise ValueError(
                     "residency='device' fuses the exchange into the "
-                    "sub-step programs and therefore requires "
+                    "cycle scan and therefore requires "
                     "transport='collective' (the host wire has no device "
                     "mesh to keep the state resident on)")
             if cfg.use_pallas:
                 raise ValueError(
                     "residency='device' compiles the vmap pair phases "
-                    "into the fused shard_map programs; use_pallas=True "
-                    "is not supported on this path yet")
-        if schedule not in ("host", "device"):
-            raise ValueError(f"schedule must be 'host' or 'device', "
-                             f"got {schedule!r}")
-        if schedule == "device" and residency != "device":
-            raise ValueError(
-                "schedule='device' derives the sub-step ladder inside the "
-                "compiled segment program from the device-resident bins "
-                "array and therefore requires residency='device'")
+                    "into the cycle scan; use_pallas=True is not "
+                    "supported on this path yet")
+        schedule = schedule_of(residency, schedule)
         if int(segment_cycles) < 1:
             raise ValueError("segment_cycles must be >= 1")
         if int(segment_cycles) > 1 and schedule != "device":
@@ -314,19 +323,9 @@ class DistTimeBinSimulation(TimeBinSimulation):
         self.halo_exported_slots = 0
         self.halo_full_slots = 0
         self.halo_log: List[Dict[str, float]] = []
-        # residency="device": host↔device traffic ledger + mid-cycle bins
-        # mirror refresh counter (one per deepening/wake event, the only
-        # state-array readback the fused path ever performs)
+        # residency="device": host↔device traffic ledger
         self.transfers = TransferProbe()
-        self.bins_refreshes = 0
-        # fused-program buckets never shrink: a whole-sub-step program is
-        # orders of magnitude more expensive to compile than the padded
-        # pair math an oversized bucket wastes, so demand dips must not
-        # mint new shape signatures (growth still recompiles, once per
-        # power-of-two crossing per stream)
-        self._fused_buckets = BucketPolicy(min_bucket=8,
-                                           shrink_patience=10 ** 9)
-        # device telemetry: the fused programs always *compute* the
+        # device telemetry: the cycle scan always *computes* the
         # per-rank metrics row (see observability/device_metrics.py —
         # that's what keeps the instrumented program the only program);
         # this flag gates the once-per-cycle host pull + observer merge
@@ -341,7 +340,7 @@ class DistTimeBinSimulation(TimeBinSimulation):
         self.device_cell_work_last: Optional[Dict] = None
         # schedule="device": whole K-cycle segments run as compiled
         # programs; run_cycle() pops one cycle's stats per call from this
-        # queue. A segment aborts back to the host-scheduled ladder
+        # queue. A segment aborts back to the host-resident ladder
         # (bitwise-recoverably) when a health sentinel or capacity/crossing
         # flag trips.
         self._segment_queue: List[Dict] = []
@@ -485,8 +484,8 @@ class DistTimeBinSimulation(TimeBinSimulation):
 
         The one selection rule (rank's touch set, optionally restricted to
         pairs touching an active cell) that both the host phase programs
-        (:meth:`_rank_pair_subsets`) and the fused device tables
-        (:meth:`_fused_tables`) build from — the bitwise-parity contract
+        (:meth:`_rank_pair_subsets`) and the cycle scan's tables
+        (:meth:`_segment_tables`) build from — the bitwise-parity contract
         between the two residencies depends on it never forking.
         """
         idxs = []
@@ -540,7 +539,6 @@ class DistTimeBinSimulation(TimeBinSimulation):
         out["program_keys"] = len(self.program_keys)
         out["residency"] = self.residency
         out["transfers"] = self.transfers.stats()
-        out["bins_refreshes"] = self.bins_refreshes
         return out
 
     # -------------------------------------------------------------- cycling
@@ -570,10 +568,7 @@ class DistTimeBinSimulation(TimeBinSimulation):
             return stats
         with tr.timed("cycle") as cyc:
             ctx = self._cycle_prologue()
-            if self.residency == "device":
-                body = self._cycle_substeps_device(ctx)
-            else:
-                body = self._cycle_substeps_host(ctx)
+            body = self._cycle_substeps_host(ctx)
             stats = self._cycle_epilogue(ctx, body)
         if tr.enabled:
             tr.ctx.pop("substep", None)
@@ -585,7 +580,7 @@ class DistTimeBinSimulation(TimeBinSimulation):
         """Plan the cycle and open it on the global mirror (host side)."""
         # planning runs once on the host for everyone — one task on every
         # rank's row, like SWIFT's tree-build. No fence: the opening kick
-        # is only dispatched here; the host-scheduled bodies wait on it
+        # is only dispatched here; the host ladder waits on it
         with self.tracer.span("plan", ranks=range(self.nranks),
                               collective=1) as sp:
             dt_max_c, depth = self._plan_cycle()
@@ -1031,298 +1026,13 @@ class DistTimeBinSimulation(TimeBinSimulation):
             time=jnp.asarray(time_h[0]),
             **{k: jnp.asarray(v) for k, v in out.items()})
 
-    def _fused_tables(self, plan: RankPlan,
-                      active_cells: Optional[np.ndarray], slots: ShipSlots,
-                      stream: str, wake_stacked: Optional[np.ndarray],
-                      level: int = 0) -> Tuple[Dict[str, jax.Array], Tuple]:
-        """One sub-step's control tables for the fused program + the static
-        shape signature that keys its compilation.
-
-        The pair subset is built exactly as :meth:`_rank_pair_subsets`
-        (shared power-of-two bucket, global pair order) and then split into
-        interior / cut *positions* (a pair is cut iff it touches a halo row
-        ≥ K); the exchange index tables come from the transport's round
-        schedule and bucket policy. Everything here is control plane —
-        int32 indices and masks — the only intra-cycle host→device traffic
-        of the resident path.
-        """
-        t = self._transport
-        nranks = plan.nranks
-        nrows = plan.K + plan.H
-        idxs, nmax = self._select_rank_pairs(plan, active_cells)
-        splits = []
-        imax, cmax = 1, 1
-        for r in range(nranks):
-            idx = idxs[r]
-            halo_pair = ((plan.ci_ext[r][idx] >= plan.K)
-                         | (plan.cj_ext[r][idx] >= plan.K))
-            splits.append(halo_pair)
-            imax = max(imax, int((~halo_pair).sum()))
-            cmax = max(cmax, int(halo_pair.sum()))
-        # pair buckets go through the engine's no-shrink policy, keyed per
-        # (stream, level), so demand wobbling across cycles cannot mint
-        # new fused-program shape signatures
-        B = self._fused_buckets.fit((stream, "pairs", level), nmax)
-        Bi = self._fused_buckets.fit((stream, "int", level), imax)
-        Bc = self._fused_buckets.fit((stream, "cut", level), cmax)
-
-        ci = np.zeros((nranks, B), np.int32)
-        cj = np.zeros((nranks, B), np.int32)
-        shift = np.zeros((nranks, B, 3), self._shift.dtype)
-        pmask = np.zeros((nranks, B), np.float32)
-        int_pos = np.zeros((nranks, Bi), np.int32)
-        int_valid = np.zeros((nranks, Bi), np.float32)
-        cut_pos = np.zeros((nranks, Bc), np.int32)
-        cut_valid = np.zeros((nranks, Bc), np.float32)
-        for r in range(nranks):
-            idx, halo_pair = idxs[r], splits[r]
-            nlive = len(idx)
-            idxp = np.concatenate(
-                [idx, np.zeros(B - nlive, dtype=idx.dtype)])
-            ci[r] = plan.ci_ext[r][idxp]
-            cj[r] = plan.cj_ext[r][idxp]
-            shift[r] = self._shift[idxp]
-            pmask[r, :nlive] = 1.0
-            ipos = np.nonzero(~halo_pair)[0]
-            cpos = np.nonzero(halo_pair)[0]
-            int_pos[r, :len(ipos)] = ipos
-            int_valid[r, :len(ipos)] = 1.0
-            cut_pos[r, :len(cpos)] = cpos
-            cut_valid[r, :len(cpos)] = 1.0
-
-        tables = {"ci": ci, "cj": cj, "shift": shift, "pmask": pmask,
-                  "int_pos": int_pos, "int_valid": int_valid,
-                  "cut_pos": cut_pos, "cut_valid": cut_valid,
-                  "wake": wake_stacked if wake_stacked is not None
-                  else np.zeros((nranks, nrows), np.int32)}
-        if t.mode == "ppermute":
-            Be = self._fused_buckets.fit(("edge", stream),
-                                         slots.max_edge_slots)
-            pack, unpack, valid = pack_rounds(t.rounds, slots, nranks, Be)
-            tables.update(e_pack=pack, e_unpack=unpack, e_valid=valid)
-            exch_sig = ("ppermute", Be, t._perms_sig)
-        else:
-            Bo = self._fused_buckets.fit(("ag_out", stream),
-                                         slots.max_rank_exports(nranks))
-            Bn = self._fused_buckets.fit(("ag_in", stream),
-                                         slots.max_rank_imports(nranks))
-            pack, usrc, urows, valid = pack_allgather(slots, nranks, Bo, Bn)
-            tables.update(e_pack=pack, e_usrc=usrc, e_urows=urows,
-                          e_valid=valid)
-            exch_sig = ("allgather", Bo, Bn)
-        self.transfers.record(
-            "tables", sum(a.nbytes for a in tables.values()), boundary=False)
-        tables = {k: jnp.asarray(v) for k, v in tables.items()}
-        sig = (nranks, nrows, plan.K, B, Bi, Bc, exch_sig)
-        return tables, sig
-
-    def _fused_program(self, sig: Tuple, *, final: bool):
-        """Compiled fused sub-step program for this shape signature (one
-        compile per (phase, bucket signature), cached with the transport's
-        exchange programs so the probe counts every build)."""
-        from .collectives import build_fused_substep_program
-        t = self._transport
-        nrows, K = sig[1], sig[2]
-        key = ("fused_final" if final else "fused_force",) + sig + (t.mode,)
-        return t.programs.get(key, lambda: build_fused_substep_program(
-            t.mesh, t.axis, mode=t.mode, rounds=t.rounds, nrows=nrows, K=K,
-            cfg=self.cfg, box=self.box, final=final))
-
-    def _cycle_substeps_device(self, ctx: Dict[str, object]
-                               ) -> Dict[str, int]:
-        """The device-resident ladder: the stacked extended states stay on
-        the mesh for the whole cycle; every force sub-step is one fused
-        shard_map program (drift → density → exchange → split force →
-        kick → exchange). Host traffic is control tables in, one changed
-        flag out — plus a bins-mirror refresh per deepening/wake event."""
-        plan: RankPlan = ctx["plan"]
-        depth, nsub = ctx["depth"], ctx["nsub"]
-        dt_max_c, dt_min = ctx["dt_max_c"], ctx["dt_min"]
-        mask_host, u_floor = ctx["mask_host"], ctx["u_floor"]
-        nreal = ctx["nreal"]
-        tr = self.tracer
-        with tr.span("scatter", ranks=range(plan.nranks), collective=1):
-            # the prologue's opening kick lands here in the task plot
-            tr.fence(self.state.cells.pos)
-            res = self._scatter_resident(plan)
-            tr.fence(res["pos"])
-
-        updates = 0
-        pair_tasks = 0
-        force_substeps = 0
-        drifted_to = 0
-        cycle_exported = 0
-        cycle_full = 0
-        self.halo_log = []
-        bins_h = ctx["bins_host"].copy()
-        wake_floor = self._wake_floor(bins_h, mask_host)
-        wake_stacked: Optional[np.ndarray] = None
-        # cycle-scoped device plan: a sub-step's control tables depend only
-        # on (level, bins mirror) — every sub-step of the same level reuses
-        # the tables already sitting on the device; a deepening/wake event
-        # invalidates the whole cache. A depth-d cycle uploads O(d) table
-        # sets, not O(2**d).
-        table_cache: Dict[int, Tuple] = {}
-
-        def wake_tbl() -> np.ndarray:
-            nonlocal wake_stacked
-            if wake_stacked is None:
-                w = np.zeros((plan.nranks, plan.K + plan.H), np.int32)
-                for r in range(plan.nranks):
-                    own, hal = plan.owned[r], plan.halo[r]
-                    w[r, :len(own)] = wake_floor[own]
-                    w[r, plan.K:plan.K + len(hal)] = wake_floor[hal]
-                wake_stacked = w
-            return wake_stacked
-
-        def level_plan(level: int) -> Tuple:
-            key = level
-            if key not in table_cache:
-                active_p = ((bins_h >= level)
-                            | (bins_h < wake_floor[:, None])) \
-                    & (mask_host > 0)
-                if not active_p.any():
-                    table_cache[key] = (active_p, None, None, None, None)
-                else:
-                    active_cells = active_p.any(axis=1)
-                    ship = self._exchange_set(plan, active_cells)
-                    slots = plan.ship_slots(ship) if ship else ShipSlots()
-                    tables, sig = self._fused_tables(
-                        plan, active_cells, slots, "fused_sub", wake_tbl(),
-                        level=level)
-                    table_cache[key] = (active_p, active_cells, slots,
-                                        tables, sig)
-            return table_cache[key]
-
-        dm_on = self.device_metrics_enabled
-        met_acc: List = []          # one (counts, values) device-ref cell
-        cell_acc: List = []         # one stacked per-cell buffer device ref
-
-        def run_fused(tables, sig, scalars, final):
-            prog = self._fused_program(sig, final=final)
-            state_in = {name: res[name] for name in
-                        self._CELL_FIELDS + self._AUX_FIELDS + ("time",)}
-            out_state, changed, met = prog(state_in, tables, scalars)
-            res.update(out_state)
-            if dm_on:
-                row = (met["counts"], met["values"])
-                if not met_acc:
-                    met_acc.append(row)
-                    cell_acc.append(met["cells"])
-                else:
-                    # eager device-side fold of the tiny rows: no host
-                    # sync, no registered program, no extra compile
-                    met_acc[0] = dmetrics.combine(met_acc[0], row, jnp)
-                    cell_acc[0] = cell_acc[0] + met["cells"]
-            return changed
-
-        for n in range(1, nsub):
-            level = active_level(n, depth)
-            active_p, active_cells, slots, tables, sig = level_plan(level)
-            if not active_p.any():
-                continue
-            cycle_exported += slots.total
-            cycle_full += plan.cut_slots
-            self.halo_log.append({
-                "substep": self.substeps + n, "level": level,
-                "exported_slots": slots.total,
-                "full_slots": plan.cut_slots})
-
-            dt_d = (n - drifted_to) * dt_min
-            drifted_to = n
-            if tr.enabled:
-                tr.ctx["substep"] = n
-            self.program_keys.add(("fused_force", level, sig[3]))
-            scalars = {"dt_drift": jnp.float32(dt_d),
-                       "level": jnp.int32(level),
-                       "dt_max": jnp.float32(dt_max_c),
-                       "depth": jnp.int32(depth),
-                       "u_floor": jnp.float32(u_floor)}
-            f_attrs = {}
-            if tr.enabled:
-                f_attrs = dict(
-                    level=level, bucket=sig[3],
-                    units=int((active_cells[self._ci]
-                               | active_cells[self._cj]).sum()),
-                    slots=slots.total,
-                    active_frac=float(active_p.sum()) / max(nreal, 1))
-            # the fused program is one task on every rank's row; fence so
-            # its device time lands inside this span, not the next
-            with tr.span("fused_substep", ranks=range(plan.nranks),
-                         collective=1, **f_attrs):
-                changed = run_fused(tables, sig, scalars, final=False)
-                tr.fence(res["pos"])
-            changed_h = np.asarray(changed)
-            self.transfers.record("flags", changed_h.nbytes, boundary=False)
-            if changed_h.any():
-                # a deepening / wake-up: refresh the bins mirror for the
-                # changed ranks only, then re-derive the wake floors —
-                # the lone mid-cycle state-array readback, counted per
-                # event by the transfer probe
-                with tr.span("bins_refresh"):
-                    for r in np.nonzero(changed_h)[0]:
-                        own = plan.owned[int(r)]
-                        if not len(own):
-                            continue
-                        row = res.pull("bins", boundary=False, index=int(r))
-                        bins_h[own] = row[:len(own)]
-                    self.bins_refreshes += 1
-                    table_cache.clear()         # invalidate the level plans
-                    new_floor = self._wake_floor(bins_h, mask_host)
-                    if not np.array_equal(new_floor, wake_floor):
-                        wake_floor = new_floor
-                        wake_stacked = None     # invalidate on wake-up
-            updates += int(active_p.sum())
-            pair_tasks += int((active_cells[self._ci]
-                               | active_cells[self._cj]).sum())
-            force_substeps += 1
-
-        # final sync sub-step: everyone active, full pair lists, full cut
-        dt_d = (nsub - drifted_to) * dt_min
-        slots = plan.ship_slots(list(plan.cut)) if plan.cut else ShipSlots()
-        cycle_exported += slots.total
-        if plan.cut:
-            cycle_full += plan.cut_slots
-        tables, sig = self._fused_tables(plan, None, slots, "fused_final",
-                                         None)
-        self.program_keys.add(("fused_final", 0, sig[3]))
-        if tr.enabled:
-            tr.ctx["substep"] = nsub
-        scalars = {"dt_drift": jnp.float32(dt_d), "level": jnp.int32(0),
-                   "dt_max": jnp.float32(dt_max_c),
-                   "depth": jnp.int32(depth),
-                   "u_floor": jnp.float32(u_floor)}
-        with tr.span("fused_final", ranks=range(plan.nranks), level=0,
-                     bucket=sig[3], units=len(self._ci), slots=slots.total,
-                     active_frac=1.0, collective=1):
-            run_fused(tables, sig, scalars, final=True)
-            tr.fence(res["pos"])
-        updates += nreal
-        pair_tasks += len(self._ci)
-
-        if dm_on and met_acc:
-            # one pull per cycle: the whole accumulated telemetry row
-            # (per-cell buffer included — same single boundary transfer)
-            self._metrics_pull(*met_acc[0], cells=cell_acc[0], plan=plan)
-        elif not dm_on:
-            self.device_metrics_last = None
-            self.device_cell_work_last = None
-
-        with tr.span("gather", ranks=range(plan.nranks), collective=1):
-            self._gather_resident(plan, res)
-        return {"updates": updates, "pair_tasks": pair_tasks,
-                "force_substeps": force_substeps,
-                "cycle_exported": cycle_exported,
-                "cycle_full": cycle_full}
-
     # ---------------------------------------------- device-scheduled segments
     def _segment_tables(self, plan: RankPlan
                         ) -> Tuple[Dict[str, jax.Array],
                                    Dict[str, jax.Array], Tuple]:
         """Static control tables of one device-scheduled segment.
 
-        Unlike :meth:`_fused_tables` these are activity-*independent*: the
-        full touch-pair set per rank (compacted in ascending global pair
+        These are activity-*independent*: the full touch-pair set per rank (compacted in ascending global pair
         order — the same subsequence every per-level host table is a
         restriction of, so masked scatters fold identical contribution
         sequences), the full-cut exchange tables, and the schedule-deriving
@@ -1463,7 +1173,7 @@ class DistTimeBinSimulation(TimeBinSimulation):
         (``TransferProbe`` shows no intra-segment entries at all). If a
         health sentinel (NaN/Inf/neg-rho), a cell crossing or a
         capacity-overflow flag tripped, the pre-segment state is restored
-        and the segment replays on the host-scheduled ladder —
+        and the segment replays on the host-resident ladder —
         bitwise-recoverable by the residency conformance contract.
         """
         tr = self.tracer
@@ -1527,11 +1237,11 @@ class DistTimeBinSimulation(TimeBinSimulation):
         if sentinels or crossed or over:
             # sentinel trip: discard the segment (the flagged program's
             # interior state is garbage by contract), restore the
-            # pre-segment state and replay host-scheduled — bitwise
-            # identical to the reference ladder, NaNs included
+            # pre-segment state and replay on the host-resident ladder —
+            # bitwise identical to the reference ladder, NaNs included
             self.segment_aborts += 1
             self.state = stash
-            return self._replay_segment_host(K_cycles)
+            return self._replay_on_host_ladder(K_cycles)
 
         with tr.span("gather", ranks=ranks, collective=1):
             self._gather_resident(plan, res)
@@ -1643,13 +1353,15 @@ class DistTimeBinSimulation(TimeBinSimulation):
             self.device_cell_work_last = None
         return stats_list
 
-    def _replay_segment_host(self, K_cycles: int) -> List[Dict]:
-        """Abort path: re-run the segment's cycles on the host-scheduled
-        device-resident ladder (the conformance-pinned reference path)."""
+    def _replay_on_host_ladder(self, K_cycles: int) -> List[Dict]:
+        """Abort path: re-run the segment's cycles on the host-resident
+        per-rank ladder over the engine's collective transport, the path
+        the conformance tests pin bitwise to the single-rank
+        :class:`TimeBinSimulation` reference."""
         out = []
         for _ in range(K_cycles):
             ctx = self._cycle_prologue()
-            body = self._cycle_substeps_device(ctx)
+            body = self._cycle_substeps_host(ctx)
             stats = self._cycle_epilogue(ctx, body)
             stats["schedule"] = "device"
             stats["segment_cycles"] = K_cycles

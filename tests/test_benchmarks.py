@@ -4,7 +4,7 @@ The benchmark modules each leave a headline ``BENCH_*.json`` at the repo
 root; ``benchmarks/common.py`` (standalone-runnable, factored out of the
 full ``benchmarks.run`` sweep) validates them into the trajectory block
 of ``summary.json``. These tests pin that contract without running any
-benchmark: the three checked-in artifacts must parse, name their
+benchmark: the two checked-in artifacts must parse, name their
 benchmark, and never claim a metrics schema newer than this tree.
 """
 
@@ -19,8 +19,7 @@ sys.path.insert(0, ROOT)
 
 from benchmarks.common import bench_trajectory, env_provenance
 
-EXPECTED_ARTIFACTS = ("BENCH_fleet.json", "BENCH_fused_cycles.json",
-                      "BENCH_observability.json")
+EXPECTED_ARTIFACTS = ("BENCH_fleet.json", "BENCH_observability.json")
 
 
 def test_bench_trajectory_nonempty_and_valid():

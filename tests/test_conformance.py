@@ -11,18 +11,19 @@ that needs a matrix, not a pair of spot checks. The contract asserted here:
   and 4 ranks) reproduces the single-host :class:`TimeBinSimulation`
   trajectory bit-for-bit over ≥2 full cycles, on Sedov and
   Kelvin–Helmholtz. This is the engine-family contract every transport /
-  residency lowering must preserve (exchanges are pure row copies; fused
-  programs re-assemble split pair work in original pair order).
+  residency lowering must preserve (exchanges are pure row copies; the
+  device cycle scan re-assembles split pair work in original pair order).
 * **global family — determinism + physics.** ``global × distributed``
   accumulates pair sums in per-device plan order (a *different* but fixed
   summation order from the local engine's global pair list), so bitwise
   equality with the local engine is not part of its contract; it is pinned
   by (a) run-twice bitwise determinism and (b) trajectory agreement with
   the local engine to float32 tolerances plus conservation checks.
-* **transfer discipline.** The fused device-resident path moves zero bytes
-  of dynamical state across the host boundary inside a cycle (measured by
-  the engine's :class:`TransferProbe`, not inferred), compiles at most one
-  program per shape signature, and re-runs bitwise-identically.
+* **transfer discipline.** The device-resident cycle scan moves zero bytes
+  across the host boundary inside a segment (measured by the engine's
+  :class:`TransferProbe`, not inferred), compiles at most one program per
+  shape signature, and re-runs bitwise-identically. A segment that trips a
+  flag replays on the host-resident ladder, still bitwise.
 
 4-rank cases need 4 addressable devices and run in the CI job with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4``; on a single real
@@ -161,6 +162,39 @@ def test_residency_requires_collective_transport():
                               cfg=SPHConfig(use_pallas=True))
 
 
+def test_schedule_follows_residency():
+    """The residency decides the schedule: host-resident state runs the
+    host ladder, device-resident state the cycle scan. A spec that names
+    the derived schedule is the same spec, and the same program, as one
+    that leaves it out; the host-scheduled fused ladder is gone."""
+    base = _timebin_spec("sedov", backend="distributed", ranks=1,
+                         transport="collective")
+    assert base.schedule == "host"
+    dev = base.with_(residency="device")
+    assert dev.schedule == "device"
+    named = base.with_(residency="device", schedule="device")
+    assert named == dev
+    assert named.program_signature() == dev.program_signature()
+    assert named.signature_key() == dev.signature_key()
+    assert dev.with_(residency="host").schedule == "host"
+    with pytest.raises(ValueError, match="fused sub-step ladder is gone"):
+        base.with_(residency="device", schedule="host")
+    with pytest.raises(ValueError, match="requires residency='device'"):
+        base.with_(schedule="device")
+    with pytest.raises(ValueError, match="schedule must be"):
+        base.with_(schedule="cloud")
+    with pytest.raises(ValueError, match="segment_cycles > 1"):
+        base.with_(segment_cycles=2)
+    from repro.sph.dist_timebins import DistTimeBinSimulation
+    from repro.sph import uniform_ic
+    ic = uniform_ic(3, seed=0)
+    with pytest.raises(ValueError, match="fused sub-step ladder is gone"):
+        DistTimeBinSimulation(ic["pos"], ic["vel"], ic["mass"], ic["u"],
+                              ic["h"], box=ic["box"],
+                              transport="collective", residency="device",
+                              schedule="host")
+
+
 # ------------------------------------------ global family (determinism + φ)
 @pytest.mark.slow
 @pytest.mark.parametrize("integrator,backend", [
@@ -223,26 +257,35 @@ def test_global_distributed_tracks_local_reference():
 
 
 # ------------------------------------------------- transfer-count regression
-def _assert_resident_discipline(eng, interior_substeps: int):
+def _assert_resident_discipline(sim, cycles: int):
+    """The cycle scan's contract: inside a segment NOTHING moves between
+    host and device — only the boundary scatter, table upload, stats pull
+    and gather — each program compiles once, and ``cycles`` more cycles
+    reuse every program."""
+    eng = sim.engine
     stats = eng.transfers.stats()
-    # zero intra-cycle dynamical-state bytes — the tentpole's core claim
     assert stats["intra_state_bytes"] == 0, stats
-    # only control plane moves mid-cycle: index tables, changed flags, and
-    # bins-mirror refreshes (one event per deepening/wake-up)
-    assert set(eng.transfers.intra_bytes) <= {"tables", "flags", "bins"}
-    assert (eng.transfers.intra_events.get("bins", 0) == 0) \
-        == (eng.bins_refreshes == 0)
+    assert dict(eng.transfers.intra_bytes) == {}
     # boundary traffic exists: the scatter/gather really went through the
     # probe (guards against the ledger silently going stale)
     for f in ("pos", "vel", "u", "bins"):
         assert stats["boundary_bytes"].get(f, 0) > 0, f
-    # ≤ 1 compile per fused (phase, shape-signature) program
+    assert stats["boundary_events"]["segment_tables"] > 0
+    assert stats["boundary_events"]["segment_stats"] == eng.segments
+    # ≤ 1 compile per (program, shape signature)
     for name, c in eng.probe.counts().items():
         if name.startswith("program:"):
             assert c == 1, (name, c)
-    assert any(k[0] == "fused_force" for k in eng.program_keys) \
-        == (interior_substeps > 0)
-    assert any(k[0] == "fused_final" for k in eng.program_keys)
+    assert any(k[0] == "cycle_scan" for k in eng.program_keys)
+    assert any(k[0] == "segment_plan" for k in eng.program_keys) \
+        == (eng.segment_cycles > 1)
+    builds = eng._transport.programs.builds
+    compiles = eng.probe.total_compiles()
+    for _ in range(cycles):                     # stable bins: full reuse
+        sim.step()
+    assert eng._transport.programs.builds == builds
+    assert eng.probe.total_compiles() == compiles
+    assert eng.transfers.stats()["intra_state_bytes"] == 0
 
 
 def _run_resident(ranks: int):
@@ -253,35 +296,21 @@ def _run_resident(ranks: int):
     for _ in range(2):
         interior += sim.step()["force_substeps"] - 1
     assert interior > 0         # the scenario must exercise a real ladder
-    return sim, interior
+    return sim
 
 
 @pytest.mark.slow
 def test_fused_resident_transfer_discipline_one_rank():
-    sim, interior = _run_resident(ranks=1)
-    _assert_resident_discipline(sim.engine, interior)
-
-
-@requires4
-@pytest.mark.slow
-def test_fused_resident_transfer_discipline_four_ranks():
-    sim, interior = _run_resident(ranks=4)
-    eng = sim.engine
-    _assert_resident_discipline(eng, interior)
-    assert eng.halo_exported_slots > 0          # a real cut was exchanged
-    builds = eng._transport.programs.builds
-    compiles = eng.probe.total_compiles()
-    sim.step()                                  # stable bins: full reuse
-    assert eng._transport.programs.builds == builds
-    assert eng.probe.total_compiles() == compiles
-    assert eng.transfers.stats()["intra_state_bytes"] == 0
+    sim = _run_resident(ranks=1)
+    assert sim.engine.segments == 2 and sim.engine.segment_aborts == 0
+    _assert_resident_discipline(sim, cycles=1)
 
 
 def _hot_sedov_spec(ranks: int) -> SimulationSpec:
     """A Sedov configuration whose blast provably deepens bins mid-cycle
     (e0=30 with a loose CFL: the central particles' demand tightens
-    inside cycle 1), so a bins-mirror refresh MUST fire. max_depth=3
-    keeps the ladder — and any fully-unrolled device program — short."""
+    inside cycle 1). max_depth=3 keeps the ladder — and the CPU's fully
+    unrolled cycle scan — short."""
     return SimulationSpec(
         scenario="sedov", scenario_params={"n_side": 6, "e0": 30.0,
                                            "seed": 0},
@@ -291,36 +320,13 @@ def _hot_sedov_spec(ranks: int) -> SimulationSpec:
         transport="collective", residency="device")
 
 
-@requires4
-@pytest.mark.slow
-def test_bins_refreshes_pinned_to_per_event_minimum():
-    """`bins_refreshes` counts deepening *events*, not ranks or substeps:
-    the 4-rank hot Sedov trips exactly one mid-cycle deepening, so the
-    counter must read 1 (a per-rank or per-substep accounting bug would
-    read 4+), and the mirror pull must move one row per tripped rank —
-    never a full-state readback."""
-    sim = build_simulation(_hot_sedov_spec(ranks=4))
-    sim.step()
-    eng = sim.engine
-    assert eng.bins_refreshes == 1
-    # one (nrows,) int32 row per rank that owns deepened particles — the
-    # central blast straddles all four ranks here, so four row pulls
-    assert eng.transfers.intra_events.get("bins", 0) == 4
-    assert eng.transfers.stats()["intra_state_bytes"] == 0
-    # the event count is rank-independent: the single-rank run of the
-    # same dynamics sees the same one event (and pulls just its own row)
-    lone = build_simulation(_hot_sedov_spec(ranks=1))
-    lone.step()
-    assert lone.engine.bins_refreshes == 1
-    assert lone.engine.transfers.intra_events.get("bins", 0) == 1
-
-
 # ------------------------------------------------ device-scheduled segments
 NCYC_SEG = 4
 
 
-def _device_sched_spec(scenario: str, K: int, **over) -> SimulationSpec:
-    return _timebin_spec(scenario, backend="distributed", ranks=4,
+def _device_sched_spec(scenario: str, K: int, ranks: int = 4,
+                       **over) -> SimulationSpec:
+    return _timebin_spec(scenario, backend="distributed", ranks=ranks,
                          transport="collective", residency="device",
                          schedule="device", segment_cycles=K, **over)
 
@@ -365,33 +371,20 @@ def test_device_schedule_conformance(scenario, K):
 
 @requires4
 @pytest.mark.slow
-def test_device_schedule_zero_intra_bytes_and_compile_discipline():
-    """The tentpole contract: a segment moves NOTHING between host and
-    device except the boundary table upload and the boundary stats pull —
-    no per-cycle flags, no bins mirrors, no schedule tables — and each
-    (signature, bucket, K) compiles its two programs exactly once, with
-    full reuse on the next segment."""
-    sim = build_simulation(_device_sched_spec("sedov", 4))
+@pytest.mark.parametrize("K", [1, 4])
+def test_device_schedule_zero_intra_bytes_and_compile_discipline(K):
+    """Four ranks, one- and four-cycle segments: a segment moves NOTHING
+    between host and device except the boundary table upload and the
+    boundary stats pull — no per-cycle flags, no bins mirrors, no schedule
+    tables — over a real cut, and each (signature, bucket, K) compiles its
+    programs exactly once, with full reuse on the next segments."""
+    sim = build_simulation(_device_sched_spec("sedov", K))
     for _ in range(NCYC_SEG):
         sim.step()
     eng = sim.engine
-    stats = eng.transfers.stats()
-    assert stats["intra_state_bytes"] == 0
-    assert dict(eng.transfers.intra_bytes) == {}
-    assert stats["boundary_events"]["segment_tables"] > 0
-    assert stats["boundary_events"]["segment_stats"] == eng.segments == 1
-    for name, c in eng.probe.counts().items():
-        if name.startswith("program:"):
-            assert c == 1, (name, c)
-    assert any(k[0] == "cycle_scan" for k in eng.program_keys)
-    assert any(k[0] == "segment_plan" for k in eng.program_keys)
-    builds = eng._transport.programs.builds
-    compiles = eng.probe.total_compiles()
-    for _ in range(NCYC_SEG):                   # second segment: full reuse
-        sim.step()
-    assert eng._transport.programs.builds == builds
-    assert eng.probe.total_compiles() == compiles
-    assert eng.transfers.stats()["intra_state_bytes"] == 0
+    assert eng.segments == NCYC_SEG // K and eng.segment_aborts == 0
+    assert eng.halo_exported_slots > 0          # a real cut was exchanged
+    _assert_resident_discipline(sim, cycles=NCYC_SEG)
 
 
 @requires4
@@ -399,12 +392,15 @@ def test_device_schedule_zero_intra_bytes_and_compile_discipline():
 def test_device_schedule_mid_segment_deepening():
     """Bins that deepen in the middle of a compiled segment are handled
     entirely on device — no sentinel trip, no host fallback — and the
-    boundary state stays bitwise. The host-scheduled run of the same
-    configuration proves the deepening event is really there (it must
-    refresh its bins mirror once)."""
-    host = build_simulation(_hot_sedov_spec(ranks=4))
-    host.step()
-    assert host.engine.bins_refreshes == 1      # the event exists
+    boundary state stays bitwise. A one-cycle run of the same
+    configuration proves the deepening event is really there: its device
+    metrics row counts the deepened particles."""
+    from repro.observability import device_metrics as dm
+    one = build_simulation(_hot_sedov_spec(ranks=4).with_(
+        observe={"trace": False, "device_metrics": True}))
+    one.step()
+    counts, _ = one.engine.device_metrics_last
+    assert counts[:, dm.COUNT_INDEX["deepen_events"]].sum() > 0
     hot = dict(scenario="sedov",
                scenario_params={"n_side": 6, "e0": 30.0, "seed": 0},
                physics=SPHConfig(alpha_visc=1.0, cfl=0.3),
@@ -425,13 +421,13 @@ def test_device_schedule_mid_segment_deepening():
     assert dict(eng.transfers.intra_bytes) == {}
 
 
-@requires4
 @pytest.mark.slow
-def test_device_schedule_nan_sentinel_trip_and_resume():
-    """A NaN minted on device trips the in-program sentinel; the segment
-    aborts back to the host ladder and replays bitwise — NaNs propagate
-    identically to the reference, and the observer's health record shows
-    the trip."""
+@pytest.mark.parametrize("ranks", [1, pytest.param(4, marks=requires4)])
+def test_device_schedule_nan_sentinel_trip_and_resume(ranks, tmp_path):
+    """A NaN minted on device trips the in-program sentinel; the one-cycle
+    segment aborts to the host-resident ladder and replays bitwise — NaNs
+    propagate identically to the reference, and the observer's health
+    record shows the trip."""
     ref = build_simulation(_timebin_spec("sedov"))
     ref.step()
     _poison_vel(ref.engine)
@@ -441,18 +437,19 @@ def test_device_schedule_nan_sentinel_trip_and_resume():
             ref.step()
             refs.append(_snapshot(ref.engine))
     sim = build_simulation(_device_sched_spec(
-        "sedov", 1, observe={"device_metrics": True}))
-    sim.step()
+        "sedov", 1, ranks=ranks,
+        observe={"device_metrics": True, "flight_dir": str(tmp_path)}))
+    assert "replayed" not in sim.step()
     rec0 = sim.observer.records[-1]
     assert rec0["health"] is not None and rec0["health"]["tripped"] is False
     _poison_vel(sim.engine)
     got = []
     with np.errstate(invalid="ignore"):
         for _ in range(2):
-            sim.step()
+            assert sim.step()["replayed"] is True
             got.append(_snapshot(sim.engine))
     eng = sim.engine
-    assert eng.segment_aborts >= 1
+    assert eng.segment_aborts == 2
     rec = sim.observer.records[-1]
     assert rec["health"]["tripped"] is True
     assert rec["health"]["flags"].get("flag_nan", 0) > 0
@@ -460,6 +457,41 @@ def test_device_schedule_nan_sentinel_trip_and_resume():
         for name in b:
             np.testing.assert_array_equal(
                 a[name], b[name], err_msg=f"nan-resume cycle {c}: {name}")
+
+
+@pytest.mark.slow
+def test_device_segment_abort_replays_on_host_ladder():
+    """A four-cycle segment whose particles cross cells (Sedov on a 5³
+    grid: the blast moves the central particles out of their cells by
+    cycle 4) trips the plan program's crossing flag — no health sentinel
+    is involved — and its cycles replay on the host-resident ladder, each
+    marked ``replayed`` and each bitwise the single-rank reference."""
+    hot = dict(scenario="sedov",
+               scenario_params={"n_side": 10, "e0": 1.0, "seed": 0,
+                                "n_target": 32.0},
+               physics=SPHConfig(alpha_visc=1.0, cfl=0.15, n_target=32.0),
+               max_depth=2, integrator="timebin")
+    refs = _trajectory(build_simulation(SimulationSpec(**hot)), NCYC_SEG)
+    sim = build_simulation(SimulationSpec(
+        **hot, backend="distributed", ranks=1, transport="collective",
+        residency="device", segment_cycles=NCYC_SEG,
+        observe={"trace": False, "device_metrics": True}))
+    eng = sim.engine
+    got = []
+    epilogue = eng._cycle_epilogue
+
+    def snap_after(ctx, body):      # the state at each replayed cycle's end
+        stats = epilogue(ctx, body)
+        got.append(_snapshot(eng))
+        return stats
+
+    eng._cycle_epilogue = snap_after
+    stats = [sim.step() for _ in range(NCYC_SEG)]
+    assert eng.segments == 1 and eng.segment_aborts == 1
+    assert not any(r["health"]["tripped"] for r in sim.observer.records)
+    assert all(s["replayed"] is True and s["segment_cycles"] == NCYC_SEG
+               and "pair_slots" not in s for s in stats)
+    _assert_bitwise(got, refs, "crossing replay")
 
 
 def _poison_vel(eng) -> None:
@@ -524,10 +556,11 @@ def test_device_metrics_carry_bitwise_all_quadrants(integrator, backend):
 
 @pytest.mark.slow
 def test_device_metrics_carry_mints_no_extra_programs():
-    """The fused program with the telemetry output IS the program: turning
+    """The cycle scan with the telemetry output IS the program: turning
     the carry off (device_metrics=False) compiles nothing different and
-    produces the bitwise-same trajectory — the row is always computed, the
-    flag only gates the once-per-cycle host pull."""
+    produces the bitwise-same trajectory — the row is always computed and
+    always rides in the segment's one stats pull; the flag only gates the
+    once-per-cycle adoption of it."""
     base = _timebin_spec("sedov", backend="distributed", ranks=1,
                          transport="collective", residency="device")
     off = build_simulation(base.with_(
@@ -539,17 +572,20 @@ def test_device_metrics_carry_mints_no_extra_programs():
     assert on.engine.probe.total_compiles() \
         == off.engine.probe.total_compiles()
     assert on.engine.probe.counts() == off.engine.probe.counts()
-    # the pull is ledgered on the instrumented engine only, once per cycle
-    assert on.engine.transfers.stats()["boundary_events"]["metrics"] \
+    # one ledgered stats pull per cycle on both engines, and the same
+    # bytes: only the instrumented engine adopts the row it carries
+    assert on.engine.transfers.stats() == off.engine.transfers.stats()
+    assert on.engine.transfers.stats()["boundary_events"]["segment_stats"] \
         == NCYCLES
-    assert "metrics" not in off.engine.transfers.stats()["boundary_events"]
+    assert on.engine.device_metrics_pulls == NCYCLES
+    assert off.engine.device_metrics_pulls == 0
     assert off.engine.device_metrics_last is None
 
 
 @requires4
 @pytest.mark.slow
 def test_device_metrics_four_rank_fused_rows():
-    """4-rank fused run: per-rank per-phase work comes from inside the
+    """4-rank cycle scan: per-rank per-phase work comes from inside the
     program, covers owned rows only (ranks sum to the global particle
     count, not 4× it), and still costs one ledgered pull per cycle."""
     spec = _timebin_spec("sedov", backend="distributed", ranks=4,
@@ -576,15 +612,16 @@ def test_device_metrics_four_rank_fused_rows():
         or (subs > 0).all()          # every rank ran every sub-step
     assert (drift > 0).all()
     assert drift.sum() == subs[0] * nreal
-    assert eng.transfers.stats()["boundary_events"]["metrics"] == NCYCLES
-    # the fused run feeds measured per-phase work into the cost model
+    assert eng.transfers.stats()["boundary_events"]["segment_stats"] \
+        == NCYCLES
+    # the scan's one aggregate sample feeds per-phase work to the model
     assert {"density", "force"} <= set(rec["cost_ratios"])
 
 
 @requires4
 @pytest.mark.slow
 def test_per_cell_attribution_sums_to_phase_units_four_rank():
-    """4-rank fused run: the per-cell work vectors (schema v3) are exact —
+    """4-rank cycle scan: the per-cell work vectors (schema v3) are exact —
     per-rank owned-row sums equal the in-program value columns for
     density/force/exchange and the drift-active count, with halo rows
     folded onto owners (no double-counting)."""

@@ -19,8 +19,7 @@ from repro.kernels.sph_pair.kernel import (density_pair_pallas,
                                            force_pair_pallas)
 from repro.sph import SPHConfig
 from repro.sph.cellgrid import PairList, ParticleCells
-from repro.sph.collectives import (build_cycle_scan_program,
-                                   build_fused_substep_program)
+from repro.sph.collectives import build_cycle_scan_program
 from repro.sph.engine import _density_pass
 
 C = 32                  # cell capacity of the kernels at their real width
@@ -93,10 +92,10 @@ def test_density_pass_keeps_f32_distances(one_chip):
             assert "operand_precision={highest,highest}" in line, line
 
 
-def _fused_args(mesh, nranks, nrows, K, B, Bi, Bc, R, Be):
-    """Argument shapes of one fused force sub-step at the n_side=16 grid
-    (capacity 40), split over ``nranks`` ranks."""
-    sh, rep = NamedSharding(mesh, P("ranks")), NamedSharding(mesh, P())
+def _scan_args(mesh, nranks, nrows, K, B, Bi, Bc, R, Be):
+    """Argument shapes of one cycle scan at the n_side=16 grid (capacity
+    40), split over ``nranks`` ranks."""
+    sh = NamedSharding(mesh, P("ranks"))
     f32, i32 = jnp.float32, jnp.int32
     cap = 40
     rows = lambda *tail: (nranks, nrows) + tail            # noqa: E731
@@ -113,36 +112,15 @@ def _fused_args(mesh, nranks, nrows, K, B, Bi, Bc, R, Be):
               "int_valid": ((nranks, Bi), f32),
               "cut_pos": ((nranks, Bc), i32),
               "cut_valid": ((nranks, Bc), f32),
-              "wake": ((nranks, nrows), i32),
               "e_pack": ((nranks, R, Be), i32),
               "e_unpack": ((nranks, R, Be), i32),
-              "e_valid": ((nranks, R, Be), f32)}
+              "e_valid": ((nranks, R, Be), f32),
+              "own_pair": ((nranks, B), f32),
+              "rowcell": ((nranks, nrows), i32)}
     tables = {k: _sds(s, d, sh) for k, (s, d) in tables.items()}
-    scalars = {"dt_drift": f32, "level": i32, "dt_max": f32, "depth": i32,
-               "u_floor": f32}
-    scalars = {k: _sds((), d, rep) for k, d in scalars.items()}
+    scalars = {k: _sds((nranks,), d, sh) for k, d in (
+        ("dt_max", f32), ("depth", i32), ("nsub", i32), ("u_floor", f32))}
     return state, tables, scalars
-
-
-@pytest.mark.parametrize("nranks", [1, 4])
-def test_fused_substep_compiles_for_v5e(topo, nranks):
-    mesh = Mesh(np.array(topo.devices[:nranks]), ("ranks",))
-    if nranks == 1:
-        rounds, shapes = [], dict(nrows=343, K=343, B=8192, Bi=8192, Bc=1,
-                                  R=0, Be=1)
-    else:
-        ring = [[(r, (r + s) % nranks) for r in range(nranks)]
-                for s in (1, nranks - 1)]
-        rounds, shapes = ring, dict(nrows=256, K=96, B=4096, Bi=2048,
-                                    Bc=4096, R=2, Be=64)
-    prog = build_fused_substep_program(
-        mesh, "ranks", mode="ppermute", rounds=rounds,
-        nrows=shapes["nrows"], K=shapes["K"],
-        cfg=SPHConfig(alpha_visc=1.0, cfl=0.15), box=1.0)
-    args = _fused_args(mesh, nranks, **shapes)
-    txt = prog.lower(*args).compile().as_text()
-    if nranks == 4:
-        assert "collective-permute" in txt or "all-gather" in txt
 
 
 def _scan_shapes(nranks):
@@ -165,14 +143,6 @@ def test_cycle_scan_compiles_rolled_for_v5e(topo, nranks):
         nrows=shapes["nrows"], K=shapes["K"],
         cfg=SPHConfig(alpha_visc=1.0, cfl=0.15), box=1.0, nsub_static=16,
         bin_delta=2)
-    state, tables, _ = _fused_args(mesh, nranks, **shapes)
-    sh = NamedSharding(mesh, P("ranks"))
-    del tables["wake"]
-    tables["own_pair"] = _sds((nranks, shapes["B"]), jnp.float32, sh)
-    tables["rowcell"] = _sds((nranks, shapes["nrows"]), jnp.int32, sh)
-    scalars = {k: _sds((nranks,), d, sh) for k, d in (
-        ("dt_max", jnp.float32), ("depth", jnp.int32), ("nsub", jnp.int32),
-        ("u_floor", jnp.float32))}
-    txt = prog.lower(state, tables, scalars).compile().as_text()
+    txt = prog.lower(*_scan_args(mesh, nranks, **shapes)).compile().as_text()
     assert " while(" in txt
     assert txt.count(" conditional(") == 3
